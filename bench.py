@@ -10,12 +10,13 @@ observably computes it (RapidsRowMatrix.scala:111-117: uncentered Gram) —
 Gram on the MXU (3-pass bf16 split, Precision.HIGH) + randomized subspace
 decomposition + sign-flip + explained variance.
 
-Methodology: the PJRT transport here has ~70 ms host↔device round-trip
-latency and an unreliable ``block_until_ready`` fence, so single-dispatch
-timing is meaningless. We time a ``lax.scan`` chain of N fits inside ONE
-program — each iteration's input multiplied by (1 + carry·1e-38) so XLA can
-neither hoist nor dead-code-eliminate the work, and the outputs consumed via
-full reductions — and take the slope between N=12 and N=2 runs. r2 showed
+Methodology: whether ``block_until_ready`` is a sound fence and how large
+the per-dispatch constant is are unverified on this machine (ROADMAP S1
+measures both), so single-dispatch timing is not used yet. We time a
+``lax.scan`` chain of N fits inside ONE program — each iteration's input
+multiplied by (1 + carry·1e-38) so XLA can neither hoist nor
+dead-code-eliminate the work, and the outputs consumed via full
+reductions — and take the slope between N=12 and N=2 runs. r2 showed
 27% round-to-round drift with min-of-3 single-slope timing, so the slope is
 now computed per (short, long) PAIR and the reported value is the MEDIAN of
 5 pairs, with the spread published alongside.
@@ -92,11 +93,11 @@ ANN_ORACLE_QUERIES = 256
 
 # --smoke: run the WHOLE bench pipeline at tiny shapes on the CPU backend.
 # Rationale (r3 post-mortem): the bench script itself was only ever executed
-# at snapshot time on the real chip, so pipeline bitrot and transport
-# wedges both surfaced as rc=1 with zero recorded numbers. The smoke mode
-# proves every stage (data gen, paired-slope timing, transform/KMeans/
-# accuracy/DataFrame metrics, JSON contract) end-to-end in seconds, with
-# numbers that are meaningless as performance but exercise identical code.
+# at snapshot time on the real chip, so pipeline bitrot surfaced as rc=1
+# with zero recorded numbers. The smoke mode proves every stage (data gen,
+# paired-slope timing, transform/KMeans/accuracy/DataFrame metrics, JSON
+# contract) end-to-end in seconds, with numbers that are meaningless as
+# performance but exercise identical code.
 SMOKE = "--smoke" in sys.argv
 
 if SMOKE:
@@ -117,54 +118,10 @@ if SMOKE:
     PAIRS = 2
 
 
-def _emit_opportunistic_fallback() -> bool:
-    """Print the round's monitor-harvested bench JSON, if one exists.
-
-    The monitor only writes ``BENCH_OPPORTUNISTIC_r*.json`` after a full
-    rc=0 run of THIS script on the real chip, stamping it with the harvest
-    time; re-emitting it (tagged) is an honest measurement — unlike
-    exiting with no numbers because the transport happened to be wedged at
-    snapshot time. A COMMITTED harvest from a PAST round must never pass
-    for this round's, so anything older than
-    ``TPU_ML_OPPORTUNISTIC_MAX_AGE_S`` (default 14 h — longer than a
-    round, shorter than two) or unstamped is rejected. Returns False when
-    no acceptable harvest exists (caller re-raises).
-    """
-    import glob
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    candidates = sorted(glob.glob(os.path.join(here, "BENCH_OPPORTUNISTIC_r*.json")))
-    if not candidates:
-        return False
-    path = candidates[-1]
-    try:
-        with open(path) as f:
-            result = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return False
-    if "metric" not in result or "value" not in result:
-        return False
-    max_age = float(
-        os.environ.get(knobs.OPPORTUNISTIC_MAX_AGE_S.name, 14 * 3600)
-    )
-    harvested = result.get("harvested_at_unix")
-    if not isinstance(harvested, (int, float)):
-        return False
-    if time.time() - float(harvested) > max_age:
-        return False
-    result["note"] = (
-        "snapshot-time transport wedged; value measured on-chip earlier "
-        f"this round by tools/healthd.py ({os.path.basename(path)}; "
-        "per-run drift series in BENCH_DRIFT of the same round)"
-    )
-    print(json.dumps(result))
-    return True
-
-
 def _paired_slope(short_call, long_call, iter_delta: int, reps: int):
     """(median per-iteration slope, raw slopes) — THE timing methodology
     every metric here shares: time a short and a long dependent-op chain
-    back to back, difference out the dispatch/transport constant, repeat
+    back to back, difference out the per-dispatch constant, repeat
     ``reps`` times, take the median (r2 weak #4: min-of-N drifted 27%).
     Raises on a non-positive median — a noisy inversion must fail the
     metric loudly, never publish a negative throughput."""
@@ -200,13 +157,14 @@ def _paired_slope(short_call, long_call, iter_delta: int, reps: int):
 
 
 def _ledger_path() -> str:
-    """PERF_LEDGER.jsonl location: ``TPU_ML_PERF_LEDGER_PATH`` override, or
-    next to this script ('' disables the ledger entirely)."""
+    """This script's own run history: ``TPU_ML_PERF_LEDGER_PATH`` override,
+    or ``bench_history.jsonl`` next to this script ('' disables it). Never
+    ``PERF_LEDGER.jsonl`` — that file is the driver's record."""
     env = os.environ.get(knobs.PERF_LEDGER_PATH.name)
     if env is not None:
         return env
     return os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "PERF_LEDGER.jsonl"
+        os.path.dirname(os.path.abspath(__file__)), "bench_history.jsonl"
     )
 
 
@@ -317,52 +275,30 @@ def _emit_result(record: dict) -> None:
 
 
 def main() -> None:
-    # Transport-recovery preamble (r3 verdict #1): the accelerator transport
-    # on this host wedges *transiently* (observed: hours, clearing on its
-    # own), and r3's single 120s in-process probe turned one such outage
-    # into a whole round with no recorded numbers. Probe in throwaway
-    # subprocesses — repeatable, never poisons this process with a stuck
-    # backend-init thread, never SIGKILLs a mid-handshake child — retrying
-    # with backoff across a configurable window before giving up.
-    from spark_rapids_ml_tpu.utils import devicepolicy
-
-    if SMOKE:
-        devicepolicy.use_platform("cpu", probe_timeout=60.0)
-    else:
-        window = float(
-            os.environ.get(knobs.BENCH_PROBE_WINDOW_S.name, "3600")
-        )
-        attempt_timeout = float(
-            os.environ.get(knobs.BENCH_PROBE_TIMEOUT.name, "120")
-        )
-        try:
-            devicepolicy.wait_for_transport(
-                window=window, attempt_timeout=attempt_timeout
-            )
-        except devicepolicy.DevicePolicyError:
-            # r4 verdict #1: a wedged snapshot must not erase a round's
-            # on-chip evidence. If the round-long monitor
-            # (tools/healthd.py) harvested a complete result from THIS
-            # round while the transport was healthy, emit that — same
-            # program, same chip, measured earlier — clearly marked.
-            if _emit_opportunistic_fallback():
-                return
-            raise
-        # Transport verified healthy moments ago — now bind THIS process to
-        # the device, still bounded in case it wedged in the gap.
-        devicepolicy.probe_platform(
-            expected=None, timeout=attempt_timeout + 60.0
-        )
-
+    # A measurement path that finds no chip fails: the full bench runs on
+    # the TPU or not at all, and --smoke is the CPU contract test. Neither
+    # selects a platform — that is the caller's JAX_PLATFORMS.
     import jax
+
+    platform = jax.devices()[0].platform
+    want = "cpu" if SMOKE else "tpu"
+    if platform != want:
+        raise SystemExit(
+            f"bench.py{' --smoke' if SMOKE else ''} runs on {want!r} but JAX "
+            f"reports platform {platform!r} — nothing was run"
+        )
+    from spark_rapids_ml_tpu.utils.config import enable_compilation_cache
+
+    enable_compilation_cache()  # the raw kernels below precede any fit()
+
     import jax.numpy as jnp
     from jax import lax
 
     from spark_rapids_ml_tpu.ops import linalg as L
 
     # Generate device-side (correlated data: realistic spectrum) — pushing
-    # 8 GB of host-generated randoms through the PJRT transport would
-    # dominate setup time and prove nothing.
+    # 8 GB of host-generated randoms over the host link would dominate
+    # setup time and prove nothing.
     @jax.jit
     def make_data(seed):
         kb, km, kn = jax.random.split(jax.random.PRNGKey(seed), 3)
@@ -418,7 +354,7 @@ def main() -> None:
 
     # --- config-3 proxy: transform (projection) throughput ----------------
     # same paired-slope methodology as the fit metric — single-dispatch
-    # timing would fold the ~70 ms transport round-trip into the number
+    # timing would fold the per-dispatch constant into the number
     pc, _ = fit_pca_jit(x)
 
     def make_transform_chain(n_iter):
@@ -556,14 +492,14 @@ def main() -> None:
     # --- multi-process serve fleet proof (this PR) ------------------------
     # 2 supervised replicas behind the consistent-hash router, loadgen on
     # both wires, a rolling drain/restart mid-window with zero failed
-    # requests and a cache-warm respawn; hard contract in --smoke,
-    # guarded on-chip like its siblings
-    try:
+    # requests and a cache-warm respawn; a hard contract where it runs
+    if platform == "cpu":
         fleet_evidence = _bench_fleet()
-    except Exception as e:
-        if SMOKE:
-            raise
-        print(f"# fleet bench skipped: {e!r}", file=sys.stderr)
+    else:
+        # every replica is a process of its own that initializes JAX, and
+        # this parent holds the chip: a CPU-only construction until a cell
+        # decides the fleet's shape (ROADMAP D5/R6)
+        print(f"# fleet bench not run on {platform!r}", file=sys.stderr)
         fleet_evidence = None
 
     # --- ANN vector-search proof (this PR) --------------------------------
@@ -645,7 +581,7 @@ def main() -> None:
         (
             {
                 # the non-smoke name is the cross-round primary-metric key:
-                # it must stay byte-identical to BENCH_r01/r02's
+                # it must stay byte-identical from run to run
                 "metric": (
                     f"pca_fit_uncentered_device_wall_clock_{ROWS // 1000}k"
                     f"x{N}_k{K}{tag}"
@@ -951,7 +887,7 @@ def main() -> None:
 
 def _bench_knn() -> float:
     """Exact-kNN queries/s via the same paired-slope chain methodology as
-    the primary metric (the ~70 ms transport RTT would otherwise dominate
+    the primary metric (the per-dispatch constant could otherwise dominate
     a single ~ms kernel call): a lax.scan of dependent knn_topk calls, the
     N=6 vs N=2 slope taken as the per-iteration time."""
     import jax
@@ -994,12 +930,12 @@ def _bench_knn() -> float:
 def _bench_forest() -> float:
     """Random-forest build throughput: rows×trees processed per second of
     one full level-order build. The build is a multi-second program at
-    this shape, so plain median-of-3 timing suffices (the ~70 ms dispatch
+    this shape, so plain median-of-3 timing suffices (the per-dispatch
     constant is noise at this duration, unlike the per-ms kernels that
     need the chain-slope methodology). Completion is forced by a host
-    float() transfer, NOT block_until_ready — the transport's fence is
-    unreliable here (see the module doc), which is why every metric in
-    this file reads a scalar back."""
+    float() transfer, NOT block_until_ready — that fence is unverified on
+    this machine (see the module doc), which is why every metric in this
+    file reads a scalar back."""
     import jax
     import jax.numpy as jnp
 
@@ -1640,8 +1576,8 @@ def _bench_fleet() -> dict:
         ``cache_misses == 0``: it re-AOT'd entirely from the shared
         persistent compile cache (zero fresh XLA compiles after restart).
 
-    Hard contract in --smoke, guarded on-chip like its siblings."""
-    import tempfile
+    Runs only where the platform is the CPU (see main): every replica is a
+    process that initializes JAX."""
     import threading
 
     from spark_rapids_ml_tpu import PCA
@@ -1664,9 +1600,6 @@ def _bench_fleet() -> dict:
     replicas = 2
     connections = 64 if SMOKE else 500
     duration = 2.0 if SMOKE else 5.0
-    cache_dir = os.path.join(
-        tempfile.gettempdir(), "tpu-ml-fleet-bench-cache"
-    )
     # trace a slice of the loadgen window: at full rate a multi-thousand-
     # request window would blow through the flight-recorder ring
     # (TPU_ML_TIMELINE_EVENTS) and evict span parents, manufacturing
@@ -1682,10 +1615,7 @@ def _bench_fleet() -> dict:
         models,
         replicas=replicas,
         bucket_list=(8, 16),
-        extra_env={
-            knobs.SERVE_COMPILE_CACHE_DIR.name: cache_dir,
-            knobs.TRACE_SAMPLE.name: fleet_sample,
-        },
+        extra_env={knobs.TRACE_SAMPLE.name: fleet_sample},
     ).start()
     restarted_worker = None
     try:

@@ -687,7 +687,7 @@ class SwallowedExceptionRule(Rule):
     doc = (
         "`except Exception: pass` (or a bare except: pass) with no "
         "explanation swallows every failure mode including the "
-        "XlaRuntimeError families the retry classifier must see — PR 3 "
+        "JaxRuntimeError families the retry classifier must see — PR 3 "
         "exists because exactly this pattern hid a retry bug. A broad "
         "swallow is allowed only with a same-line comment saying why "
         "(narrow handlers, or handlers that do something, are fine)."

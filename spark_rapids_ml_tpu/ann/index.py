@@ -120,14 +120,14 @@ def _lloyd_mesh_fold_prog(mesh):
     allreduce happens once at finalize, not per chunk."""
     from jax.sharding import PartitionSpec as P
 
-    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, shard_map
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS), P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=P(DATA_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     def _fold(carry, xl, wl):
         st = KM.kmeans_stats(xl, carry.centers[0], weights=wl)

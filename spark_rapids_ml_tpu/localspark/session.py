@@ -76,6 +76,11 @@ class _Worker:
 
     def __init__(self, extra_env: dict[str, str | None] | None = None):
         env = devicepolicy.apply_overrides(os.environ, extra_env or {})
+        # the child imports this package by name; a parent that found it
+        # through its cwd or a sys.path edit must hand the root over
+        env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (devicepolicy.PACKAGE_ROOT, env.get("PYTHONPATH")) if p
+        )
         self._probe_armed = bool(env.get(devicepolicy.PROBE_VAR))
         self._tasks_done = 0
         self._stderr = tempfile.NamedTemporaryFile(
@@ -229,11 +234,10 @@ class LocalSparkSession:
       (``spark.sql.execution.arrow.maxRecordsPerBatch``)
     - ``worker_platform``: the device policy for worker processes (see
       ``utils.devicepolicy``). Default ``"cpu"`` — one device owner per
-      host: the driver keeps the accelerator, workers run the JAX CPU
-      backend, and the known accelerator-bootstrap env triggers are
-      scrubbed from worker environments so an interpreter-start plugin
-      cannot claim (or block on) the chip. Pass ``None`` to let workers
-      inherit the parent environment untouched.
+      host: the driver keeps the chips, workers get ``JAX_PLATFORMS=cpu``
+      (so they never load libtpu) and the parent's ``TPU_*`` topology
+      variables are scrubbed from their environment. Pass ``None`` to let
+      workers inherit the parent environment untouched.
     - ``worker_env``: extra env overrides for workers, applied on top of
       the device policy (a value of ``None`` removes the variable)
     """

@@ -163,8 +163,8 @@ def main() -> None:
     # Device-policy probe BEFORE accepting tasks: if this process cannot
     # initialize JAX on its assigned platform within a bounded time, exit
     # with a diagnosable error instead of hanging the first fit() job
-    # indefinitely (utils/devicepolicy.py documents why the env var alone is
-    # not enough). Armed by the session only on accelerator-attached hosts,
+    # indefinitely (utils/devicepolicy.py). Armed by the session only on
+    # hosts whose environment carries TPU topology variables,
     # because it costs the cold-interpreter fidelity documented above. The
     # driver maps PROBE_EXIT_CODE to a policy-specific WorkerException.
     from spark_rapids_ml_tpu.utils import devicepolicy

@@ -39,6 +39,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _CONTRACT_ROWS = (((0,), (0,)), ((), ()))  # aᵀb for row-major tiles
 
@@ -162,7 +163,10 @@ def symmetric_gram_moments(
       on the final row block.
 
     Fits when the n×n f32 Gram + two bf16 row blocks fit VMEM: n ≤ ~1280 at
-    the defaults. Callers gate on n and fall back to the XLA path above.
+    the defaults, where Mosaic allocates 16.25 MiB — just past its 16 MiB
+    default scoped limit (measured on v5e, jax 0.9.0), hence the explicit
+    ``vmem_limit_bytes``. Callers gate on n and fall back to the XLA path
+    above.
     """
     hi, lo, n = _pad_and_split(x, block_rows, block_cols)
     rows_p, n_p = hi.shape
@@ -190,6 +194,7 @@ def symmetric_gram_moments(
             bytes_accessed=2 * rows_p * n_p * 2 + n_p * n_p * 4,
             transcendentals=0,
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=32 * 2**20),
         interpret=interpret,
     )(hi, lo)
 

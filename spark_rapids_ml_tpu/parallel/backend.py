@@ -24,7 +24,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from spark_rapids_ml_tpu.parallel.mesh import shard_map
 from spark_rapids_ml_tpu.parallel.tree_aggregate import tree_reduce
 
 _initialized = False
@@ -88,7 +87,7 @@ def mapreduce_data_axis(
     if in_specs is None:
         in_specs = (P(DATA_AXIS, None),) + (P(),) * replicated_args
 
-    @partial(shard_map, mesh=mesh, in_specs=in_specs, out_specs=P(), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False)
     def _run(*args):
         return jax.tree.map(lambda v: lax.psum(v, DATA_AXIS), kernel(*args))
 
@@ -97,7 +96,7 @@ def mapreduce_data_axis(
 
 @lru_cache(maxsize=None)
 def _allreduce_prog(mesh: Mesh, axis: str):
-    @partial(shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False)
     def _psum(v):
         return lax.psum(v.sum(axis=0), axis)
 
@@ -114,7 +113,7 @@ def allreduce(x: jax.Array, mesh: Mesh, axis: str) -> jax.Array:
 @lru_cache(maxsize=None)
 def _allgather_prog(mesh: Mesh, axis: str):
     @partial(
-        shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(), check_rep=False
+        jax.shard_map, mesh=mesh, in_specs=P(axis), out_specs=P(), check_vma=False
     )
     def _gather(v):
         return lax.all_gather(v, axis, tiled=True)

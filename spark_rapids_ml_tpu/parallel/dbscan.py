@@ -22,7 +22,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_ml_tpu.ops import dbscan as DB
-from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, shard_map
+from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
 
 
 @lru_cache(maxsize=32)
@@ -36,11 +36,11 @@ def make_sharded_dbscan(mesh: Mesh, *, block_rows: int = 2048):
     """
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(x_shard, w_shard, valid_shard, eps_sq, min_pts):
         me = lax.axis_index(DATA_AXIS)

@@ -18,7 +18,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_ml_tpu.ops import forest as FO
-from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, shard_map
+from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
 
 
 @lru_cache(maxsize=32)
@@ -46,7 +46,7 @@ def make_sharded_forest(
             )
         )(keys, weights)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -54,7 +54,7 @@ def make_sharded_forest(
             P(), P(),
         ),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(
         sharded,

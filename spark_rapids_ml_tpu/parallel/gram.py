@@ -27,7 +27,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_ml_tpu.ops import linalg as L
-from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, FEAT_AXIS, shard_map
+from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, FEAT_AXIS
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 from spark_rapids_ml_tpu.telemetry.timeline import TIMELINE
 
@@ -125,11 +125,11 @@ def _ring_gram_prog(mesh: Mesh, precision):
     n_feat = mesh.shape[FEAT_AXIS]
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(DATA_AXIS, FEAT_AXIS),
         out_specs=(P(FEAT_AXIS, None), P(None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def _ring(xl):
         c = xl.shape[1]
@@ -221,11 +221,11 @@ def _range_stats_prog(mesh: Mesh):
     from spark_rapids_ml_tpu.ops import scaler as S
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def _run(xl, wl):
         # ws pad-mask convention: 0 on pad rows; ONE masking kernel shared
@@ -256,11 +256,11 @@ def _histogram_prog(mesh: Mesh, bins: int):
     from spark_rapids_ml_tpu.ops import scaler as S
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def _run(xl, wl, mins, maxs):
         hist = S.histogram_stats(
@@ -394,11 +394,11 @@ def _chunk_fold_prog(mesh: Mesh, kernel, vec_args: int):
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(DATA_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     def _fold(carry, xl, *vecs):
         local = kernel(xl, *vecs)

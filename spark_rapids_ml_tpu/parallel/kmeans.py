@@ -112,16 +112,15 @@ def make_distributed_kmeans_chunk(
     import jax.numpy as jnp
     from jax import lax
 
-    from spark_rapids_ml_tpu.parallel.mesh import shard_map
 
     tol_sq = tol * tol
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(), P()),
         out_specs=(P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(x, w, centers0, budget):
         limit = jnp.minimum(jnp.int32(chunk_iters), budget.astype(jnp.int32))
@@ -192,7 +191,6 @@ def make_distributed_kmeans_parallel_init(
     import jax.numpy as jnp
     from jax import lax
 
-    from spark_rapids_ml_tpu.parallel.mesh import shard_map
 
     ndev = mesh.shape[DATA_AXIS]
     s = max(1, -(-2 * k // ndev))  # ndev*s >= ell = 2k candidates per round
@@ -221,11 +219,11 @@ def make_distributed_kmeans_parallel_init(
         return jnp.where(valid[None, :], d2, jnp.inf)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(x, w, key):
         me = lax.axis_index(DATA_AXIS)

@@ -294,7 +294,6 @@ def make_distributed_logreg_chunk(
     import jax.numpy as jnp
     from jax import lax
 
-    from spark_rapids_ml_tpu.parallel.mesh import shard_map
 
     if loss not in ("logistic", "squared_hinge"):
         raise ValueError(f"loss must be 'logistic' or 'squared_hinge', got {loss!r}")
@@ -305,11 +304,11 @@ def make_distributed_logreg_chunk(
     )
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(x_aug, y, w_vec, w0, budget):
         limit = jnp.minimum(jnp.int32(chunk_iters), budget.astype(jnp.int32))
@@ -365,14 +364,13 @@ def make_distributed_softmax_chunk(
     import jax.numpy as jnp
     from jax import lax
 
-    from spark_rapids_ml_tpu.parallel.mesh import shard_map
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P(DATA_AXIS), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(x_aug, y, w_vec, w0, budget):
         y_idx = y.astype(jnp.int32)

@@ -15,7 +15,6 @@ this package:
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import numpy as np
@@ -38,21 +37,6 @@ def center_columns_shard(xl):
     s = lax.psum(jnp.sum(xl, axis=0), DATA_AXIS)
     c = lax.psum(jnp.asarray(xl.shape[0], xl.dtype), DATA_AXIS)
     return xl - (s / c)[None, :]
-
-
-def shard_map(f=None, **kwargs):
-    """``jax.shard_map`` across JAX versions: new releases renamed the
-    replication-check kwarg ``check_rep`` → ``check_vma`` and moved the API
-    out of ``jax.experimental``. All sharded kernels in this package route
-    through this shim."""
-    if f is None:
-        return partial(shard_map, **kwargs)
-    if hasattr(jax, "shard_map"):
-        kwargs.setdefault("check_vma", kwargs.pop("check_rep", True))
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _sm  # pragma: no cover
-
-    return _sm(f, **kwargs)  # pragma: no cover
 
 
 def create_mesh(

@@ -20,7 +20,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_ml_tpu.ops import neighbors as NN
-from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, shard_map
+from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
 
 
 @lru_cache(maxsize=32)
@@ -39,11 +39,11 @@ def make_sharded_knn(
     """
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def run(corpus, valid, queries):
         me = lax.axis_index(DATA_AXIS)

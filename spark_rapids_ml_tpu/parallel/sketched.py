@@ -38,7 +38,6 @@ from spark_rapids_ml_tpu.parallel.mesh import (
     DATA_AXIS,
     FEAT_AXIS,
     center_columns_shard,
-    shard_map,
 )
 from spark_rapids_ml_tpu.parallel.tsqr import merge_r
 
@@ -92,11 +91,11 @@ def sketched_pca_fit(
     mm = partial(jnp.matmul, precision=precision)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(DATA_AXIS, FEAT_AXIS),
         out_specs=(P(FEAT_AXIS, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def _fit(xl):
         j = lax.axis_index(FEAT_AXIS)
@@ -147,11 +146,11 @@ def sharded_column_means(x: jax.Array, mesh: Mesh) -> jax.Array:
     centered sketched fit needs at transform time, spec ``P(feat)``."""
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(DATA_AXIS, FEAT_AXIS),
         out_specs=P(FEAT_AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     def _mean(xl):
         s = lax.psum(jnp.sum(xl, axis=0), DATA_AXIS)
@@ -193,11 +192,11 @@ def sharded_project(
         in_specs.append(P(FEAT_AXIS))
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=P(DATA_AXIS, None),
-        check_rep=False,
+        check_vma=False,
     )
     def _proj(xl, vl, *maybe_mu):
         if maybe_mu:

@@ -29,7 +29,6 @@ from spark_rapids_ml_tpu.ops import linalg as L
 from spark_rapids_ml_tpu.parallel.mesh import (
     DATA_AXIS,
     center_columns_shard,
-    shard_map,
 )
 
 
@@ -89,11 +88,11 @@ def _tsqr_r_prog(mesh: Mesh):
     n_data = mesh.shape[DATA_AXIS]
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(DATA_AXIS, None),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def _tsqr(xl):
         return merge_r(L.qr_r(xl), n_data)
@@ -118,11 +117,11 @@ def distributed_pca_fit_svd(
     if mean_centering:
 
         @partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=P(DATA_AXIS, None),
             out_specs=P(DATA_AXIS, None),
-            check_rep=False,
+            check_vma=False,
         )
         def _center(xl):
             return center_columns_shard(xl)
@@ -162,11 +161,11 @@ def make_distributed_fit_svd_masked(
     n_data = mesh.shape[DATA_AXIS]
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(xl, wl):
         if mean_centering:

@@ -15,11 +15,11 @@ contract instead, in two halves:
 
 - :mod:`.retry` — the one shared retry policy. Errors are classified
   (transient / resource-exhausted / poisoned-backend / fatal, recognizing
-  jaxlib ``XlaRuntimeError`` families by status string), and
+  ``jax.errors.JaxRuntimeError`` families by status string), and
   :func:`retry.call_with_retry` drives exponential backoff with jitter
-  under a deadline. It replaces the ad-hoc loops in ``parallel/executor``
-  and ``utils/devicepolicy`` — and unlike the loop it replaced, it never
-  sleeps after the final failed attempt.
+  under a deadline. It replaces the ad-hoc loop in ``parallel/executor``
+  — and unlike the loop it replaced, it never sleeps after the final
+  failed attempt.
 
 - :mod:`.supervisor` — worker-slot supervision for ``localspark``: leases
   (spawn time, task count, last-trailer heartbeat), bounded respawn with

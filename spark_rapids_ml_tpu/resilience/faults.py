@@ -12,7 +12,7 @@ Kinds:
 
 - ``oom``        raise :class:`InjectedResourceExhausted` — a synthetic
                  ``RESOURCE_EXHAUSTED``-style device OOM, classified like
-                 the jaxlib ``XlaRuntimeError`` family it imitates.
+                 the ``JaxRuntimeError`` family it imitates.
 - ``io``         raise :class:`InjectedTransientIOError` (an ``IOError``
                  subclass) — a transient I/O failure, retryable.
 - ``hang``       sleep ``arg`` seconds (default 0.25) — a slow/hung call;

@@ -2,8 +2,8 @@
 
 Spark gave the reference a uniform answer to every task failure: fail the
 task, re-schedule it ``spark.task.maxFailures`` times (SURVEY.md §5). This
-framework's failures are more differentiated — a jaxlib ``XlaRuntimeError``
-can mean a transient transport blip (retry), device memory exhaustion
+framework's failures are more differentiated — a ``jax.errors.JaxRuntimeError``
+can mean a transient runtime blip (retry), device memory exhaustion
 (retry *smaller* — stream_fold bisects), or a poisoned PJRT client that no
 in-process retry will ever fix — so retries here start with a classifier:
 
@@ -13,22 +13,21 @@ in-process retry will ever fix — so retries here start with a classifier:
 - ``RESOURCE_EXHAUSTED``   — device/host OOM. Retrying the identical call
   is usually futile; retrying a *smaller* call works (chunk bisection).
 - ``POISONED``             — the backend/client is wedged (dead PJRT
-  client, hung fold). Only a fresh process helps; see
-  ``utils.devicepolicy.probe_transport_subprocess``.
+  client, hung fold). Only a fresh process helps.
 - ``FATAL``                — everything else (shape errors, value errors,
   simulated preemption). Never retried.
 
-``XlaRuntimeError`` is recognized structurally (class name / ``jaxlib``
-module anywhere in the MRO) so no jax import is needed here and synthetic
-faults classify identically to the real thing.
+A device error is a ``jax.errors.JaxRuntimeError`` (``isinstance``; jax is
+imported only once an exception is in hand). Synthetic faults declare the
+class they imitate and never reach that test.
 
 :func:`call_with_retry` is the single backoff loop the framework uses —
 exponential with deterministic jitter, capped, under an optional deadline,
 counting every retry in the telemetry registry (``retry.attempts{site}``)
-— replacing the hand-rolled loops in ``parallel/executor`` and
-``utils/devicepolicy``. By construction it never sleeps after the final
-failed attempt (the executor bug the migration fixed): the sleep only
-happens when a retry is actually coming.
+— replacing the hand-rolled loop in ``parallel/executor``. By
+construction it never sleeps after the final failed attempt (the executor
+bug the migration fixed): the sleep only happens when a retry is actually
+coming.
 """
 
 from __future__ import annotations
@@ -63,14 +62,7 @@ class FoldHangTimeout(RuntimeError):
 # XLA status families, matched against the upper-cased message
 _XLA_TRANSIENT = ("UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED", "CANCELLED", "UNKNOWN")
 _XLA_OOM = ("RESOURCE_EXHAUSTED", "RESOURCE EXHAUSTED", "OUT OF MEMORY", "ALLOCATION FAILURE")
-_XLA_POISONED = ("PJRT CLIENT", "BACKEND WAS", "DEVICE GRANT", "HEARTBEAT")
-
-
-def _is_xla_runtime_error(exc: BaseException) -> bool:
-    return any(
-        klass.__name__ == "XlaRuntimeError" or klass.__module__.startswith("jaxlib")
-        for klass in type(exc).__mro__
-    )
+_XLA_POISONED = ("PJRT CLIENT", "BACKEND WAS", "HEARTBEAT")
 
 
 def classify(exc: BaseException) -> ErrorClass:
@@ -86,7 +78,9 @@ def classify(exc: BaseException) -> ErrorClass:
         return ErrorClass.RESOURCE_EXHAUSTED
     if isinstance(exc, FoldHangTimeout):
         return ErrorClass.POISONED
-    if _is_xla_runtime_error(exc):
+    from jax.errors import JaxRuntimeError
+
+    if isinstance(exc, JaxRuntimeError):
         msg = str(exc).upper()
         if any(m in msg for m in _XLA_OOM):
             return ErrorClass.RESOURCE_EXHAUSTED

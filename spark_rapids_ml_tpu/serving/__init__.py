@@ -7,8 +7,8 @@ of the model lifecycle — low-latency scoring of already-fitted models:
   fitted model lowers its pure ``kernel(params, x)`` transform for every
   rung of the serve bucket ladder up front (``jit(...).lower(...).compile()``)
   and persists the executables through the XLA compilation cache
-  (``TPU_ML_SERVE_COMPILE_CACHE_DIR``), so a fresh process warms from disk
-  instead of recompiling.
+  (``utils.config.enable_compilation_cache``), so a fresh process warms
+  from disk instead of recompiling.
 - :mod:`.buckets` — power-of-two row buckets with zero padding and
   valid-row slicing; the enumerable bucket ladder is what makes the
   zero-recompile regime a hard guarantee rather than a hope.

@@ -16,9 +16,9 @@ Design points, each riding machinery an earlier PR shipped:
   replica trips its breaker instead of eating the fleet's wall clock;
   ``TPU_ML_WORKER_SLOT`` stamps each replica's identity.
 
-- **Warm respawns (PR 13).** Every replica shares
-  ``TPU_ML_SERVE_COMPILE_CACHE_DIR``, so a respawned replica re-AOTs
-  from the persistent XLA cache — zero fresh compiles after a rolling
+- **Warm respawns (PR 13).** Every replica shares one compile cache
+  (``JAX_COMPILATION_CACHE_DIR``, else ``<repo root>/.jax_cache``), so a
+  respawned replica re-AOTs from the persistent XLA cache — zero fresh compiles after a rolling
   restart (asserted by test). Models travel to replicas as an
   ``.npz`` + JSON spec (param arrays + family), reconstructed and
   registered on the replica side.

@@ -25,11 +25,11 @@ dwarfs a single-row matmul. This module strips both away:
   TPL003-sanctioned shape for program construction.
 
 - **Persistent warm start.** Compiles go through the XLA compilation
-  cache: ``TPU_ML_SERVE_COMPILE_CACHE_DIR`` names a serve-specific cache
-  dir (falling back to the shared ``TPU_ML_COMPILE_CACHE``), and the
-  persistence floor is dropped to zero so even fast kernels are written —
-  a fresh process re-registering the same models warms from disk
-  (``compile.cache_hits > 0``) instead of recompiling.
+  cache (``utils.config.enable_compilation_cache``: the directory named by
+  ``JAX_COMPILATION_CACHE_DIR``, else ``<repo root>/.jax_cache``), which
+  keeps even the fastest kernels — a fresh process re-registering the same
+  models warms from disk (``compile.cache_hits > 0``) instead of
+  recompiling.
 
 - **Tuning-cache consult.** The registry asks the PR 7 tuning cache for a
   blessed serve-kernel precision policy (key ``serve.<family>``); an
@@ -54,10 +54,10 @@ from spark_rapids_ml_tpu.serving import buckets, hbm
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 from spark_rapids_ml_tpu.telemetry.timeline import TIMELINE
 from spark_rapids_ml_tpu.utils import knobs
+from spark_rapids_ml_tpu.utils.config import enable_compilation_cache
 
 logger = logging.getLogger("spark_rapids_ml_tpu.serving")
 
-SERVE_COMPILE_CACHE_DIR_VAR = knobs.SERVE_COMPILE_CACHE_DIR.name
 SWAP_SHADOW_TOLERANCE_VAR = knobs.SWAP_SHADOW_TOLERANCE.name
 
 FAMILIES = ("pca", "linear", "scaler", "forest", "ann")
@@ -99,65 +99,6 @@ def validate_request(x: Any, n_features: int, model: str) -> np.ndarray:
             f"got shape {mat.shape}"
         )
     return mat
-
-
-# -- compile cache ----------------------------------------------------------
-
-_CACHE_LOCK = threading.Lock()
-_CACHE_DIR: str | None = None
-_CACHE_READY = False
-
-
-def enable_serve_compile_cache() -> str | None:
-    """Point the XLA compilation cache at the serve cache dir and drop the
-    persistence floor to zero, so every AOT serve kernel is written to disk
-    and a fresh process warms from it. Idempotent; returns the dir in use
-    (None when caching is disabled)."""
-    global _CACHE_DIR, _CACHE_READY
-    with _CACHE_LOCK:
-        if _CACHE_READY:
-            return _CACHE_DIR
-        import jax
-
-        from spark_rapids_ml_tpu.utils import config as config_mod
-
-        serve_dir = os.environ.get(SERVE_COMPILE_CACHE_DIR_VAR, "")
-        if serve_dir:
-            serve_dir = os.path.abspath(os.path.expanduser(serve_dir))
-            os.makedirs(serve_dir, exist_ok=True)
-            # enable_compilation_cache respects a pre-set dir, so set ours
-            # first and let it finish the wiring
-            jax.config.update("jax_compilation_cache_dir", serve_dir)
-        used = config_mod.enable_compilation_cache()
-        try:
-            # serve kernels are tiny: without this, fast compiles fall
-            # under the 0.5s persistence floor and never reach disk,
-            # which would silently defeat the warm start
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:  # noqa: BLE001 - older jax: keep the floor
-            logger.debug("jax_persistent_cache_min_compile_time_secs unsupported")
-        if used:
-            try:
-                # jax memoizes its cache-or-not decision at the FIRST
-                # backend compile of the process (compilation_cache
-                # ._cache_checked) — and model fits compile before any
-                # registration can set the dir, permanently disabling
-                # persistence for this process. Reset to pristine so the
-                # AOT serve compiles below re-evaluate with the dir set.
-                from jax.experimental.compilation_cache import (
-                    compilation_cache as _jax_cc,
-                )
-
-                _jax_cc.reset_cache()
-            except Exception:  # noqa: BLE001 - private-ish API: warm start
-                # degrades to cold compiles, never to a serve failure
-                logger.warning(
-                    "could not reset jax compilation cache; persistent "
-                    "serve warm start may be inactive", exc_info=True
-                )
-        _CACHE_DIR = used
-        _CACHE_READY = True
-        return used
 
 
 # -- pure serve kernels (params, x) -> out ----------------------------------
@@ -528,7 +469,7 @@ class ModelRegistry:
         """Extract the model's pure kernel and AOT-compile it for every
         bucket in ``bucket_list`` (default: the whole serve ladder). After
         this returns, requests up to the ladder cap never compile."""
-        enable_serve_compile_cache()
+        enable_compilation_cache()
         from spark_rapids_ml_tpu.telemetry import compilemon
 
         compilemon.install_monitoring()
@@ -647,7 +588,7 @@ class ModelRegistry:
         ``<name>@prior``) until :meth:`prune_prior` — the probation
         contract — or :meth:`rollback` restores it."""
         live = self.get(name)
-        enable_serve_compile_cache()
+        enable_compilation_cache()
         candidate = servable_from_model(name, model)
         if candidate.n_features != live.n_features:
             REGISTRY.counter_inc("serve.swap_refused", model=name,
@@ -880,7 +821,7 @@ def get_registry() -> ModelRegistry:
 def reset_for_tests() -> None:
     """Drop the singleton registry and every cached executable (tests
     only — production processes register once and keep everything warm)."""
-    global _MODEL_REGISTRY, _CACHE_READY, _CACHE_DIR
+    global _MODEL_REGISTRY
     with _REGISTRY_LOCK:
         _MODEL_REGISTRY = None
     with _TOKEN_LOCK:
@@ -889,6 +830,3 @@ def reset_for_tests() -> None:
     _hedge_compiled_for.cache_clear()
     _hedge_params.cache_clear()
     hbm.reset_fleet()
-    with _CACHE_LOCK:
-        _CACHE_READY = False
-        _CACHE_DIR = None

@@ -111,20 +111,19 @@ logger = logging.getLogger("spark_rapids_ml_tpu")
 
 
 def _mesh_or_fallback():
-    """Create the driver's device mesh for a mesh-local streamed fit, or
-    degrade gracefully: a non-fatal device-init failure (wedged transport,
-    exhausted device, poisoned client — or an injected fault at site
-    ``device.init``) downgrades to the single-device fallback path (returns
-    None) with a loud warning and a ``degraded.cpu_fallback`` telemetry
-    flag, instead of failing a fit that the host can still finish.
+    """The driver's device mesh for a mesh-local streamed fit.
 
-    A fit admitted under ``TPU_ML_ADMISSION_POLICY=degrade`` while a health
-    component is FAILING takes the same fallback *before* touching the
-    device — the point of degrading at admission is not to poke the sick
-    accelerator again."""
+    A failure to create it raises, whatever its class (an injected fault at
+    site ``device.init`` included): the fit was asked to run on this host's
+    devices, the single-device fold needs the very backend that just failed,
+    and a quiet CPU run is not what the caller asked for.
+
+    The one way to the single-device fallback (returns None) is a fit
+    admitted under ``TPU_ML_ADMISSION_POLICY=degrade`` while a health
+    component is FAILING — the operator chose not to poke the sick
+    accelerator again. It is loud: a warning and ``degraded.cpu_fallback``."""
     from spark_rapids_ml_tpu.parallel import mesh as M
     from spark_rapids_ml_tpu.resilience import faults
-    from spark_rapids_ml_tpu.resilience import retry as _retry
     from spark_rapids_ml_tpu.telemetry import health as health_mod
     from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 
@@ -136,19 +135,8 @@ def _mesh_or_fallback():
         )
         REGISTRY.counter_inc("degraded.cpu_fallback")
         return None
-    try:
-        faults.inject("device.init")
-        return M.create_mesh()
-    except Exception as e:  # noqa: BLE001 — classified below
-        if _retry.classify(e) is _retry.ErrorClass.FATAL:
-            raise
-        logger.warning(
-            "DEGRADED: device mesh initialization failed (%s: %s); "
-            "streaming this fit through the single-device fallback path — "
-            "expect reduced throughput", type(e).__name__, e,
-        )
-        REGISTRY.counter_inc("degraded.cpu_fallback")
-        return None
+    faults.inject("device.init")
+    return M.create_mesh()
 
 
 def _require_pyspark():
@@ -170,9 +158,6 @@ def _sql_mods(dataset):
     for a pyspark DataFrame, localspark's for the no-JVM engine. All plan
     construction below goes through this pair, so the two backends run the
     SAME estimator code."""
-    from spark_rapids_ml_tpu.utils.config import enable_compilation_cache
-
-    enable_compilation_cache()  # every Spark-path entry is compile-heavy
     mod = type(dataset).__module__ or ""
     if mod.startswith("pyspark."):
         _require_pyspark()
@@ -438,8 +423,7 @@ class SparkPCA(_HasDistribution, PCA):
         (parallel.gram.sharded_gram_fold) so device memory stays
         O(chunk + n²) — the resident [rows, n] array is never assembled.
         A ``checkpoint_dir`` makes that streamed pass resumable (carry +
-        chunk cursor every ``checkpoint_every`` chunks), and a non-fatal
-        device-init failure degrades it to the single-device fold."""
+        chunk cursor every ``checkpoint_every`` chunks)."""
         import jax
         import jax.numpy as jnp
 
@@ -455,7 +439,7 @@ class SparkPCA(_HasDistribution, PCA):
             ckpt = TrainingCheckpointer(checkpoint_dir) if checkpoint_dir else None
             dt = ingest.wire_dtype()
             mesh = _mesh_or_fallback()
-            if mesh is None:  # degraded: single-device donated fold
+            if mesh is None:  # admission-degraded: single-device fold
                 res = ingest.stream_fold(
                     selected,
                     L.gram_fold_step(precision),
@@ -760,7 +744,7 @@ class SparkLinearRegression(_HasDistribution, LinearRegression):
                     )
                     dt = ingest.wire_dtype()
                     mesh = _mesh_or_fallback()
-                    if mesh is None:  # degraded: single-device donated fold
+                    if mesh is None:  # admission-degraded: single-device fold
                         res = ingest.stream_fold(
                             selected,
                             LIN.linear_fold_step(),

@@ -477,8 +477,8 @@ class StreamFold:
 
 
 def _bounded_wait(carry, timeout_s: float):
-    """``jax.block_until_ready`` with a bound: a wedged device (hung
-    collective, dead transport) surfaces as a diagnosable
+    """``jax.block_until_ready`` with a bound: a device that stopped
+    answering (a hung collective, a dead runtime) surfaces as a diagnosable
     :class:`~spark_rapids_ml_tpu.resilience.retry.FoldHangTimeout` instead
     of blocking the driver forever. The waiter runs on a daemon thread; on
     timeout the stuck wait is abandoned with the thread (the process is
@@ -506,9 +506,9 @@ def _bounded_wait(carry, timeout_s: float):
     if t.is_alive():
         raise FoldHangTimeout(
             f"fold.wait did not complete within {timeout_s:g}s: the device "
-            "fold is hung, not slow — most likely a wedged collective or "
-            "device transport (check device health; on a mesh, every "
-            "participant must reach the same collective). Raise "
+            "fold is hung, not slow — most likely a collective that not "
+            "every participant reached, or a device runtime that died (check "
+            "device health). Raise "
             f"{FOLD_WAIT_TIMEOUT_VAR} to wait longer, or set it to 0 to "
             "disable the bound."
         )

@@ -18,9 +18,8 @@ subscribes once per process and folds them into the telemetry registry:
 - ``/jax/compilation_cache/compile_time_saved_sec`` → counter (seconds the
   cache provably saved).
 
-Key names drift across JAX releases, so unmatched compile-ish durations fall
-through to a generic ``compile.other_seconds`` histogram rather than being
-dropped.
+Compile-ish durations this table does not name fall through to a generic
+``compile.other_seconds`` histogram rather than being dropped.
 
 Device memory has no event stream; :func:`sample_device_memory` polls
 ``Device.memory_stats()`` (PJRT exposes ``bytes_in_use`` /
@@ -73,31 +72,23 @@ def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
     if name:
         REGISTRY.counter_inc(name, duration_secs)
         return
-    if "compile" in event:  # future JAX: keep the signal, generically
+    if "compile" in event:  # unnamed: keep the signal, generically
         REGISTRY.histogram_record("compile.other_seconds", duration_secs)
 
 
-def install_monitoring() -> bool:
-    """Register the jax.monitoring listeners (idempotent, thread-safe).
-
-    Returns False when this JAX build lacks the monitoring module; the rest
-    of the telemetry layer works regardless — compile fields just stay 0.
-    """
+def install_monitoring() -> None:
+    """Register the jax.monitoring listeners (idempotent, thread-safe)."""
     global _installed
     if _installed:
-        return True
+        return
     with _install_lock:
         if _installed:
-            return True
-        try:
-            import jax.monitoring as M
+            return
+        import jax.monitoring as M
 
-            M.register_event_listener(_on_event)
-            M.register_event_duration_secs_listener(_on_duration)
-        except (ImportError, AttributeError):  # pragma: no cover - old jax
-            return False
+        M.register_event_listener(_on_event)
+        M.register_event_duration_secs_listener(_on_duration)
         _installed = True
-    return True
 
 
 # memory_stats keys worth exporting (PJRT's full dict carries ~15 allocator

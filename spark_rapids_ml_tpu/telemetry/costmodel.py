@@ -22,8 +22,9 @@ them when the kernel compiled in this process.
 :func:`window_summary` turns a delta into the ``cost_model`` dict stamped
 into FitReport v3 / TransformReport: per-kernel calls + per-call analytical
 cost, window totals, and a roofline utilization estimate
-``analytical_flops / (wall_seconds × peak_flops)`` with the peak taken from
-``TPU_ML_PEAK_TFLOPS`` (default: TPU v5e bf16 peak, matching bench.py).
+``analytical_flops / (wall_seconds × peak_flops)`` with the peak looked up
+by ``device_kind`` (``TPU_ML_PEAK_TFLOPS`` overrides); a device the table
+does not know — the CPU included — gets no roofline figure.
 
 Analysis is strictly best-effort: any lowering/compile failure is cached as
 a no-op for that signature and never raises into the fit/transform path.
@@ -40,8 +41,9 @@ from spark_rapids_ml_tpu.utils import knobs
 
 logger = logging.getLogger("spark_rapids_ml_tpu")
 
-# TPU v5e bf16 peak (same anchor bench.py uses for its derived fractions).
-DEFAULT_PEAK_TFLOPS = 197.0
+# Published bf16 peak per chip in TFLOP/s, keyed by jax ``device_kind``.
+# v5e: Google Cloud documentation, "TPU v5e".
+PEAK_TFLOPS_BY_DEVICE_KIND = {"TPU v5 lite": 197.0}
 
 _LOCK = threading.Lock()
 _KERNELS: dict[str, dict] = {}  # kernel name -> analytical entry (per call)
@@ -55,14 +57,22 @@ _MEMORY_FIELDS = (
 )
 
 
-def peak_flops() -> float:
-    """Device peak FLOP/s for the roofline denominator."""
-    try:
-        return float(
-            os.environ.get(knobs.PEAK_TFLOPS.name, DEFAULT_PEAK_TFLOPS)
-        ) * 1e12
-    except (TypeError, ValueError):
-        return DEFAULT_PEAK_TFLOPS * 1e12
+def peak_flops() -> float | None:
+    """Device peak FLOP/s for the roofline denominator: the explicit
+    ``TPU_ML_PEAK_TFLOPS``, else the table entry for this process's
+    ``device_kind``, else ``None`` (no roofline figure)."""
+    raw = os.environ.get(knobs.PEAK_TFLOPS.name, "")
+    if raw:
+        try:
+            return float(raw) * 1e12
+        except ValueError:
+            raise ValueError(
+                f"{knobs.PEAK_TFLOPS.name}={raw!r} is not a number of TFLOP/s"
+            ) from None
+    import jax
+
+    tflops = PEAK_TFLOPS_BY_DEVICE_KIND.get(jax.devices()[0].device_kind)
+    return None if tflops is None else tflops * 1e12
 
 
 def _sig(a) -> str:
@@ -79,11 +89,7 @@ def _analyze(kernel: str, jitted_fn, args, kwargs) -> dict | None:
     """AOT-lower+compile the kernel and read XLA's analytical numbers."""
     try:
         compiled = jitted_fn.lower(*args, **kwargs).compile()
-        cost = compiled.cost_analysis()
-        # older jax returns [dict] (one per executable), newer a plain dict
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        cost = cost or {}
+        cost = compiled.cost_analysis() or {}
         entry = {
             "flops": float(cost.get("flops", 0.0) or 0.0),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0) or 0.0),
@@ -205,16 +211,17 @@ def window_summary(delta, wall_seconds: float) -> dict:
         kernels[kernel] = entry
     total_flops = sum(flops.values())
     total_bytes = sum(nbytes.values())
-    peak = peak_flops()
     out = {
         "kernels": kernels,
         "analytical_flops": total_flops,
         "analytical_bytes": total_bytes,
-        "peak_flops": peak,
     }
+    peak = peak_flops()
+    if peak:
+        out["peak_flops"] = peak
     if wall_seconds > 0 and total_flops > 0:
         achieved = total_flops / wall_seconds
         out["achieved_flop_s"] = achieved
-        if peak > 0:
+        if peak:
             out["roofline_utilization"] = achieved / peak
     return out

@@ -1,12 +1,9 @@
 """Live component health: a background monitor with a tiny state machine.
 
-Bench rounds 3-5 lost >14 h to 120 s device-probe timeouts that were only
-visible to a detached one-off script (the since-retired
-``transport_monitor_r5``, whose probe loop ``tools/healthd.py`` absorbed) —
-nothing inside the framework watched device health *while work ran*. This
-module closes that gap: a daemon :class:`HealthMonitor` thread polls a
-fixed set of components every ``TPU_ML_HEALTH_INTERVAL_S`` seconds and
-rolls the results into per-component states:
+Nothing else inside the framework watches device health *while work runs*.
+A daemon :class:`HealthMonitor` thread polls a fixed set of components every
+``TPU_ML_HEALTH_INTERVAL_S`` seconds and rolls the results into
+per-component states:
 
     OK (0) → DEGRADED (1) → FAILING (2)
 
@@ -15,14 +12,13 @@ Components and their evidence:
 - ``device``      — HBM watermark from ``memory_stats()`` gauges
   (:func:`telemetry.compilemon.sample_device_memory`): DEGRADED above
   ``TPU_ML_HEALTH_HBM_WATERMARK`` of ``bytes_limit``.
-- ``transport``   — a bounded-deadline liveness probe, generalizing the
-  retired ``transport_monitor_r5`` loop: ``inline`` (default) runs a cheap
-  in-process check on a throwaway thread; ``subprocess`` runs the full
-  :func:`utils.devicepolicy.probe_transport_subprocess` (repeatable even
-  when a probe wedges); ``off`` disables. Consecutive failures escalate
-  DEGRADED → FAILING after ``TPU_ML_HEALTH_FAILING_AFTER`` polls. The
-  inline probe passes the ``device.init`` fault gate, so a chaos plan's
-  injected hang exercises the timeout path end to end.
+- ``transport``   — a bounded-deadline liveness probe of the device runtime
+  from inside this process (the process that holds the chip is the only one
+  that can ask): ``inline`` (default) runs a cheap check on a throwaway
+  thread; ``off`` disables. Consecutive failures escalate DEGRADED →
+  FAILING after ``TPU_ML_HEALTH_FAILING_AFTER`` polls. The inline probe
+  passes the ``device.init`` fault gate, so a chaos plan's injected hang
+  exercises the timeout path end to end.
 - ``stream``      — streamed-fit heartbeat staleness: ``spark.ingest``
   stamps ``stream.last_beat`` per dispatch and ``stream.active`` around
   each stream; a beat older than ``TPU_ML_HEALTH_STALE_S`` while a stream
@@ -86,7 +82,7 @@ COMPONENTS = (
     "device", "transport", "stream", "workers", "resilience", "scheduler",
 )
 
-PROBE_MODES = ("off", "inline", "subprocess")
+PROBE_MODES = ("off", "inline")
 
 ADMISSION_POLICIES = ("off", "refuse", "degrade")
 
@@ -346,15 +342,8 @@ class HealthMonitor:
 
     def _run_probe(self) -> tuple[bool, str, float]:
         t0 = time.monotonic()
-        if self.probe_mode == "subprocess":
-            from spark_rapids_ml_tpu.utils import devicepolicy
-
-            ok, detail = devicepolicy.probe_transport_subprocess(
-                timeout=self.probe_timeout_s
-            )
-            return ok, detail, time.monotonic() - t0
-        # inline: the probe body runs on a throwaway daemon thread so a
-        # wedged call cannot stall the monitor loop past the deadline
+        # the probe body runs on a throwaway daemon thread so a hung call
+        # cannot stall the monitor loop past the deadline
         result: dict = {}
         done = threading.Event()
 

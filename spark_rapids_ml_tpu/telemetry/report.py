@@ -28,6 +28,7 @@ from typing import Any
 from spark_rapids_ml_tpu.telemetry import compilemon, costmodel, spans
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY, render_key
 from spark_rapids_ml_tpu.telemetry.timeline import TIMELINE
+from spark_rapids_ml_tpu.utils.config import enable_compilation_cache
 
 # v2: + fit_id (log↔report correlation) and overlap_fraction (H2D↔compute
 # overlap evidence from the streamed fold). v3: + cost_model (analytical
@@ -178,10 +179,11 @@ class _FitCapture:
 
 
 def begin_fit(estimator: str, uid: str = "") -> _FitCapture:
-    """Open a capture window: install the compile listeners and the
-    fit_id log filter (first call only), snapshot the registry and the
-    timeline watermark, mint a fit_id, and label subsequent spans with
-    the estimator name."""
+    """Open a capture window: place the compile cache and install the
+    compile listeners and the fit_id log filter (first call only), snapshot
+    the registry and the timeline watermark, mint a fit_id, and label
+    subsequent spans with the estimator name."""
+    enable_compilation_cache()
     compilemon.install_monitoring()
     spans.install_fit_id_filter()
     # with TPU_ML_HTTP_PORT set, the first fit brings up the /metrics +
@@ -430,6 +432,7 @@ class _TransformCapture:
 def begin_transform(transformer: str, uid: str = "") -> _TransformCapture:
     """Open a serve-side capture window: mirror of :func:`begin_fit` minting
     a ``transform_id`` instead of a ``fit_id``."""
+    enable_compilation_cache()
     compilemon.install_monitoring()
     spans.install_fit_id_filter()
     transform_id = uuid.uuid4().hex[:12]

@@ -20,13 +20,6 @@ module is tier 2 for the TPU build — process-level knobs read from
   O(chunk + n²) device memory instead of materializing the full resident
   array. Small data keeps the resident path — it is still fastest when it
   fits.
-- ``TPU_ML_COMPILE_CACHE``   (path, default ``~/.cache/spark_rapids_ml_tpu/
-  xla``; empty string disables) — persistent XLA compilation cache shared by
-  every process of a deployment. In-process executable reuse is handled by
-  the ``lru_cache``d program builders in ``parallel/``; this cache is what
-  saves the barrier-stage/executor WORKER processes (fresh interpreter per
-  job) and repeated driver runs from paying the multi-second XLA compile on
-  every fit.
 - ``TPU_ML_TELEMETRY_PATH``  (path, default ``''`` = disabled) — JSONL sink
   for per-fit telemetry reports (``telemetry.export``). Each completed
   ``fit()`` appends one ``fit_report`` record; render with
@@ -69,13 +62,15 @@ module is tier 2 for the TPU build — process-level knobs read from
   sets the ``spark_rapids_ml_tpu`` logger level at package import. The
   package attaches only a ``logging.NullHandler``; output routing stays the
   application's choice.
-- ``TPU_ML_PEAK_TFLOPS`` (float, default 197.0 = TPU v5e bf16 peak; read
-  directly by ``telemetry.costmodel``) — device peak for the cost model's
-  roofline-utilization denominator stamped into Fit/TransformReports.
-- ``TPU_ML_PERF_LEDGER_PATH`` (path, default ``PERF_LEDGER.jsonl`` next to
+- ``TPU_ML_PEAK_TFLOPS`` (float, default unset = looked up by
+  ``device_kind``; read directly by ``telemetry.costmodel``) — explicit
+  device peak for the cost model's roofline-utilization denominator stamped
+  into Fit/TransformReports. A device the table does not know gets none.
+- ``TPU_ML_PERF_LEDGER_PATH`` (path, default ``bench_history.jsonl`` next to
   ``bench.py``; empty string disables; read directly by ``bench.py``) —
-  persistent perf ledger each bench run appends its metrics + cost-model
-  numbers to; compared across runs by ``tools/perf_sentinel.py``.
+  history file each bench run appends its metrics + cost-model numbers to;
+  compared across runs by ``tools/perf_sentinel.py``. Never the driver's
+  ``PERF_LEDGER.jsonl``.
 - ``TPU_ML_PERF_SENTINEL`` (``1`` to enable; read directly by ``bench.py``)
   — after appending the ledger entry, the bench runs
   ``tools/perf_sentinel.py --strict`` on it and fails on regressions
@@ -171,56 +166,38 @@ class RuntimeConfig:
 
 
 _config: RuntimeConfig | None = None
-_compile_cache_enabled = False
+
+# <repo root>/.jax_cache: fixed, because the directory is part of what a
+# cache entry is found by — one that moves with $HOME, a pid or the time
+# never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
 
 
-def enable_compilation_cache() -> str | None:
-    """Point JAX at the persistent XLA compilation cache (idempotent).
+def enable_compilation_cache() -> str:
+    """The one rule for the persistent XLA compilation cache (idempotent).
 
-    Returns the cache directory, or None when disabled
-    (``TPU_ML_COMPILE_CACHE=''``) or when this JAX build rejects the
-    options. Safe to call before or after backend initialization; callers
-    invoke it lazily right before the first compile-heavy path (estimator
-    fits, SPMD workers) so importing the package stays side-effect free.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX took the directory when it
+    was imported, and nothing is set here (the same holds for a directory an
+    embedding application configured); otherwise the cache is
+    :data:`DEFAULT_COMPILE_CACHE_DIR`. Either way every compile is kept,
+    however short: the serve kernels compile in milliseconds and a fresh
+    process must still find them. Returns the directory in force.
+
+    Every entry point that compiles calls this first (estimator ``fit`` and
+    ``transform`` windows, worker plan functions, servable registration), so
+    the process's first compile already goes through the cache.
     """
-    global _compile_cache_enabled
-    cache_dir = os.environ.get(
-        knobs.COMPILE_CACHE.name,
-        os.path.join(
-            os.path.expanduser("~"), ".cache", "spark_rapids_ml_tpu", "xla"
-        ),
-    )
-    if not cache_dir:
-        return None
-    if _compile_cache_enabled:
-        return cache_dir
-    try:
-        import jax
+    import jax
 
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            # an embedding application (or the test harness) already chose a
-            # cache location — respect it
-            _compile_cache_enabled = True
-            return jax.config.jax_compilation_cache_dir
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except (ImportError, OSError, AttributeError, ValueError):
-        return None
-    _compile_cache_enabled = True
-    # Tuning knobs are best-effort per-knob: a JAX build that lacks or
-    # rejects one must not leave the just-applied cache dir looking like an
-    # external choice on the next call (half-applied-state trap).
-    for knob, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.5),
-        # cache regardless of backend: the CPU fallback deployments (worker
-        # ingestion processes, tests) recompile just as painfully
-        ("jax_persistent_cache_enable_xla_caches", "all"),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, ValueError):
-            pass
-    return cache_dir
+    if not jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 
 def get_config() -> RuntimeConfig:

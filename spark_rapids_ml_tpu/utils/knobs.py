@@ -46,10 +46,6 @@ _DECLARATIONS = (
     Knob("TPU_ML_STREAM_FIT_MAX_RESIDENT_BYTES", "int", str(1 << 31),
          "device-footprint cutover above which DataFrame fits stream "
          "chunk-wise instead of materializing", "utils.config"),
-    Knob("TPU_ML_COMPILE_CACHE", "path",
-         "~/.cache/spark_rapids_ml_tpu/xla",
-         "persistent XLA compilation cache dir (empty string disables)",
-         "utils.config"),
     Knob("TPU_ML_LOG_LEVEL", "str", "",
          "package logger level (name or number) set at import",
          "spark_rapids_ml_tpu"),
@@ -66,9 +62,10 @@ _DECLARATIONS = (
     Knob("TPU_ML_PROGRESS", "float", "",
          "emit a live streamed-fit heartbeat to stderr every N seconds "
          "(unset = off)", "spark.ingest"),
-    Knob("TPU_ML_PEAK_TFLOPS", "float", "197.0",
-         "device peak for the cost model's roofline denominator (default "
-         "= TPU v5e bf16)", "telemetry.costmodel"),
+    Knob("TPU_ML_PEAK_TFLOPS", "float", "",
+         "explicit device peak for the cost model's roofline denominator "
+         "(unset = looked up by device_kind; an unknown device gets no "
+         "roofline figure)", "telemetry.costmodel"),
     # -- resilience ---------------------------------------------------------
     Knob("TPU_ML_RETRY_MAX_ATTEMPTS", "int", "4",
          "shared retry-policy attempt budget per call site", "utils.config"),
@@ -145,20 +142,12 @@ _DECLARATIONS = (
          "extra comma-separated env vars scrubbed from cpu-policy worker "
          "environments", "utils.devicepolicy"),
     # -- bench / perf ledger ------------------------------------------------
-    Knob("TPU_ML_PERF_LEDGER_PATH", "path", "PERF_LEDGER.jsonl",
-         "persistent perf ledger bench runs append to (empty disables)",
-         "bench.py"),
+    Knob("TPU_ML_PERF_LEDGER_PATH", "path", "bench_history.jsonl",
+         "history file bench runs append to (empty disables); never the "
+         "driver's PERF_LEDGER.jsonl", "bench.py"),
     Knob("TPU_ML_PERF_SENTINEL", "flag", "",
          "`1`: bench runs tools/perf_sentinel.py --strict after appending "
          "the ledger entry", "bench.py"),
-    Knob("TPU_ML_BENCH_PROBE_WINDOW_S", "float", "3600",
-         "window the bench preamble waits for a healthy device transport",
-         "bench.py"),
-    Knob("TPU_ML_BENCH_PROBE_TIMEOUT", "float", "120",
-         "per-attempt timeout of the bench device probe", "bench.py"),
-    Knob("TPU_ML_OPPORTUNISTIC_MAX_AGE_S", "float", str(14 * 3600),
-         "max age of an opportunistic bench harvest before it is ignored",
-         "bench.py"),
     # -- autotune (spark_rapids_ml_tpu.autotune) ----------------------------
     Knob("TPU_ML_AUTOTUNE", "enum", "cache",
          "`off`/`cache`/`search` tuner mode: ignore the tuning cache, "
@@ -183,10 +172,6 @@ _DECLARATIONS = (
          "for streamed IVF index builds (0 = train on the full stream)",
          "ann.index"),
     # -- warm-path serving runtime (spark_rapids_ml_tpu.serving) ------------
-    Knob("TPU_ML_SERVE_COMPILE_CACHE_DIR", "path", "",
-         "persistent XLA cache dir for AOT-compiled serve kernels (fresh "
-         "processes warm from disk; empty = share TPU_ML_COMPILE_CACHE)",
-         "serving.registry"),
     Knob("TPU_ML_SERVE_MIN_BUCKET", "int", "8",
          "serve-path row-bucket floor (smaller than the fit-path "
          "TPU_ML_MIN_BUCKET so single-row scoring pads less)",
@@ -225,7 +210,7 @@ _DECLARATIONS = (
     Knob("TPU_ML_SERVE_FLEET_REPLICAS", "int", "0",
          "replica count of the multi-process serve fleet (0 = fleet off; "
          "each replica is a UDS server process with its own AOT cache "
-         "warmed from TPU_ML_SERVE_COMPILE_CACHE_DIR)", "serving.fleet"),
+         "warmed from the shared compile cache)", "serving.fleet"),
     Knob("TPU_ML_SERVE_FLEET_SOCKET_DIR", "path", "",
          "directory for fleet replica + router UDS sockets (empty = a "
          "fresh tempdir per fleet; must be short enough for AF_UNIX's "
@@ -265,33 +250,12 @@ _DECLARATIONS = (
          "post-swap probation window: an SLO burn inside it rolls back to "
          "the prior version (which stays HBM-resident until probation "
          "clears)", "refresh.daemon"),
-    # -- transport monitor / health daemon (tools/healthd.py) ---------------
-    Knob("TPU_ML_MONITOR_BENCH_OUT", "path", "BENCH_OPPORTUNISTIC_r05.json",
-         "opportunistic bench output file (relative to the repo)",
-         "tools/healthd.py"),
-    Knob("TPU_ML_MONITOR_DRIFT_OUT", "path", "BENCH_DRIFT_r05.jsonl",
-         "transport-monitor drift log (relative to the repo)",
-         "tools/healthd.py"),
-    Knob("TPU_ML_MONITOR_INTERVAL_S", "float", "600",
-         "seconds between transport probes", "tools/healthd.py"),
-    Knob("TPU_ML_MONITOR_PROBE_TIMEOUT_S", "float", "120",
-         "per-probe timeout of the transport monitor",
-         "tools/healthd.py"),
-    Knob("TPU_ML_MONITOR_WINDOW_S", "float", str(11.5 * 3600),
-         "total monitoring window before the monitor gives up",
-         "tools/healthd.py"),
-    Knob("TPU_ML_MONITOR_BENCH_RUNS", "int", "5",
-         "bench repetitions per opportunistic harvest",
-         "tools/healthd.py"),
-    Knob("TPU_ML_MONITOR_BENCH_TIMEOUT_S", "float", "3600",
-         "timeout of one opportunistic bench run",
-         "tools/healthd.py"),
     # -- live health monitor (telemetry.health) -----------------------------
     Knob("TPU_ML_HEALTH_INTERVAL_S", "float", "5.0",
          "seconds between HealthMonitor poll cycles", "telemetry.health"),
     Knob("TPU_ML_HEALTH_PROBE", "enum", "inline",
-         "`off`/`inline`/`subprocess` transport liveness probe mode of the "
-         "health monitor", "telemetry.health"),
+         "`off`/`inline` device liveness probe mode of the health monitor",
+         "telemetry.health"),
     Knob("TPU_ML_HEALTH_PROBE_TIMEOUT_S", "float", "20.0",
          "deadline of one health-monitor liveness probe", "telemetry.health"),
     Knob("TPU_ML_HEALTH_HBM_WATERMARK", "float", "0.92",
@@ -334,7 +298,6 @@ MAX_WORKERS = KNOBS["TPU_ML_MAX_WORKERS"]
 TASK_RETRIES = KNOBS["TPU_ML_TASK_RETRIES"]
 DEFAULT_PRECISION = KNOBS["TPU_ML_DEFAULT_PRECISION"]
 STREAM_FIT_MAX_RESIDENT_BYTES = KNOBS["TPU_ML_STREAM_FIT_MAX_RESIDENT_BYTES"]
-COMPILE_CACHE = KNOBS["TPU_ML_COMPILE_CACHE"]
 LOG_LEVEL = KNOBS["TPU_ML_LOG_LEVEL"]
 TELEMETRY_PATH = KNOBS["TPU_ML_TELEMETRY_PATH"]
 TIMELINE_PATH = KNOBS["TPU_ML_TIMELINE_PATH"]
@@ -366,16 +329,12 @@ WORKER_PROBE_TIMEOUT = KNOBS["TPU_ML_WORKER_PROBE_TIMEOUT"]
 WORKER_SCRUB_VARS = KNOBS["TPU_ML_WORKER_SCRUB_VARS"]
 PERF_LEDGER_PATH = KNOBS["TPU_ML_PERF_LEDGER_PATH"]
 PERF_SENTINEL = KNOBS["TPU_ML_PERF_SENTINEL"]
-BENCH_PROBE_WINDOW_S = KNOBS["TPU_ML_BENCH_PROBE_WINDOW_S"]
-BENCH_PROBE_TIMEOUT = KNOBS["TPU_ML_BENCH_PROBE_TIMEOUT"]
-OPPORTUNISTIC_MAX_AGE_S = KNOBS["TPU_ML_OPPORTUNISTIC_MAX_AGE_S"]
 AUTOTUNE = KNOBS["TPU_ML_AUTOTUNE"]
 AUTOTUNE_TRIALS = KNOBS["TPU_ML_AUTOTUNE_TRIALS"]
 TUNING_CACHE_PATH = KNOBS["TPU_ML_TUNING_CACHE_PATH"]
 PRECISION_POLICY = KNOBS["TPU_ML_PRECISION_POLICY"]
 ANN_CAP_PERCENTILE = KNOBS["TPU_ML_ANN_CAP_PERCENTILE"]
 ANN_SAMPLE_ROWS = KNOBS["TPU_ML_ANN_SAMPLE_ROWS"]
-SERVE_COMPILE_CACHE_DIR = KNOBS["TPU_ML_SERVE_COMPILE_CACHE_DIR"]
 SERVE_MIN_BUCKET = KNOBS["TPU_ML_SERVE_MIN_BUCKET"]
 SERVE_MAX_BATCH_ROWS = KNOBS["TPU_ML_SERVE_MAX_BATCH_ROWS"]
 SERVE_MAX_DELAY_US = KNOBS["TPU_ML_SERVE_MAX_DELAY_US"]
@@ -395,13 +354,6 @@ REFRESH_CHECKPOINT_DIR = KNOBS["TPU_ML_REFRESH_CHECKPOINT_DIR"]
 SWAP_SHADOW_ROWS = KNOBS["TPU_ML_SWAP_SHADOW_ROWS"]
 SWAP_SHADOW_TOLERANCE = KNOBS["TPU_ML_SWAP_SHADOW_TOLERANCE"]
 SWAP_PROBATION_S = KNOBS["TPU_ML_SWAP_PROBATION_S"]
-MONITOR_BENCH_OUT = KNOBS["TPU_ML_MONITOR_BENCH_OUT"]
-MONITOR_DRIFT_OUT = KNOBS["TPU_ML_MONITOR_DRIFT_OUT"]
-MONITOR_INTERVAL_S = KNOBS["TPU_ML_MONITOR_INTERVAL_S"]
-MONITOR_PROBE_TIMEOUT_S = KNOBS["TPU_ML_MONITOR_PROBE_TIMEOUT_S"]
-MONITOR_WINDOW_S = KNOBS["TPU_ML_MONITOR_WINDOW_S"]
-MONITOR_BENCH_RUNS = KNOBS["TPU_ML_MONITOR_BENCH_RUNS"]
-MONITOR_BENCH_TIMEOUT_S = KNOBS["TPU_ML_MONITOR_BENCH_TIMEOUT_S"]
 HEALTH_INTERVAL_S = KNOBS["TPU_ML_HEALTH_INTERVAL_S"]
 HEALTH_PROBE = KNOBS["TPU_ML_HEALTH_PROBE"]
 HEALTH_PROBE_TIMEOUT_S = KNOBS["TPU_ML_HEALTH_PROBE_TIMEOUT_S"]

@@ -20,7 +20,6 @@ def test_bench_smoke_json_contract(tmp_path):
     env = dict(
         os.environ,
         JAX_PLATFORMS="cpu",
-        JAX_COMPILATION_CACHE_DIR="/tmp/jax_test_cache",
         TPU_ML_PERF_LEDGER_PATH=ledger,
         TPU_ML_PERF_SENTINEL="1",  # the bench gates itself on the sentinel
     )

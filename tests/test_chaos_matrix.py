@@ -7,7 +7,7 @@ not behind the slow marker.
 Matrix: device OOM (chunk bisection), transient I/O (retry-in-place), hang
 (bounded fold.wait + FoldHangTimeout diagnosis), preemption (durable
 checkpoint + bitwise resume), non-finite rows (raise/skip policy),
-collective blips (finalize retry), device-init failure (CPU degradation).
+collective blips (finalize retry), device-init failure (raises).
 """
 
 import os
@@ -252,20 +252,25 @@ class TestCollectiveRetry:
         assert d.counter("retry.attempts", site="collective") == 1
 
 
-class TestDeviceInitDegradation:
-    def test_nonfatal_init_failure_degrades(self, monkeypatch, snap):
+class TestDeviceInit:
+    @pytest.mark.parametrize(
+        "kind,exc",
+        [
+            ("io", faults.InjectedTransientIOError),
+            ("preempt", faults.InjectedPreemption),
+        ],
+    )
+    def test_init_failure_raises_never_degrades(
+        self, monkeypatch, snap, kind, exc
+    ):
+        """A mesh that cannot be created fails the fit, whatever the class
+        of the error: nothing swaps the device for another one quietly."""
         from spark_rapids_ml_tpu.spark import estimators as E
 
-        monkeypatch.setenv(faults.FAULT_PLAN_VAR, "device.init:io:1")
-        assert E._mesh_or_fallback() is None
-        assert snap.delta().counter("degraded.cpu_fallback") == 1
-
-    def test_fatal_init_failure_propagates(self, monkeypatch):
-        from spark_rapids_ml_tpu.spark import estimators as E
-
-        monkeypatch.setenv(faults.FAULT_PLAN_VAR, "device.init:preempt:1")
-        with pytest.raises(faults.InjectedPreemption):
+        monkeypatch.setenv(faults.FAULT_PLAN_VAR, f"device.init:{kind}:1")
+        with pytest.raises(exc):
             E._mesh_or_fallback()
+        assert snap.delta().counter("degraded.cpu_fallback") == 0
 
     def test_healthy_init_returns_mesh(self):
         from spark_rapids_ml_tpu.spark import estimators as E
@@ -917,7 +922,7 @@ class TestFleetSwapChaos:
             socket_dir=str(tmp_path / "sock"),
             bucket_list=(8,),
             extra_env={
-                "TPU_ML_SERVE_COMPILE_CACHE_DIR": str(tmp_path / "cache")
+                "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache")
             },
         ).start()
         stop = threading.Event()
@@ -1027,7 +1032,7 @@ class TestFleetTraceChaos:
             socket_dir=str(tmp_path / "sock"),
             bucket_list=(8,),
             extra_env={
-                "TPU_ML_SERVE_COMPILE_CACHE_DIR": str(tmp_path / "cache"),
+                "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
                 "TPU_ML_TRACE_SAMPLE": sample,
             },
         ).start()

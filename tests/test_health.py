@@ -3,7 +3,7 @@ telemetry.httpd).
 
 Covers the ISSUE-8 acceptance scenarios without hardware:
 
-- an injected ``device.init`` hang wedges the inline transport probe past
+- an injected ``device.init`` hang holds the inline liveness probe past
   its deadline → the component escalates to FAILING and ``/healthz``
   flips 200 → 503;
 - the sliding-window SLO engine breaches only after the burn streak and
@@ -156,6 +156,16 @@ class TestSloEngine:
 
 
 class TestHealthMonitor:
+    def test_probe_runs_in_this_process_or_not_at_all(self, monkeypatch):
+        """A child cannot ask about a chip its parent holds: the probe modes
+        are ``inline`` and ``off``, from the knob as from the argument."""
+        assert health.PROBE_MODES == ("off", "inline")
+        with pytest.raises(ValueError, match="must be one of"):
+            health.HealthMonitor(probe_mode="subprocess")
+        monkeypatch.setenv(health.PROBE_VAR, "subprocess")
+        with pytest.raises(ValueError, match="must be one of"):
+            health.HealthMonitor()
+
     def test_all_ok_rollup(self):
         mon = health.HealthMonitor(
             interval_s=60.0, probe_mode="inline",

@@ -236,7 +236,10 @@ class TestMapInArrowBoundary:
         pids2 = {r.pid for r in df.mapInArrow(tag_pid, schema).collect()}
         assert pids1 == pids2 and len(pids1) == 1
 
-    def test_two_workers_parallel(self):
+    def test_two_workers_parallel(self, monkeypatch):
+        # no hedging: on a loaded machine the first worker is warm and idle
+        # before its sibling has started, and would take the sibling's task
+        monkeypatch.setenv("TPU_ML_HEDGE_FACTOR", "0")
         with LocalSparkSession(parallelism=4, num_workers=2) as s:
             df, _ = _features_df(s, rows=40)
 

@@ -24,7 +24,6 @@ def session():
         worker_env={
             "JAX_PLATFORMS": "cpu",
             "JAX_ENABLE_X64": "1",
-            "JAX_COMPILATION_CACHE_DIR": "/tmp/jax_test_cache",
         },
     )
     yield s

@@ -1,17 +1,7 @@
-"""The health daemon's opportunistic harvest glue (tools/healthd.py).
-
-The harvest path (ported from the retired-and-deleted
-tools/transport_monitor_r5.py) only executes when the accelerator
-transport heals — which may
-never happen in a round. These tests drive the glue with a stubbed bench
-runner so the file contracts (drift log lines, the stamped
-BENCH_OPPORTUNISTIC payload bench.py's fallback consumes, the re-wedge
-retreat) are verified without a chip, plus the --once exit-code contract
-CI gates on.
-"""
+"""The health daemon CLI (tools/healthd.py): the --once exit-code contract
+CI gates on."""
 
 import importlib.util
-import json
 import sys
 from pathlib import Path
 
@@ -21,88 +11,15 @@ _TOOLS = Path(__file__).resolve().parent.parent / "tools"
 
 
 @pytest.fixture
-def monitor(tmp_path, monkeypatch):
+def monitor():
     spec = importlib.util.spec_from_file_location(
         "healthd_under_test", _TOOLS / "healthd.py"
     )
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
-    monkeypatch.setattr(mod, "LOG_PATH", str(tmp_path / "log.jsonl"))
-    monkeypatch.setattr(mod, "BENCH_OUT", str(tmp_path / "opportunistic.json"))
-    monkeypatch.setattr(mod, "DRIFT_OUT", str(tmp_path / "drift.jsonl"))
-    monkeypatch.setattr(mod, "N_BENCH_RUNS", 3)
     yield mod
     del sys.modules[spec.name]
-
-
-def _fake_record(run, rc, value=0.0171):
-    payload = None
-    if rc == 0:
-        payload = {
-            "metric": "pca_fit_uncentered_device_wall_clock_2Mx512_k50",
-            "value": value,
-            "unit": "seconds",
-            "vs_baseline": 5.38,
-        }
-    return {
-        "t": "2026-01-01T00:00:00+00:00",
-        "elapsed_s": 1.0,
-        "run": run,
-        "rc": rc,
-        "took_s": 12.3,
-        "json": payload,
-    }
-
-
-class TestHarvestGlue:
-    def test_harvest_writes_stamped_primary_and_drift_series(
-        self, monitor, monkeypatch
-    ):
-        values = iter([0.017, 0.018, 0.016])
-        monkeypatch.setattr(
-            monitor,
-            "run_bench",
-            lambda i: _fake_record(i, 0, next(values)),
-        )
-        assert monitor.harvest() is True
-        primary = json.loads(Path(monitor.BENCH_OUT).read_text())
-        # the FIRST complete run is the primary, stamped for bench.py's
-        # snapshot-time fallback age gate
-        assert primary["value"] == 0.017
-        assert isinstance(primary["harvested_at_unix"], float)
-        assert "harvested_at" in primary
-        drift = [
-            json.loads(line)
-            for line in Path(monitor.DRIFT_OUT).read_text().splitlines()
-        ]
-        assert [d["run"] for d in drift] == [1, 2, 3]
-        assert [d["json"]["value"] for d in drift] == [0.017, 0.018, 0.016]
-
-    def test_rewedge_mid_harvest_retreats_without_primary(
-        self, monitor, monkeypatch
-    ):
-        rcs = iter([1, 1, 1])
-        monkeypatch.setattr(
-            monitor, "run_bench", lambda i: _fake_record(i, next(rcs))
-        )
-        assert monitor.harvest() is False
-        assert not Path(monitor.BENCH_OUT).exists()
-        drift = Path(monitor.DRIFT_OUT).read_text().splitlines()
-        assert len(drift) == 2  # gave up after the second failure
-
-    def test_first_failure_then_success_still_lands_primary(
-        self, monitor, monkeypatch
-    ):
-        seq = iter([(1, 1), (2, 0), (3, 0)])
-
-        def fake(i):
-            run, rc = next(seq)
-            return _fake_record(run, rc)
-
-        monkeypatch.setattr(monitor, "run_bench", fake)
-        assert monitor.harvest() is True
-        assert json.loads(Path(monitor.BENCH_OUT).read_text())["value"] == 0.0171
 
 
 class TestExitCodes:
@@ -125,11 +42,3 @@ class TestExitCodes:
         breached = {"state": "OK", "slo": {"total_breaches": 2}}
         assert monitor._exit_code(breached, strict=False) == 0
         assert monitor._exit_code(breached, strict=True) == 1
-
-
-def test_transport_monitor_shim_is_retired():
-    """The deprecation shim had one release of grace and is now deleted;
-    only healthd remains. (Resurrecting the old entry point would hide
-    the migration from anyone still scripting against it.)"""
-    assert not (_TOOLS / "transport_monitor_r5.py").exists()
-    assert (_TOOLS / "healthd.py").exists()

@@ -131,12 +131,13 @@ class TestMeshHelpers:
         with pytest.raises(ValueError, match="feat=3"):
             M.create_hybrid_mesh(feat=3, slice_groups=[[0, 1, 2, 3]])
 
-    def test_shard_map_shim_decorator_form(self, mesh8):
+    def test_shard_map_decorator_form(self, mesh8):
+        # the installed jax.shard_map, called the way parallel/ calls it
         import jax
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        @M.shard_map(mesh=mesh8, in_specs=P(M.DATA_AXIS), out_specs=P(), check_rep=False)
+        @jax.shard_map(mesh=mesh8, in_specs=P(M.DATA_AXIS), out_specs=P(), check_vma=False)
         def total(v):
             return lax.psum(v.sum(), M.DATA_AXIS)
 

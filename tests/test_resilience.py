@@ -64,14 +64,6 @@ class TestPlanParsing:
         assert faults.inject("anything", x) is x
 
 
-class _FakeXlaRuntimeError(Exception):
-    pass
-
-
-# classify() recognizes XlaRuntimeError structurally by class name
-_FakeXlaRuntimeError.__name__ = "XlaRuntimeError"
-
-
 class TestClassify:
     @pytest.mark.parametrize(
         "exc,want",
@@ -103,7 +95,20 @@ class TestClassify:
         ],
     )
     def test_xla_status_families(self, msg, want):
-        assert R.classify(_FakeXlaRuntimeError(msg)) is want
+        from jax.errors import JaxRuntimeError
+
+        assert R.classify(JaxRuntimeError(msg)) is want
+
+    def test_a_real_device_oom_is_resource_exhausted(self):
+        """The error the installed JAX actually raises, not a stand-in: an
+        allocation no machine can satisfy, under jit."""
+        import jax
+        import jax.numpy as jnp
+        from jax.errors import JaxRuntimeError
+
+        with pytest.raises(JaxRuntimeError) as err:
+            jax.jit(lambda: jnp.zeros((1 << 40,), jnp.float32))().block_until_ready()
+        assert R.classify(err.value) is R.ErrorClass.RESOURCE_EXHAUSTED
 
 
 class TestRetryPolicy:

@@ -67,7 +67,7 @@ def live_fleet(fitted_models, tmp_path_factory):
         replicas=2,
         socket_dir=str(tmp_path_factory.mktemp("fleet_sock")),
         bucket_list=(8,),
-        extra_env={"TPU_ML_SERVE_COMPILE_CACHE_DIR": cache_dir},
+        extra_env={"JAX_COMPILATION_CACHE_DIR": cache_dir},
     ).start()
     yield x, fleet
     fleet.stop()

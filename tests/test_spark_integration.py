@@ -98,7 +98,6 @@ def backend(request):
             worker_env={
                 "JAX_PLATFORMS": "cpu",
                 "JAX_ENABLE_X64": "1",
-                "JAX_COMPILATION_CACHE_DIR": "/tmp/jax_test_cache",
             },
         )
         yield Backend("localspark", session, LT, LF)

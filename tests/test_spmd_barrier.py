@@ -25,7 +25,6 @@ def session():
         parallelism=4,
         worker_env={
             "JAX_ENABLE_X64": "1",
-            "JAX_COMPILATION_CACHE_DIR": "/tmp/jax_test_cache",
         },
     )
     yield s

@@ -116,7 +116,9 @@ class TestTransformReport:
         fit_cm = model.fit_report.cost_model
         assert "linalg.gram_stats" in fit_cm.get("kernels", {})
         assert fit_cm["analytical_flops"] > 0
-        assert fit_cm["peak_flops"] > 0
+        # the CPU is not in the peak table: no roofline figure, not the v5e's
+        assert "peak_flops" not in fit_cm
+        assert "roofline_utilization" not in fit_cm
 
         model.transform(df).toArrow()
         cm = model.transform_report.cost_model
@@ -126,8 +128,7 @@ class TestTransformReport:
         assert k["flops"] > 0 and k["bytes_accessed"] > 0
         assert cm["analytical_flops"] >= k["flops"] * 3 * (1 - 1e-6)
         assert cm["analytical_bytes"] > 0
-        if "roofline_utilization" in cm:
-            assert 0 < cm["roofline_utilization"] < 1
+        assert "roofline_utilization" not in cm
 
     def test_transform_timeline_exported_with_transform_id(
         self, pca_df_and_model, tmp_path
@@ -190,11 +191,12 @@ class TestTransformIdLogFilter:
 
 
 class TestWindowSummaryUnit:
-    def test_counter_driven_rollup(self):
+    def test_counter_driven_rollup(self, monkeypatch):
         """window_summary needs only the costmodel.* counters — the shape
         of worker-side captures arriving via the telemetry trailer."""
         from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 
+        monkeypatch.setenv("TPU_ML_PEAK_TFLOPS", "2.5")  # explicit override
         snap = REGISTRY.snapshot()
         REGISTRY.counter_inc("costmodel.calls", 2, kernel="k")
         REGISTRY.counter_inc("costmodel.flops", 200.0, kernel="k")
@@ -206,9 +208,19 @@ class TestWindowSummaryUnit:
         )
         assert cm["analytical_flops"] == 200.0
         assert cm["achieved_flop_s"] == 100.0
-        assert cm["roofline_utilization"] == pytest.approx(
-            100.0 / cm["peak_flops"]
+        assert cm["peak_flops"] == 2.5e12
+        assert cm["roofline_utilization"] == pytest.approx(100.0 / 2.5e12)
+
+    def test_peak_is_keyed_by_device_kind(self, monkeypatch):
+        """v5e is in the table; a device that is not gets no peak."""
+        import jax
+
+        monkeypatch.delenv("TPU_ML_PEAK_TFLOPS", raising=False)
+        assert costmodel.PEAK_TFLOPS_BY_DEVICE_KIND["TPU v5 lite"] == 197.0
+        assert jax.devices()[0].device_kind not in (
+            costmodel.PEAK_TFLOPS_BY_DEVICE_KIND
         )
+        assert costmodel.peak_flops() is None
 
     def test_empty_window_is_empty_dict(self):
         from spark_rapids_ml_tpu.telemetry.registry import REGISTRY

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Bench-history regression sentinel over PERF_LEDGER.jsonl.
+"""Bench-history regression sentinel over bench_history.jsonl.
 
 ``bench.py`` appends one ``perf_ledger`` record per run (every emitted
 metric as ``name -> {value, unit}`` plus the analytical cost-model
@@ -9,8 +9,8 @@ preceding ``--last N`` entries, metric by metric, and flags any move beyond
 the unit (``rows/s`` up is good, ``seconds`` up is bad), so one rule covers
 throughputs, latencies and accuracy bars alike::
 
-    python tools/perf_sentinel.py PERF_LEDGER.jsonl            # report
-    python tools/perf_sentinel.py PERF_LEDGER.jsonl --strict   # CI gate
+    python tools/perf_sentinel.py bench_history.jsonl            # report
+    python tools/perf_sentinel.py bench_history.jsonl --strict   # CI gate
 
 ``--strict`` exits 2 on any regression, which is how ``bench --smoke``
 becomes a perf gate (``TPU_ML_PERF_SENTINEL=1`` makes the bench invoke this
@@ -181,7 +181,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="Flag bench regressions against the perf-ledger history"
     )
-    ap.add_argument("path", help="PERF_LEDGER.jsonl (appended by bench.py)")
+    ap.add_argument("path", help="bench_history.jsonl (appended by bench.py)")
     ap.add_argument(
         "--last", type=int, default=DEFAULT_LAST, metavar="N",
         help=f"history window: median of the last N prior entries "

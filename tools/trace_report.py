@@ -17,7 +17,7 @@ that turn the numbers into a diagnosis:
   pipeline is NOT overlapping H2D with compute (the terminal block is
   eating what double-buffering should hide).
 - compile seconds > 50% of fit wall ⇒ compile-dominated fit (check the
-  persistent cache, TPU_ML_COMPILE_CACHE, and shape-bucketing).
+  persistent compile cache directory and shape-bucketing).
 - zero rows ingested with nonzero wall ⇒ the fit never saw the data path
   this report instruments (fine for array fits fed device arrays; worth a
   look for DataFrame fits).
@@ -33,7 +33,7 @@ that turn the numbers into a diagnosis:
 - backend compiles far exceeding the distinct cost-model kernel count ⇒
   recompile storm: static-shape bucketing is not holding, so the same
   logical kernels keep recompiling per shape (check TPU_ML_MIN_BUCKET and
-  TPU_ML_COMPILE_CACHE).
+  the compile cache directory).
 - ``scheduler.hedge`` count > 20% of ``scheduler.tasks`` ⇒ hedge storm:
   speculative duplicates are no longer the exception — the hedge
   threshold is mis-tuned for this workload or most partitions are
@@ -115,7 +115,7 @@ def check_anomalies(rec: dict) -> list[str]:
     if wall > 0 and compile_s > 0.5 * wall:
         out.append(
             f"compile-dominated fit: {_fmt_s(compile_s)} of {_fmt_s(wall)} "
-            "wall went to XLA compiles (check TPU_ML_COMPILE_CACHE and that "
+            "wall went to XLA compiles (check the compile cache directory and that "
             "input shapes hit the row buckets)"
         )
     if wall > 0 and not rec.get("rows_ingested"):
@@ -188,7 +188,7 @@ def _recompile_storm(rec: dict) -> str | None:
             f"recompile storm: {count:g} backend compiles for "
             f"{len(kernels)} distinct cost-model kernel(s) — the same "
             "logical kernels are recompiling per input shape (check "
-            "TPU_ML_MIN_BUCKET row-bucketing and TPU_ML_COMPILE_CACHE; "
+            "TPU_ML_MIN_BUCKET row-bucketing and the compile cache directory; "
             "if a code path builds jax.jit programs per call, "
             "`python -m tools.tpulint` rule TPL003 finds it statically)"
         )
