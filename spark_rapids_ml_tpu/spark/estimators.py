@@ -318,13 +318,16 @@ class SparkPCA(_HasDistribution, PCA):
             pc, ev = L.pca_fit_from_cov(
                 cov, k, solver=self.getOrDefault("solver")
             )
-        model = SparkPCAModel(
-            uid=self.uid,
-            pc=np.asarray(pc),
-            explainedVariance=np.asarray(ev),
-            mean=None if mean is None else np.asarray(mean),
-            std=None if std is None else np.asarray(std),
-        )
+        with trace_range("model.to_host"):
+            # the host may wait here for the eager decomposition's last
+            # programs: the eigh span closes once they are enqueued
+            model = SparkPCAModel(
+                uid=self.uid,
+                pc=np.asarray(pc),
+                explainedVariance=np.asarray(ev),
+                mean=None if mean is None else np.asarray(mean),
+                std=None if std is None else np.asarray(std),
+            )
         return self._copyValues(model)
 
     def _fit_svd(

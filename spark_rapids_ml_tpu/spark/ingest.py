@@ -44,7 +44,6 @@ from typing import Any, Iterator
 
 import numpy as np
 
-from spark_rapids_ml_tpu.telemetry import costmodel
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 from spark_rapids_ml_tpu.telemetry.timeline import TIMELINE
 from spark_rapids_ml_tpu.utils import columnar, knobs
@@ -620,9 +619,13 @@ def stream_fold(
     a jitted step with ``donate_argnums=0`` (ops.linalg.gram_fold_step and
     friends), whose call returns the moment it is dispatched — so while
     chunk i's fold executes on the MXU, the host is already extracting and
-    ``device_put``-ing chunk i+1. Each phase is traced
-    (``ingest.chunk`` / ``fold.dispatch`` / ``fold.wait``,
-    telemetry.metrics()) so the overlap is observable.
+    ``device_put``-ing chunk i+1. Each phase is traced, so the overlap is
+    observable and the host's seconds have names (telemetry.metrics()):
+    ``ingest.chunk`` (the pull), ``ingest.scan`` (the non-finite check),
+    ``ingest.stage`` (the copy into the staging buffer), ``fold.dispatch``
+    with ``h2d.put`` and ``fold.enqueue`` inside it, and ``fold.wait``;
+    ``fold.input_in_flight`` counts the chunks whose transfer had not
+    landed when their fold was enqueued.
 
     ``source`` is either a DataFrame-shaped object (localspark / pyspark —
     drained via the same strategy-gated ``_iter_chunks`` the resident
@@ -727,8 +730,8 @@ def stream_fold(
     def timed_chunks():
         it = chunks()
         while True:
-            # host-side extraction span; the staging memcpy below is noise
-            # next to the DataFrame pull this times
+            # the pull of the next batch from the source alone; the scan and
+            # the staging copy have their own spans (ingest.scan/.stage)
             with trace_range("ingest.chunk"):
                 try:
                     item = next(it)
@@ -859,17 +862,22 @@ def stream_fold(
             # inject BEFORE the donated fold consumes its buffers, so the
             # carry is still valid when the retry re-enters
             faults.inject("fold.dispatch")
-            xd = put(xb)
-            wd = put(wb)
+            with trace_range("h2d.put"):
+                xd = put(xb)
+                wd = put(wb)
+                yd = put(yb) if yb is not None else None
             nbytes = xb.nbytes + wb.nbytes
             if yb is not None:
-                yd = put(yb)
                 nbytes += yb.nbytes
-                costmodel.capture("stream.fold_step", fold_fn, carry, xd, yd, wd)
-                carry = fold_fn(carry, xd, yd, wd)
-            else:
-                costmodel.capture("stream.fold_step", fold_fn, carry, xd, wd)
-                carry = fold_fn(carry, xd, wd)
+            # the fold's device time holds a wait for its chunk's DMA when
+            # the chunk has not landed by now (is_ready: no sync)
+            if hasattr(xd, "is_ready") and not xd.is_ready():
+                REGISTRY.counter_inc("fold.input_in_flight")
+            with trace_range("fold.enqueue"):
+                if yb is not None:
+                    carry = fold_fn(carry, xd, yd, wd)
+                else:
+                    carry = fold_fn(carry, xd, wd)
         if busy:
             overlapped += 1
         max_put = max(max_put, nbytes)
@@ -917,8 +925,11 @@ def stream_fold(
         dispatch_buffers(x_buf, y_buf if want_y else None, w_buf)
         REGISTRY.gauge_set("stream.last_beat", time.monotonic())
         # never reuse a put buffer: device_put of a host ndarray may alias
-        # rather than copy on some backends (stream_to_mesh rationale)
-        x_buf, y_buf, w_buf = fresh()
+        # rather than copy on some backends (stream_to_mesh rationale). The
+        # new buffer's page faults (and the old one's release) are booked
+        # with the copy that causes them.
+        with trace_range("ingest.stage"):
+            x_buf, y_buf, w_buf = fresh()
         fill = 0
 
     try:
@@ -954,34 +965,39 @@ def stream_fold(
                 policy=policy,
                 retry_on=transient_only,
             )
-            if nonfinite != "allow" and not (
-                # scalar pre-check keeps the all-finite fast path off the
-                # per-row mask allocation
-                np.isfinite(xc).all()
-                and (yc is None or np.isfinite(yc).all())
-                and (wc is None or np.isfinite(wc).all())
-            ):
-                bad = ~np.isfinite(xc).all(axis=1)
-                if yc is not None:
-                    bad |= ~np.isfinite(yc)
-                if wc is not None:
-                    bad |= ~np.isfinite(wc)
-                n_bad = int(bad.sum())
-                if n_bad:
-                    if nonfinite == "raise":
-                        raise ValueError(
-                            f"{n_bad} non-finite input row(s) in a streamed "
-                            "chunk; set TPU_ML_NONFINITE_POLICY=skip to "
-                            "drop and count them instead"
-                        )
-                    keep = ~bad
-                    xc = xc[keep]
-                    yc = yc[keep] if yc is not None else None
-                    wc = wc[keep] if wc is not None else None
-                    skipped += n_bad
-                    REGISTRY.counter_inc("rows.nonfinite_skipped", n_bad)
-                    if not len(xc):
-                        continue
+            if nonfinite != "allow":
+                with trace_range("ingest.scan"):
+                    if not (
+                        # scalar pre-check keeps the all-finite fast path
+                        # off the per-row mask allocation
+                        np.isfinite(xc).all()
+                        and (yc is None or np.isfinite(yc).all())
+                        and (wc is None or np.isfinite(wc).all())
+                    ):
+                        bad = ~np.isfinite(xc).all(axis=1)
+                        if yc is not None:
+                            bad |= ~np.isfinite(yc)
+                        if wc is not None:
+                            bad |= ~np.isfinite(wc)
+                        n_bad = int(bad.sum())
+                        if n_bad:
+                            if nonfinite == "raise":
+                                raise ValueError(
+                                    f"{n_bad} non-finite input row(s) in a "
+                                    "streamed chunk; set "
+                                    "TPU_ML_NONFINITE_POLICY=skip to drop "
+                                    "and count them instead"
+                                )
+                            keep = ~bad
+                            xc = xc[keep]
+                            yc = yc[keep] if yc is not None else None
+                            wc = wc[keep] if wc is not None else None
+                            skipped += n_bad
+                            REGISTRY.counter_inc(
+                                "rows.nonfinite_skipped", n_bad
+                            )
+                            if not len(xc):
+                                continue
             if wc is not None:
                 wc = columnar.validate_weights(
                     wc, len(xc), allow_all_zero=True
@@ -989,14 +1005,15 @@ def stream_fold(
             at = 0
             while at < len(xc):
                 take = min(chunk_rows - fill, len(xc) - at)
-                x_buf[fill : fill + take, :n] = xc[at : at + take]
-                if augment_intercept:
-                    x_buf[fill : fill + take, n] = 1.0
-                if want_y:
-                    y_buf[fill : fill + take] = yc[at : at + take]
-                w_buf[fill : fill + take] = (
-                    1.0 if wc is None else wc[at : at + take]
-                )
+                with trace_range("ingest.stage"):
+                    x_buf[fill : fill + take, :n] = xc[at : at + take]
+                    if augment_intercept:
+                        x_buf[fill : fill + take, n] = 1.0
+                    if want_y:
+                        y_buf[fill : fill + take] = yc[at : at + take]
+                    w_buf[fill : fill + take] = (
+                        1.0 if wc is None else wc[at : at + take]
+                    )
                 fill += take
                 at += take
                 seen += take

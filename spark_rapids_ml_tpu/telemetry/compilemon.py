@@ -7,16 +7,25 @@ already emits the needed signals through ``jax.monitoring`` — this module
 subscribes once per process and folds them into the telemetry registry:
 
 - ``/jax/core/compile/backend_compile_duration``  → ``compile.seconds``
-  histogram (its count IS the compile count per window — one event per
-  XLA backend compile, i.e. per jitted fold/program actually built).
+  histogram: one event per compile REQUEST, i.e. per program built *or
+  loaded from the persistent cache* (JAX times the cache lookup inside the
+  same event). Where the cache is in use, ``compile.cache_misses`` is the
+  count of programs actually compiled. The event's ``fun_name`` keyword
+  also books ``compile.program_seconds{program}``, so a cold run says which
+  program its minutes went to.
 - ``/jax/core/compile/jaxpr_trace_duration`` and
   ``.../jaxpr_to_mlir_module_duration``           → ``compile.trace_seconds``
   / ``compile.lower_seconds`` histograms (Python-side tracing/lowering).
 - ``/jax/compilation_cache/cache_hits|cache_misses`` → counters — whether
   the persistent XLA cache is actually saving the worker/driver processes
   the recompile.
+- ``/jax/compilation_cache/cache_retrieval_time_sec`` →
+  ``compile.cache_load_seconds`` histogram (what a cache hit cost).
 - ``/jax/compilation_cache/compile_time_saved_sec`` → counter (seconds the
-  cache provably saved).
+  cache provably saved). JAX reports compile time less load time, which is
+  negative for a program that loads slower than it compiled; a counter
+  never goes down, so ``max(0, saved)`` is booked and the load seconds
+  above carry the rest.
 
 Compile-ish durations this table does not name fall through to a generic
 ``compile.other_seconds`` histogram rather than being dropped.
@@ -50,6 +59,7 @@ _DURATION_HISTS = {
     "/jax/core/compile/backend_compile_duration": "compile.seconds",
     "/jax/core/compile/jaxpr_trace_duration": "compile.trace_seconds",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower_seconds",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load_seconds",
 }
 
 _DURATION_COUNTERS = {
@@ -67,10 +77,15 @@ def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
     name = _DURATION_HISTS.get(event)
     if name:
         REGISTRY.histogram_record(name, duration_secs)
+        program = kwargs.get("fun_name")
+        if program and name == "compile.seconds":
+            REGISTRY.histogram_record(
+                "compile.program_seconds", duration_secs, program=str(program)
+            )
         return
     name = _DURATION_COUNTERS.get(event)
     if name:
-        REGISTRY.counter_inc(name, duration_secs)
+        REGISTRY.counter_inc(name, max(0.0, duration_secs))
         return
     if "compile" in event:  # unnamed: keep the signal, generically
         REGISTRY.histogram_record("compile.other_seconds", duration_secs)

@@ -38,17 +38,23 @@ METRICS: frozenset[str] = frozenset({
     "stream.overlap_fraction",
     "chunk.bisections",
     "rows.nonfinite_skipped",
-    # spans
+    # chunks whose H2D transfer was still in flight when their fold was
+    # enqueued: the fold's device time then holds a wait for the DMA
+    "fold.input_in_flight",
+    # spans: duration, and duration less what child spans covered
     "span.seconds",
+    "span.self_seconds",
     # compile monitoring (telemetry.compilemon event mappings)
     "compile.count",
     "compile.seconds",
+    "compile.program_seconds",
     "compile.trace_seconds",
     "compile.lower_seconds",
     "compile.other_seconds",
     "compile.cache_hits",
     "compile.cache_misses",
     "compile.cache_time_saved_s",
+    "compile.cache_load_seconds",
     # resilience
     "retry.attempts",
     "fault.injected",
@@ -171,7 +177,10 @@ METRIC_PREFIXES: tuple[str, ...] = (
 
 HISTOGRAMS: frozenset[str] = frozenset({
     "span.seconds",
+    "span.self_seconds",
     "compile.seconds",
+    "compile.program_seconds",
+    "compile.cache_load_seconds",
     "compile.trace_seconds",
     "compile.lower_seconds",
     "compile.other_seconds",
@@ -220,8 +229,14 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "refresh.probation",
     # streamed-fit / dispatch machinery
     "fold.dispatch",
+    "fold.enqueue",
     "fold.wait",
+    "fold.finalize",
+    "h2d.put",
     "ingest.chunk",
+    "ingest.scan",
+    "ingest.stage",
+    "model.to_host",
     "autotune.search",
     "autotune.trial",
     "transform.plan",

@@ -385,13 +385,18 @@ class RegistrySnapshot:
 
     def phase_table(self, percentiles=(50, 90, 99)) -> dict[str, dict[str, float]]:
         """Per-phase span statistics (the FitReport/trace-report payload):
-        ``{phase: {count, sum, min, max, p50, p90, p99}}`` aggregated over
-        the estimator label."""
+        ``{phase: {count, sum, self, min, max, p50, p90, p99}}`` aggregated
+        over the estimator label; ``self`` is the phase's seconds that no
+        child span covered (``span.self_seconds``)."""
         phases: dict[str, Histogram] = {}
+        self_s: dict[str, float] = {}
         for (name, labels), h in self.hists.items():
-            if name != "span.seconds":
+            if name not in ("span.seconds", "span.self_seconds"):
                 continue
             phase = dict(labels).get("phase", "")
+            if name == "span.self_seconds":
+                self_s[phase] = self_s.get(phase, 0.0) + h.total
+                continue
             if phase in phases:
                 m = phases[phase]
                 m.count += h.count
@@ -402,7 +407,10 @@ class RegistrySnapshot:
                     m.buckets[k] = m.buckets.get(k, 0) + v
             else:
                 phases[phase] = h.copy()
-        return {p: h.to_dict(percentiles) for p, h in sorted(phases.items())}
+        return {
+            p: {**h.to_dict(percentiles), "self": self_s.get(p, h.total)}
+            for p, h in sorted(phases.items())
+        }
 
     def to_wire(self) -> dict:
         """JSON-safe lossless form — labels kept structured, histogram

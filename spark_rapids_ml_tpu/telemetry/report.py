@@ -324,6 +324,7 @@ def end_fit(cap: _FitCapture) -> FitReport:
             "cache_hits": delta.counter("compile.cache_hits"),
             "cache_misses": delta.counter("compile.cache_misses"),
             "cache_time_saved_s": delta.counter("compile.cache_time_saved_s"),
+            "cache_load_seconds": delta.hist("compile.cache_load_seconds").total,
         },
         device_memory=device_memory,
         counters=counters,

@@ -11,6 +11,12 @@ phase name and the estimator currently fitting (set by the ``models.base``
 fit instrumentation) — so one fit later reads back as per-phase latency
 percentiles, not just sums.
 
+Spans know what caused them: a context variable holds the open span's frame,
+a closing span adds its seconds to its parent's frame, and beside
+``span.seconds`` it books ``span.self_seconds`` — its duration less what its
+child spans (same context, so same thread) covered. An outer span's self time
+is the seconds of it that no narrower span names.
+
 Accounting is in a ``finally`` block: a body that raises still books its
 elapsed time (a fit that dies 40 s into ``compute cov`` must show those
 40 s, or the post-mortem blames the wrong phase).
@@ -48,6 +54,24 @@ _current_fit_id: contextvars.ContextVar[str | None] = contextvars.ContextVar(
 # lazy localspark materialization).
 _current_transform_id: contextvars.ContextVar[str | None] = (
     contextvars.ContextVar("tpu_ml_current_transform_id", default=None)
+)
+
+
+class _Frame:
+    """An open span: its name, and the seconds its closed children took."""
+
+    __slots__ = ("name", "child_seconds")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.child_seconds = 0.0
+
+
+# The innermost span open in this context (None outside any). A new thread
+# starts with an empty context, so a span opened there has no parent and
+# takes nothing off a span of the thread that started it.
+_open_span: contextvars.ContextVar[_Frame | None] = contextvars.ContextVar(
+    "tpu_ml_open_span", default=None
 )
 
 
@@ -119,24 +143,35 @@ def trace_range(name: str):
     # the first call this is one sys.modules lookup
     import jax
 
+    parent = _open_span.get()
+    frame = _Frame(name)
+    token = _open_span.set(frame)
     start = time.perf_counter()
     try:
         with jax.profiler.TraceAnnotation(name), jax.named_scope(name):
             yield
     finally:
         end = time.perf_counter()
+        _open_span.reset(token)
         elapsed = end - start
+        if parent is not None:
+            parent.child_seconds += elapsed
+        estimator = _current_estimator.get() or ""
         REGISTRY.histogram_record(
-            "span.seconds",
-            elapsed,
+            "span.seconds", elapsed, phase=name, estimator=estimator
+        )
+        REGISTRY.histogram_record(
+            "span.self_seconds",
+            max(0.0, elapsed - frame.child_seconds),
             phase=name,
-            estimator=_current_estimator.get() or "",
+            estimator=estimator,
         )
         TIMELINE.record_span(
             name,
             start,
             end,
-            estimator=_current_estimator.get() or "",
+            parent=parent.name if parent is not None else "",
+            estimator=estimator,
             fit_id=_current_fit_id.get() or "",
             transform_id=_current_transform_id.get() or "",
         )

@@ -479,11 +479,17 @@ class TestElasticScheduler:
             == 1
         )
 
-    def test_barrier_failure_leaves_no_workers_or_dirs(self, monkeypatch):
+    def test_barrier_failure_leaves_no_workers_or_dirs(
+        self, monkeypatch, tmp_path
+    ):
         """Retries exhausted: the epoch's failure must still tear down every
         rank worker and remove the rendezvous scratch dir (try/finally —
         the old path leaked both on a failed rank)."""
         import tempfile
+
+        # a temp dir of this test's own: another xdist worker's barrier
+        # stage, live in the shared /tmp meanwhile, is not this one's leak
+        monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
 
         def _barrier_dirs():
             return {

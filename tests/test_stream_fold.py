@@ -227,3 +227,162 @@ class TestStreamedOverlap:
                 init=L.init_gram_carry(4, np.float64),
                 chunk_rows=128,
             )
+
+
+class TestStreamedSpans:
+    """The spans and the counter that name a streamed fit's host seconds:
+    ingest.scan and ingest.stage for every source batch, h2d.put and
+    fold.enqueue once a chunk inside fold.dispatch, fold.finalize around the
+    one collective (the benchmark's per-layer metrics read them)."""
+
+    @staticmethod
+    def fold(x, batches, **kw):
+        from spark_rapids_ml_tpu.telemetry import REGISTRY, TIMELINE
+
+        reset_metrics()
+        seq = TIMELINE.seq()
+        res = ingest.stream_fold(
+            iter(np.array_split(x, batches)),
+            L.gram_fold_step(),
+            n=x.shape[1],
+            init=L.init_gram_carry(x.shape[1], x.dtype),
+            chunk_rows=512,
+            **kw,
+        )
+        spans = [e for e in TIMELINE.events(seq) if e["cat"] == "span"]
+        return res, metrics(), spans, REGISTRY.snapshot()
+
+    @pytest.mark.parametrize("batches", [1, 3, 7])
+    def test_scan_and_stage_for_every_batch(self, data, batches):
+        x, _, _ = data
+        res, m, _, _ = self.fold(x, batches)
+        assert m["ingest.scan"]["count"] == batches
+        # a batch that straddles a chunk is staged in two slices, and every
+        # dispatch is followed by one more for the fresh buffer
+        assert m["ingest.stage"]["count"] >= batches + res.chunks
+        assert m["ingest.stage"]["count"] <= 2 * batches + 2 * res.chunks
+
+    def test_put_and_enqueue_once_a_chunk_inside_dispatch(self, data):
+        x, _, _ = data
+        res, m, spans, snap = self.fold(x, 3)
+        assert res.chunks == 3
+        for phase in ("h2d.put", "fold.enqueue", "fold.dispatch"):
+            assert m[phase]["count"] == res.chunks, phase
+        for e in spans:
+            if e["name"] in ("h2d.put", "fold.enqueue"):
+                assert e["args"]["parent"] == "fold.dispatch"
+        # so fold.dispatch's own seconds are what neither covers
+        own = snap.hist("span.self_seconds", phase="fold.dispatch").total
+        assert own == pytest.approx(
+            m["fold.dispatch"]["seconds"]
+            - m["h2d.put"]["seconds"]
+            - m["fold.enqueue"]["seconds"],
+            abs=1e-9,
+        )
+
+    def test_stage_never_covers_a_dispatch(self, data):
+        x, _, _ = data
+        _, _, spans, _ = self.fold(x, 3)
+        assert not [
+            e for e in spans
+            if e["name"] == "fold.dispatch"
+            and e["args"].get("parent") == "ingest.stage"
+        ]
+
+    def test_no_scan_when_nonfinite_is_allowed(self, data):
+        x, _, _ = data
+        _, m, _, _ = self.fold(x, 3, nonfinite="allow")
+        assert "ingest.scan" not in m
+        assert m["ingest.stage"]["count"] >= 3
+
+    def test_scan_covers_the_filter_when_it_trips(self, data):
+        x, _, _ = data
+        bad = x.copy()
+        bad[5, 2] = np.nan
+        res, m, _, _ = self.fold(bad, 3, nonfinite="skip")
+        assert res.skipped_rows == 1 and res.rows == len(x) - 1
+        assert m["ingest.scan"]["count"] == 3
+        with pytest.raises(ValueError, match="non-finite"):
+            self.fold(bad, 3, nonfinite="raise")
+        # the scan that raised still booked its seconds
+        assert metrics()["ingest.scan"]["count"] == 1
+
+    def test_input_in_flight_is_declared_and_bounded(self, data):
+        from spark_rapids_ml_tpu.telemetry import names
+
+        assert "fold.input_in_flight" in names.METRICS
+        assert "fold.input_in_flight" not in names.HISTOGRAMS | names.GAUGES
+        x, _, _ = data
+        res, _, _, snap = self.fold(x, 3)
+        assert 0 <= snap.counter("fold.input_in_flight") <= res.chunks
+
+    def test_new_span_names_are_declared(self):
+        from spark_rapids_ml_tpu.telemetry import names
+
+        assert {
+            "ingest.scan", "ingest.stage", "h2d.put", "fold.enqueue",
+            "fold.finalize", "model.to_host",
+        } <= names.SPAN_PHASES
+        assert {"span.self_seconds"} <= names.METRICS & names.HISTOGRAMS
+
+    def test_finalize_records_its_span(self):
+        from spark_rapids_ml_tpu.parallel import gram as G
+        from spark_rapids_ml_tpu.parallel import mesh as M
+
+        mesh = M.create_mesh()
+        example = L.GramStats(
+            xtx=jax.ShapeDtypeStruct((4, 4), np.float32),
+            col_sum=jax.ShapeDtypeStruct((4,), np.float32),
+            count=jax.ShapeDtypeStruct((), np.float32),
+        )
+        reset_metrics()
+        stats = G.finalize_chunk_fold(G.init_chunk_carry(example, mesh), mesh)
+        assert stats.xtx.shape == (4, 4)
+        assert metrics()["fold.finalize"]["count"] == 1
+
+    def test_stream_fold_does_not_touch_costmodel(self, data, monkeypatch):
+        from spark_rapids_ml_tpu.telemetry import costmodel
+
+        def refuse(*a, **kw):
+            raise AssertionError("stream_fold called costmodel.capture")
+
+        monkeypatch.setattr(costmodel, "capture", refuse)
+        assert not hasattr(ingest, "costmodel")
+        x, _, _ = data
+        res, _, _, snap = self.fold(x, 3)
+        assert res.chunks == 3
+        assert snap.counter("costmodel.calls") == 0
+
+    def test_the_fold_program_keeps_the_name_the_benchmark_reads(self):
+        """benchmarks/layer_metrics/gram_roofline.json finds the fold in the
+        device trace by its module name, ``jit__fold``: a rename breaks this
+        test on the CPU and not a metric on the chip."""
+        import json
+        from pathlib import Path
+
+        from spark_rapids_ml_tpu.parallel import gram as G
+        from spark_rapids_ml_tpu.parallel import mesh as M
+
+        mesh = M.create_mesh(devices=jax.devices()[:1])
+        prog = G._gram_chunk_fold_prog(mesh, L.DEFAULT_PRECISION, "f32")
+        carry = G.init_chunk_carry(
+            L.GramStats(
+                xtx=jax.ShapeDtypeStruct((8, 8), np.float32),
+                col_sum=jax.ShapeDtypeStruct((8,), np.float32),
+                count=jax.ShapeDtypeStruct((), np.float32),
+            ),
+            mesh,
+        )
+        lowered = prog.lower(
+            carry,
+            jax.ShapeDtypeStruct((16, 8), np.float32),
+            jax.ShapeDtypeStruct((16,), np.float32),
+        )
+        assert "module @jit__fold" in lowered.as_text()
+        spec = json.loads(
+            (
+                Path(__file__).resolve().parent.parent
+                / "benchmarks/layer_metrics/gram_roofline.json"
+            ).read_text()
+        )
+        assert spec["reader"]["program"] == "jit__fold"

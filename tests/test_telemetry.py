@@ -139,6 +139,204 @@ class TestSpans:
         assert h.count == 1
 
 
+def _nest(tree: dict) -> None:
+    """Open the spans of ``tree`` ({name: subtree | Exception}) in order; an
+    exception as a subtree is raised inside that span and caught outside."""
+    for name, sub in tree.items():
+        try:
+            with trace_range(name):
+                if isinstance(sub, Exception):
+                    raise sub
+                _nest(sub)
+        except RuntimeError:
+            pass
+
+
+def _children(tree: dict):
+    """(name, names of its direct children) for every span of ``tree``."""
+    for name, sub in tree.items():
+        sub = {} if isinstance(sub, Exception) else sub
+        yield name, list(sub)
+        yield from _children(sub)
+
+
+class TestSpanParentage:
+    """A span adds its seconds to its parent's frame, so span.self_seconds
+    is the span's duration less what its child spans (same context) took."""
+
+    TREES = {
+        "leaf": {"t.a": {}},
+        "child": {"t.a": {"t.b": {}}},
+        "grandchild": {"t.a": {"t.b": {"t.c": {}}}},
+        "siblings": {"t.a": {"t.b": {}, "t.c": {}, "t.d": {"t.e": {}}}},
+        "child_raises": {"t.a": {"t.b": RuntimeError("died"), "t.c": {}}},
+    }
+
+    @pytest.mark.parametrize("shape", list(TREES))
+    def test_self_seconds_is_duration_less_children(self, shape):
+        tree = self.TREES[shape]
+        _nest(tree)
+        snap = T.REGISTRY.snapshot()
+        for name, kids in _children(tree):
+            dur = snap.hist("span.seconds", phase=name)
+            own = snap.hist("span.self_seconds", phase=name)
+            # a body that raises still books both
+            assert dur.count == own.count == 1, name
+            covered = sum(
+                snap.hist("span.seconds", phase=k).total for k in kids
+            )
+            assert own.total == pytest.approx(dur.total - covered, abs=1e-9)
+            assert 0.0 <= own.total <= dur.total
+            # only DIRECT children come off: a grandchild is already inside
+            # its own parent's seconds
+            assert covered <= dur.total
+
+    def test_repeated_child_comes_off_once_each(self):
+        with trace_range("t.loop"):
+            for _ in range(5):
+                with trace_range("t.step"):
+                    pass
+        snap = T.REGISTRY.snapshot()
+        steps = snap.hist("span.seconds", phase="t.step")
+        assert steps.count == 5
+        assert snap.hist("span.self_seconds", phase="t.loop").total == (
+            pytest.approx(
+                snap.hist("span.seconds", phase="t.loop").total - steps.total,
+                abs=1e-9,
+            )
+        )
+
+    def test_span_on_another_thread_is_not_subtracted(self):
+        import time
+
+        def work():
+            with trace_range("t.elsewhere"):
+                time.sleep(0.02)
+
+        with trace_range("t.main"):
+            th = threading.Thread(target=work)
+            th.start()
+            th.join()
+        snap = T.REGISTRY.snapshot()
+        assert snap.hist("span.seconds", phase="t.elsewhere").total >= 0.02
+        main = snap.hist("span.seconds", phase="t.main").total
+        # the other thread's span has no parent: the whole of t.main is its own
+        assert snap.hist("span.self_seconds", phase="t.main").total == (
+            pytest.approx(main, abs=1e-9)
+        )
+
+    def test_open_frame_is_restored_after_a_raise(self):
+        with trace_range("t.outer"):
+            with pytest.raises(RuntimeError):
+                with trace_range("t.bad"):
+                    raise RuntimeError("died")
+            with trace_range("t.after"):
+                pass
+        events = {
+            e["name"]: e for e in T.TIMELINE.events() if e["name"].startswith("t.")
+        }
+        assert events["t.after"]["args"]["parent"] == "t.outer"
+
+    def test_timeline_event_carries_parent(self):
+        seq = T.TIMELINE.seq()
+        _nest({"t.a": {"t.b": {"t.c": {}}}})
+        parents = {
+            e["name"]: e["args"].get("parent")
+            for e in T.TIMELINE.events(seq)
+            if e["cat"] == "span"
+        }
+        # a root span carries no parent key (empty labels are dropped)
+        assert parents == {"t.a": None, "t.b": "t.a", "t.c": "t.b"}
+
+    def test_phase_table_carries_self(self):
+        _nest({"t.a": {"t.b": {}}})
+        table = T.REGISTRY.snapshot().phase_table()
+        assert table["t.b"]["self"] == pytest.approx(table["t.b"]["sum"])
+        assert table["t.a"]["self"] == pytest.approx(
+            table["t.a"]["sum"] - table["t.b"]["sum"], abs=1e-9
+        )
+
+
+class TestCompileMonitoring:
+    """telemetry.compilemon's mapping of jax.monitoring events, fed by hand
+    (the listeners are plain functions)."""
+
+    def test_cache_retrieval_is_booked_as_load_seconds(self):
+        from spark_rapids_ml_tpu.telemetry import compilemon
+
+        compilemon._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25
+        )
+        compilemon._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.5
+        )
+        h = T.REGISTRY.snapshot().hist("compile.cache_load_seconds")
+        assert (h.count, h.total) == (2, 0.75)
+        # no longer swept into the catch-all
+        assert T.REGISTRY.snapshot().hist("compile.other_seconds").count == 0
+
+    @pytest.mark.parametrize(
+        "saved, want", [([1.5, 2.0], 3.5), ([-0.4], 0.0), ([2.0, -5.0, 1.0], 3.0)]
+    )
+    def test_time_saved_never_goes_down(self, saved, want):
+        from spark_rapids_ml_tpu.telemetry import compilemon
+
+        seen = []
+        for s in saved:
+            compilemon._on_duration(
+                "/jax/compilation_cache/compile_time_saved_sec", s
+            )
+            seen.append(
+                T.REGISTRY.snapshot().counter("compile.cache_time_saved_s")
+            )
+        assert seen == sorted(seen)
+        assert seen[-1] == pytest.approx(want)
+
+    def test_compile_seconds_are_booked_by_program(self):
+        from spark_rapids_ml_tpu.telemetry import compilemon
+
+        event = "/jax/core/compile/backend_compile_duration"
+        compilemon._on_duration(event, 166.0, fun_name="eigh")
+        compilemon._on_duration(event, 2.0, fun_name="_fold")
+        compilemon._on_duration(event, 1.0, fun_name="_fold")
+        compilemon._on_duration(event, 0.5)  # an older JAX: no keyword
+        # tracing durations carry fun_name too, and are not compiles
+        compilemon._on_duration(
+            "/jax/core/compile/jaxpr_trace_duration", 9.0, fun_name="eigh"
+        )
+        snap = T.REGISTRY.snapshot()
+        assert snap.hist("compile.seconds").count == 4
+        assert snap.hist("compile.seconds").total == pytest.approx(169.5)
+        by = snap.hist("compile.program_seconds", program="eigh")
+        assert (by.count, by.total) == (1, 166.0)
+        fold = snap.hist("compile.program_seconds", program="_fold")
+        assert (fold.count, fold.total) == (2, 3.0)
+
+    def test_a_real_compile_is_booked_by_program(self):
+        import jax
+        import jax.numpy as jnp
+
+        T.install_monitoring()
+
+        def _tpu_ml_compilemon_probe(x):
+            return x * 3 + 1
+
+        jax.jit(_tpu_ml_compilemon_probe)(jnp.arange(7.0)).block_until_ready()
+        snap = T.REGISTRY.snapshot()
+        programs = {
+            dict(labels).get("program")
+            for (name, labels) in snap.hists
+            if name == "compile.program_seconds"
+        }
+        assert any("_tpu_ml_compilemon_probe" in (p or "") for p in programs)
+
+    def test_fit_report_carries_cache_load_seconds(self, data):
+        x, _ = data
+        r = StandardScaler().fit(x).fit_report
+        assert r.compile["cache_load_seconds"] >= 0.0
+        assert r.compile["cache_time_saved_s"] >= 0.0
+
+
 class TestRegistryThreadSafety:
     def test_concurrent_counters_and_spans_exact(self):
         # the localspark partition-executor load shape: many threads, one

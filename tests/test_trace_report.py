@@ -94,6 +94,72 @@ def test_compile_dominated_anomaly_fires():
     assert any("compile-dominated" in a for a in anomalies)
 
 
+@pytest.mark.parametrize(
+    "compile_rec, fires",
+    [
+        # no persistent cache: every request is a compile
+        ({"count": 3, "seconds": 1.5}, True),
+        # the cache in use and every request a load (an eager eigh makes
+        # sixty): nothing was compiled, however long the loads took
+        ({"count": 60, "seconds": 1.5, "cache_hits": 60, "cache_misses": 0,
+          "cache_load_seconds": 1.4}, False),
+        # two real compiles among the loads, and they took the time
+        ({"count": 60, "seconds": 1.8, "cache_hits": 58, "cache_misses": 2,
+          "cache_load_seconds": 0.3}, True),
+        # two real compiles, but the seconds were the loads'
+        ({"count": 60, "seconds": 1.8, "cache_hits": 58, "cache_misses": 2,
+          "cache_load_seconds": 1.5}, False),
+    ],
+)
+def test_compile_dominated_counts_compiles_not_cache_loads(compile_rec, fires):
+    mod = _load_cli_module()
+    rec = {
+        "type": "fit_report", "estimator": "X", "wall_seconds": 2.0,
+        "rows_ingested": 100, "phases": {}, "compile": compile_rec,
+    }
+    got = any("compile-dominated" in a for a in mod.check_anomalies(rec))
+    assert got is fires
+
+
+@pytest.mark.parametrize(
+    "compile_rec, fires",
+    [
+        ({"count": 60}, True),
+        ({"count": 60, "cache_hits": 60, "cache_misses": 0}, False),
+        ({"count": 60, "cache_hits": 56, "cache_misses": 4}, False),
+        ({"count": 60, "cache_hits": 30, "cache_misses": 30}, True),
+    ],
+)
+def test_recompile_storm_counts_cache_misses_where_the_cache_is_in_use(
+    compile_rec, fires
+):
+    mod = _load_cli_module()
+    rec = {
+        "type": "fit_report", "estimator": "X", "wall_seconds": 100.0,
+        "rows_ingested": 100, "phases": {}, "compile": compile_rec,
+        "cost_model": {"kernels": {"gram": {}}},
+    }
+    got = any("recompile storm" in a for a in mod.check_anomalies(rec))
+    assert got is fires
+
+
+def test_phase_table_prints_self_seconds(capsys):
+    mod = _load_cli_module()
+    mod._print_phase_table(
+        {"phases": {
+            "compute cov": {"count": 1, "sum": 30.0, "self": 0.5},
+            "ingest.stage": {"count": 66, "sum": 12.0},  # an older report
+        }},
+        sys.stdout,
+    )
+    out = capsys.readouterr().out
+    assert out.splitlines()[0].split()[:4] == ["phase", "count", "total", "self"]
+    rows = {line.split("  ")[0]: line.split() for line in out.splitlines()[2:]}
+    assert rows["compute cov"][3:5] == ["30.000s", "500.00ms"]
+    # a report from before spans knew their parent: the whole span is its own
+    assert rows["ingest.stage"][2:4] == ["12.000s", "12.000s"]
+
+
 def test_strict_exit_code(tmp_path):
     mod = _load_cli_module()
     import json
