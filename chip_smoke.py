@@ -5,7 +5,10 @@ One process, run as ``python chip_smoke.py`` from the repo root on a machine
 with a TPU. It drives the two normal entry points once, at the north-star
 width (n = 2048, k = 50, ``BASELINE.json``), on data made from a seed:
 
-1. device      assert the platform is ``tpu``; print kind, count, versions
+1. device      assert the platform is ``tpu``; print kind, count, versions;
+               put a 1 GiB float32 host array, wait for it, overwrite the host
+               array and read the device array back: it must read as it was
+               put wherever ``stream_fold`` would write its staging set again
 2. fit         ``SparkPCA().setDistribution("mesh-local").fit(df)`` on 65,536
                rows — the resident branch (ingest.stream_to_mesh + psum Gram)
 3. fit         the same on 196,608 rows: three default chunks, past the 2 GiB
@@ -50,6 +53,7 @@ N, K = 2048, 50
 RESIDENT_ROWS = 65_536
 STREAMED_ROWS = 196_608          # three default 65,536-row chunks
 TRANSFORM_ROWS = 8_192
+PUT_ROWS = 131_072               # x N float32 = 1 GiB: the overwrite probe
 SERVE_REQUEST_ROWS = (1, 8, 1000)
 MIN_COSINE = 0.9999              # BASELINE.md's accuracy bar
 # max |served − x·pc| over max |x·pc|: an f32 projection at HIGHEST is ~1e-6,
@@ -142,6 +146,38 @@ def spy(module, name: str, record):
         yield
     finally:
         setattr(module, name, original)
+
+
+def put_then_overwrite(rows: int, n: int) -> dict:
+    """Does a device array still read its host source once it is ready? Put
+    ``rows`` x ``n`` float32, wait, overwrite the host array, read the device
+    array back and compare with what was put. ``stream_fold`` rewrites a
+    staging set when its arrays are ready and ``ingest._shares_memory`` says
+    they do not live in it: the check has to agree with what the write shows
+    (the CPU backend aliases an aligned source, and there the set is not
+    rewritten)."""
+    import jax
+    import numpy as np
+
+    from spark_rapids_ml_tpu.spark import ingest
+
+    host = np.arange(rows * n, dtype=np.float32).reshape(rows, n)
+    placed = jax.block_until_ready(jax.device_put(host))
+    shares = bool(ingest._shares_memory(placed, host))
+    host[:] = -1.0
+    back = np.array(placed)  # a copy: on an aliasing backend a view is the source
+    host[:] = np.arange(rows * n, dtype=np.float32).reshape(rows, n)
+    unchanged = bool(np.array_equal(back, host))
+    if shares == unchanged:
+        raise AssertionError(
+            f"the device array {'kept' if unchanged else 'lost'} its contents "
+            f"when its source was overwritten, and _shares_memory says {shares}"
+        )
+    return {
+        "bytes": int(host.nbytes),
+        "device_array_unchanged": unchanged,
+        "shares_memory": shares,
+    }
 
 
 class Phases:
@@ -292,6 +328,10 @@ def run(rehearse: bool):
     before = telemetry.REGISTRY.snapshot()
     phases = Phases()
     ndev = device["count"]
+
+    with phases.timed("put_then_overwrite"):
+        verdict = put_then_overwrite(4096 if rehearse else PUT_ROWS, n)
+    phases.note("put_then_overwrite", **verdict)
 
     x = make_rows(streamed_rows, n, k)
     table = to_table(x)

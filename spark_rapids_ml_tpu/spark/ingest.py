@@ -16,10 +16,12 @@ This module replaces that with a streaming fill:
 - chunks drain from the DataFrame lazily (localspark partitions are
   generator-produced; real pyspark uses ``toLocalIterator`` which fetches
   one partition at a time);
-- each chunk is copied into a per-device shard buffer, ``device_put`` to
-  its device the moment it fills, and the host buffer is never reused
-  (``device_put`` of a host ndarray may alias rather than copy on some
-  backends);
+- each chunk is copied into a per-device shard buffer and ``device_put`` to
+  its device the moment it fills. The resident path (``stream_to_mesh``)
+  takes a new buffer for every shard, because ``device_put`` of a host
+  ndarray may alias rather than copy; the streamed fold (``stream_fold``)
+  keeps one staging set and rewrites it, under the buffer rule stated
+  there, which finds out from the arrays whether a put aliased;
 - the global array is assembled zero-copy on device with
   ``jax.make_array_from_single_device_arrays``.
 
@@ -27,9 +29,12 @@ Peak host footprint: one inbound chunk + the shard buffer being filled —
 independent of dataset size. Wire dtype is selectable
 (``TPU_ML_MESH_LOCAL_WIRE_DTYPE=float32`` halves both host RSS and HBM;
 default float64 keeps the reference's FLOAT64 semantics,
-rapidsml_jni.cu:89). An optional hard cap (``TPU_ML_MESH_LOCAL_MAX_BYTES``)
-turns the otherwise-undiagnosed device OOM of oversized mesh-local ingests
-into a descriptive error naming the alternatives.
+rapidsml_jni.cu:89) where x64 is on; with x64 off the device holds
+float32 whatever it says, and the streamed fold stages in that dtype, so
+the one host copy of a chunk is also its cast. An optional hard cap
+(``TPU_ML_MESH_LOCAL_MAX_BYTES``) turns the otherwise-undiagnosed device OOM
+of oversized mesh-local ingests into a descriptive error naming the
+alternatives.
 """
 
 from __future__ import annotations
@@ -591,6 +596,100 @@ def _split_chunk_buffers(bx, by, bw, size: int):
     return out
 
 
+def _shares_memory(placed, buf: np.ndarray) -> bool:
+    """Whether ``placed`` (what a ``put_fn`` returned) lives in ``buf``'s
+    bytes: a host array by its bounds, a device array by where each
+    addressable shard starts (the CPU backend puts an aligned ndarray with
+    no copy). Anything that cannot say counts as sharing."""
+    if isinstance(placed, np.ndarray):
+        return np.may_share_memory(placed, buf)
+    shards = getattr(placed, "addressable_shards", None)
+    if shards is None:
+        return True
+    lo = buf.__array_interface__["data"][0]
+    return any(
+        lo <= s.data.unsafe_buffer_pointer() < lo + buf.nbytes for s in shards
+    )
+
+
+class _StagingSet:
+    """The host buffers one chunk is staged in — ``x`` [chunk_rows, n_eff],
+    ``y`` (or None) and ``w`` — in the dtype their device arrays will have,
+    with the arrays put from them since they were last reclaimed. Rows
+    ``[dirty:]`` are zero. ``stream_fold`` states when a set may be written
+    again; :meth:`reclaim` is that rule's check."""
+
+    def __init__(self, key):
+        chunk_rows, n_eff, dtype, layout, want_y = self.key = key
+        self.x = np.zeros(
+            (chunk_rows, n_eff), dtype, order="F" if layout == "col" else "C"
+        )
+        self.y = np.zeros(chunk_rows, dtype) if want_y else None
+        self.w = np.zeros(chunk_rows, dtype)
+        self.dirty = 0
+        self.placed: list[Any] = []
+
+    def buffers(self):
+        return [b for b in (self.x, self.y, self.w) if b is not None]
+
+    def zero_from(self, fill: int) -> None:
+        """Zero rows ``[fill:dirty]``: what an earlier chunk left past a
+        ragged tail (a fresh set has nothing there)."""
+        for b in self.buffers():
+            b[fill : self.dirty] = 0
+        self.dirty = fill
+
+    def reclaim(self, wait: bool = True) -> bool:
+        """Let go of the placed arrays; True where the buffers may now be
+        written again. Without ``wait`` a transfer still in flight answers
+        False instead of blocking."""
+        import jax
+
+        placed, self.placed = self.placed, []
+        on_device = [a for a in placed if isinstance(a, jax.Array)]
+        if any(a.is_deleted() for a in on_device):
+            return False  # donated on: nothing left to ask whether it landed
+        if wait:
+            jax.block_until_ready(on_device)
+        elif not all(a.is_ready() for a in on_device):
+            return False
+        return not any(
+            _shares_memory(a, b) for a in placed for b in self.buffers()
+        )
+
+
+# the one staging set kept between stream_folds (one set, not two in
+# rotation: PERF.md section 6, PR 27 has both readings)
+_kept_staging: list[_StagingSet] = []
+_kept_staging_lock = threading.Lock()
+
+
+def _borrow_staging(key) -> _StagingSet | None:
+    """Take the kept set out of the holder for one ``stream_fold``; None
+    where it is lent out already or was made for another key."""
+    with _kept_staging_lock:
+        if _kept_staging and _kept_staging[0].key == key:
+            return _kept_staging.pop()
+    return None
+
+
+def _return_staging(staging: _StagingSet | None) -> None:
+    """Give back what a ``stream_fold`` ends with. The holder keeps the
+    newest and drops what it had; a set whose last transfer cannot be seen
+    to have landed, or whose arrays share its memory, is not kept."""
+    if staging is not None and staging.reclaim(wait=False):
+        with _kept_staging_lock:
+            _kept_staging[:] = [staging]
+
+
+def release_staging() -> None:
+    """Drop the staging set kept between streamed folds (one chunk of host
+    memory, held so that the next fold of the same shape writes into pages
+    that are already mapped)."""
+    with _kept_staging_lock:
+        _kept_staging.clear()
+
+
 def stream_fold(
     source,
     fold_fn,
@@ -622,10 +721,13 @@ def stream_fold(
     ``device_put``-ing chunk i+1. Each phase is traced, so the overlap is
     observable and the host's seconds have names (telemetry.metrics()):
     ``ingest.chunk`` (the pull), ``ingest.scan`` (the non-finite check),
-    ``ingest.stage`` (the copy into the staging buffer), ``fold.dispatch``
-    with ``h2d.put`` and ``fold.enqueue`` inside it, and ``fold.wait``;
-    ``fold.input_in_flight`` counts the chunks whose transfer had not
-    landed when their fold was enqueued.
+    ``ingest.stage`` (the one host copy, into the staging set and in the
+    dtype the device holds), ``stage.reclaim`` (the wait before a set is
+    written again), ``fold.dispatch`` with ``h2d.put`` and ``fold.enqueue``
+    inside it, and ``fold.wait``; ``fold.input_in_flight`` counts the chunks
+    whose transfer had not landed when their fold was enqueued, and
+    ``stage.buffers{state}`` whether each chunk's set was ``reused``,
+    ``fresh`` or taken anew because the old one was ``aliased``.
 
     ``source`` is either a DataFrame-shaped object (localspark / pyspark —
     drained via the same strategy-gated ``_iter_chunks`` the resident
@@ -677,6 +779,10 @@ def stream_fold(
 
     cfg = get_config()
     dt = wire_dtype()
+    # what a put of ``dt`` becomes on the device (float32 where x64 is off):
+    # the staging set has that dtype, so the slice copy below is the one
+    # cast and device_put finds nothing left to canonicalise on the host
+    stage_dt = np.dtype(jax.dtypes.canonicalize_dtype(dt))
     n_eff = n + 1 if augment_intercept else n
     # a caller-pinned chunk_rows (mesh paths, tests) wins outright; only the
     # unpinned path consults the ledger-driven tuner below
@@ -739,16 +845,6 @@ def stream_fold(
                     return
             yield item
 
-    def fresh():
-        return (
-            np.zeros(
-                (chunk_rows, n_eff), dt,
-                order="F" if layout == "col" else "C",
-            ),
-            np.zeros(chunk_rows, dt) if want_y else None,
-            np.zeros(chunk_rows, dt),
-        )
-
     carry = init() if callable(init) else init
 
     if tune_geometry:
@@ -765,7 +861,7 @@ def stream_fold(
             rows=rows,
             dtype=dt,
             measure=autotune.stream_fold_measure(
-                fold_fn, carry, n_eff, dt, put, want_y=want_y
+                fold_fn, carry, n_eff, stage_dt, put, want_y=want_y
             ),
             candidates=autotune.candidate_grid(
                 chunk_rows, floor=min_chunk_rows
@@ -809,8 +905,39 @@ def stream_fold(
                 "already folded)", n_chunks, seen,
             )
 
-    x_buf, y_buf, w_buf = fresh()
+    def staging_key():
+        return (chunk_rows, n_eff, stage_dt, layout, want_y)
+
+    # the set last put from, which the next chunk rewrites: at first what
+    # the holder kept from the last fold of this shape
+    spare = _borrow_staging(staging_key())
+    staged: _StagingSet | None = None  # the set being filled
     fill = 0
+
+    def take_staging() -> _StagingSet:
+        """The set the next chunk is staged in. THE BUFFER RULE, which
+        holds on every backend and is found out from the arrays, not from a
+        platform's name: a set is written again only after (1) every array
+        put from it is ready — a runtime may read the host buffer until the
+        transfer completes, and not after — and (2) none of those arrays
+        shares memory with it (the CPU backend puts an aligned ndarray with
+        no copy, and an identity ``put_fn`` hands the buffer itself on):
+        such a buffer goes with its array, and a new one is taken. Rows past
+        a ragged tail are zeroed before the put (``dispatch``)."""
+        nonlocal spare
+        key = staging_key()
+        candidate, spare = spare, None
+        state = "fresh"
+        # another key: a bisection changed the chunk's shape
+        if candidate is not None and candidate.key == key:
+            with trace_range("stage.reclaim"):
+                reusable = candidate.reclaim()
+            if reusable:
+                REGISTRY.counter_inc("stage.buffers", state="reused")
+                return candidate
+            state = "aliased"
+        REGISTRY.counter_inc("stage.buffers", state=state)
+        return _StagingSet(key)
 
     # live-health heartbeat: the monitor (telemetry.health) compares
     # stream.last_beat against time.monotonic() and flags the stream stale
@@ -862,10 +989,18 @@ def stream_fold(
             # inject BEFORE the donated fold consumes its buffers, so the
             # carry is still valid when the retry re-enters
             faults.inject("fold.dispatch")
+            # arrays put from the staging set are kept until it is reclaimed
+            placed = staged.placed if xb is staged.x else []
+
+            def place(buf):
+                arr = put(buf)
+                placed.append(arr)
+                return arr
+
             with trace_range("h2d.put"):
-                xd = put(xb)
-                wd = put(wb)
-                yd = put(yb) if yb is not None else None
+                xd = place(xb)
+                wd = place(wb)
+                yd = place(yb) if yb is not None else None
             nbytes = xb.nbytes + wb.nbytes
             if yb is not None:
                 nbytes += yb.nbytes
@@ -921,16 +1056,19 @@ def stream_fold(
                 chunk_rows = min(chunk_rows, new)
 
     def dispatch():
-        nonlocal x_buf, y_buf, w_buf, fill
-        dispatch_buffers(x_buf, y_buf if want_y else None, w_buf)
+        nonlocal staged, spare, fill
+        if fill < staged.dirty:
+            # a new buffer's rows past a ragged tail were zero for nothing;
+            # a rewritten one's are an earlier chunk's, and under
+            # nonfinite="allow" a stale row times w=0 is not zero
+            with trace_range("ingest.stage"):
+                staged.zero_from(fill)
+        try:
+            dispatch_buffers(staged.x, staged.y, staged.w)
+        finally:
+            spare, staged = staged, None
+            fill = 0
         REGISTRY.gauge_set("stream.last_beat", time.monotonic())
-        # never reuse a put buffer: device_put of a host ndarray may alias
-        # rather than copy on some backends (stream_to_mesh rationale). The
-        # new buffer's page faults (and the old one's release) are booked
-        # with the copy that causes them.
-        with trace_range("ingest.stage"):
-            x_buf, y_buf, w_buf = fresh()
-        fill = 0
 
     try:
         for xc, yc, wc in timed_chunks():
@@ -1004,17 +1142,22 @@ def stream_fold(
                 )
             at = 0
             while at < len(xc):
+                if staged is None:
+                    staged = take_staging()
                 take = min(chunk_rows - fill, len(xc) - at)
+                # the one host copy of these rows, and their cast to the
+                # device's dtype (numpy rounds to nearest, as device_put did)
                 with trace_range("ingest.stage"):
-                    x_buf[fill : fill + take, :n] = xc[at : at + take]
+                    staged.x[fill : fill + take, :n] = xc[at : at + take]
                     if augment_intercept:
-                        x_buf[fill : fill + take, n] = 1.0
+                        staged.x[fill : fill + take, n] = 1.0
                     if want_y:
-                        y_buf[fill : fill + take] = yc[at : at + take]
-                    w_buf[fill : fill + take] = (
+                        staged.y[fill : fill + take] = yc[at : at + take]
+                    staged.w[fill : fill + take] = (
                         1.0 if wc is None else wc[at : at + take]
                     )
                 fill += take
+                staged.dirty = max(staged.dirty, fill)
                 at += take
                 seen += take
                 if fill == chunk_rows:
@@ -1046,6 +1189,7 @@ def stream_fold(
         # inactive stream as OK regardless of beat age, so a dead stream
         # must not read as "wedged" forever
         REGISTRY.gauge_set("stream.active", 0)
+        _return_staging(staged or spare)
     # per-stream H2D↔compute overlap evidence: fraction of dispatches
     # issued while the prior fold was still on device. Recorded as a
     # histogram so end_fit's snapshot delta reads a per-fit mean into

@@ -41,6 +41,10 @@ METRICS: frozenset[str] = frozenset({
     # chunks whose H2D transfer was still in flight when their fold was
     # enqueued: the fold's device time then holds a wait for the DMA
     "fold.input_in_flight",
+    # one increment a chunk staged: state="reused" (a kept staging set
+    # written again), "fresh" (none to reuse) or "aliased" (the arrays put
+    # from the old one share its memory, so a new one was taken)
+    "stage.buffers",
     # spans: duration, and duration less what child spans covered
     "span.seconds",
     "span.self_seconds",
@@ -236,6 +240,7 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "ingest.chunk",
     "ingest.scan",
     "ingest.stage",
+    "stage.reclaim",
     "model.to_host",
     "autotune.search",
     "autotune.trial",

@@ -257,10 +257,11 @@ class TestStreamedSpans:
         x, _, _ = data
         res, m, _, _ = self.fold(x, batches)
         assert m["ingest.scan"]["count"] == batches
-        # a batch that straddles a chunk is staged in two slices, and every
-        # dispatch is followed by one more for the fresh buffer
-        assert m["ingest.stage"]["count"] >= batches + res.chunks
-        assert m["ingest.stage"]["count"] <= 2 * batches + 2 * res.chunks
+        # one slice a batch and one more for every chunk boundary inside a
+        # batch; a rewritten set's ragged tail is zeroed under one more.
+        # Taking a set books no span of its own
+        assert m["ingest.stage"]["count"] >= max(batches, res.chunks)
+        assert m["ingest.stage"]["count"] <= batches + res.chunks
 
     def test_put_and_enqueue_once_a_chunk_inside_dispatch(self, data):
         x, _, _ = data
@@ -386,3 +387,238 @@ class TestStreamedSpans:
             ).read_text()
         )
         assert spec["reader"]["program"] == "jit__fold"
+
+
+class TestStagingSet:
+    """The staging set is made in the dtype the device holds, kept, and
+    written again only under the buffer rule stated in ``stream_fold``:
+    after the arrays put from it are ready, and never where they share its
+    memory; rows past a ragged tail are zeroed before the put."""
+
+    N = 6
+    CHUNK = 64
+
+    @pytest.fixture(autouse=True)
+    def empty_holder(self):
+        ingest.release_staging()
+        yield
+        ingest.release_staging()
+
+    @staticmethod
+    def copying_put(seen=None):
+        """A put_fn whose result owns its bytes, as a TPU's does."""
+
+        def put(a):
+            if seen is not None:
+                seen.append(np.array(a))
+            return jax.device_put(np.array(a))
+
+        return put
+
+    def fold(self, x, put_fn=None, *, dtype=np.float64, chunk=None, **kw):
+        from spark_rapids_ml_tpu.telemetry import REGISTRY
+
+        before = REGISTRY.snapshot()
+        res = ingest.stream_fold(
+            iter(np.array_split(x, 5)),
+            L.gram_fold_step(),
+            n=x.shape[1],
+            init=L.init_gram_carry(x.shape[1], dtype),
+            chunk_rows=chunk or self.CHUNK,
+            put_fn=put_fn,
+            **kw,
+        )
+        return res, REGISTRY.snapshot().delta(before)
+
+    @staticmethod
+    def states(moved):
+        return {
+            state: int(moved.counter("stage.buffers", state=state))
+            for state in ("reused", "fresh", "aliased")
+        }
+
+    def rows(self, n_rows, scale=1.0, seed=3):
+        rng = np.random.default_rng(seed)
+        return np.asarray(rng.normal(size=(n_rows, self.N)), np.float64) * scale
+
+    def test_ragged_tail_after_full_chunks_is_one_numpy_pass(self):
+        # three full chunks and 23 rows more, all of large values
+        x = self.rows(3 * self.CHUNK + 23, scale=1e150)
+        seen = []
+        res, moved = self.fold(x, self.copying_put(seen), nonfinite="allow")
+        assert res.chunks == 4 and res.rows == len(x)
+        np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
+        np.testing.assert_allclose(res.carry.col_sum, x.sum(0), rtol=1e-12)
+        assert float(res.carry.count) == len(x)
+        assert self.states(moved) == {"reused": 3, "fresh": 1, "aliased": 0}
+        last_x, last_w = seen[-2], seen[-1]
+        np.testing.assert_array_equal(last_x[:23], x[-23:])
+        assert not last_x[23:].any() and not last_w[23:].any()
+        assert (last_w[:23] == 1.0).all()
+
+    def test_stale_rows_of_a_kept_set_never_reach_a_fold(self):
+        """Under nonfinite="allow" a stale inf times w=0 is NaN: the tail of
+        a second fold must not see what the first one left in the set."""
+        poisoned = self.rows(2 * self.CHUNK)
+        poisoned[:, 0] = np.inf
+        self.fold(poisoned, self.copying_put(), nonfinite="allow")
+        x = self.rows(self.CHUNK + 9, seed=4)
+        res, moved = self.fold(x, self.copying_put(), nonfinite="allow")
+        assert self.states(moved)["fresh"] == 0
+        np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
+        assert float(res.carry.count) == len(x)
+
+    def test_labels_and_intercept_follow_the_same_rule(self):
+        from spark_rapids_ml_tpu.ops import linear as LIN
+
+        x = self.rows(2 * self.CHUNK + 5)
+        y = x @ np.arange(1.0, self.N + 1)
+        seen = []
+        put = self.copying_put(seen)
+        for _ in range(2):  # the second fold rewrites the first one's set
+            seen.clear()
+            res = ingest.stream_fold(
+                iter([(x[:70], y[:70]), (x[70:], y[70:])]),
+                LIN.linear_fold_step(),
+                n=self.N,
+                init=LIN.init_linear_carry(self.N + 1, np.float64),
+                label_col="y",
+                augment_intercept=True,
+                chunk_rows=self.CHUNK,
+                put_fn=put,
+                nonfinite="allow",
+            )
+            assert res.chunks == 3
+            last_x, last_w, last_y = seen[-3:]
+            assert (last_x[:5, self.N] == 1.0).all()
+            assert not last_x[5:].any() and not last_w[5:].any()
+            assert not last_y[5:].any()
+            np.testing.assert_array_equal(last_y[:5], y[-5:])
+
+    @pytest.mark.parametrize("x64", [False, True])
+    def test_the_set_has_the_dtype_the_device_holds(self, x64):
+        x = self.rows(3 * self.CHUNK)
+        seen = []
+
+        def put(a):
+            seen.append(a)
+            return jax.device_put(np.array(a))
+
+        want = np.float64 if x64 else np.float32
+        with jax.enable_x64(x64):
+            res, moved = self.fold(x, put, dtype=want)
+            assert res.chunks == 3
+            assert all(
+                isinstance(a, np.ndarray) and a.dtype == want for a in seen
+            )
+            assert res.carry.xtx.dtype == want
+            # x of N columns and w; no label, no intercept
+            itemsize = np.dtype(want).itemsize
+            assert moved.counter("h2d.bytes", path="stream") == (
+                res.chunks * self.CHUNK * (self.N + 1) * itemsize
+            )
+            assert res.max_put_bytes == self.CHUNK * (self.N + 1) * itemsize
+            np.testing.assert_allclose(
+                res.carry.xtx, x.T @ x, rtol=1e-12 if x64 else 1e-5
+            )
+
+    def test_a_put_that_shares_memory_takes_the_buffer_with_it(self):
+        x = self.rows(4 * self.CHUNK + 7)
+        want = x.T @ x
+        # identity: the fold is handed the staging buffers themselves
+        res, moved = self.fold(x, lambda a: a)
+        assert res.chunks == 5
+        assert self.states(moved) == {"reused": 0, "fresh": 1, "aliased": 4}
+        np.testing.assert_allclose(res.carry.xtx, want, rtol=1e-12)
+        assert not ingest._kept_staging  # and no such set is kept
+        # the CPU backend's own device_put aliases an aligned ndarray and
+        # copies any other: whichever it does, the answer is the same
+        res, moved = self.fold(x)
+        states = self.states(moved)
+        assert states["fresh"] == 1
+        assert states["reused"] + states["aliased"] == res.chunks - 1
+        np.testing.assert_allclose(res.carry.xtx, want, rtol=1e-12)
+        # a put that copies, as a TPU's does
+        ingest.release_staging()
+        res, moved = self.fold(x, self.copying_put())
+        assert self.states(moved) == {"reused": 4, "fresh": 1, "aliased": 0}
+        np.testing.assert_allclose(res.carry.xtx, want, rtol=1e-12)
+
+    @pytest.mark.parametrize("offset", [0, 8])
+    def test_shared_memory_is_read_off_the_array(self, offset):
+        """``_shares_memory`` against what a write shows: the CPU backend
+        aliases a 64-byte-aligned source and copies a misaligned one."""
+        raw = np.zeros(4096 * 8 + 128, np.uint8)
+        at = (-raw.ctypes.data) % 64 + offset
+        buf = raw[at : at + 4096 * 8].view(np.float64)
+        placed = jax.block_until_ready(jax.device_put(buf))
+        buf[:] = 7.0
+        aliased = bool(np.asarray(placed)[0] == 7.0)
+        assert ingest._shares_memory(placed, buf) == aliased
+        assert not ingest._shares_memory(placed, np.zeros(4096))
+        assert ingest._shares_memory(buf[8:], buf)
+        assert ingest._shares_memory(object(), buf)  # cannot say: shares
+
+    def test_the_holder_keeps_one_set_between_folds(self):
+        x = self.rows(2 * self.CHUNK + 3)
+        put = self.copying_put()
+        _, moved = self.fold(x, put)
+        assert self.states(moved) == {"reused": 2, "fresh": 1, "aliased": 0}
+        (kept,) = ingest._kept_staging
+        # the same shape again: nothing new is taken
+        _, moved = self.fold(x, put)
+        assert self.states(moved) == {"reused": 3, "fresh": 0, "aliased": 0}
+        assert ingest._kept_staging == [kept] and not kept.placed
+        # another shape takes its own, and the holder keeps the newest
+        _, moved = self.fold(x, put, chunk=2 * self.CHUNK)
+        assert self.states(moved) == {"reused": 1, "fresh": 1, "aliased": 0}
+        (newest,) = ingest._kept_staging
+        assert newest is not kept and newest.key[0] == 2 * self.CHUNK
+        ingest.release_staging()
+        assert not ingest._kept_staging
+
+    def test_the_wait_has_a_name_and_the_counter_is_declared(self):
+        from spark_rapids_ml_tpu.telemetry import names
+
+        assert "stage.reclaim" in names.SPAN_PHASES
+        assert "stage.buffers" in names.METRICS
+        assert "stage.buffers" not in names.HISTOGRAMS | names.GAUGES
+        reset_metrics()
+        res, _ = self.fold(self.rows(3 * self.CHUNK), self.copying_put())
+        # one wait for every chunk that rewrites a set, none for a new one
+        assert metrics()["stage.reclaim"]["count"] == res.chunks - 1
+
+    def test_a_set_lent_out_is_not_lent_twice(self):
+        x = self.rows(2 * self.CHUNK)
+        put = self.copying_put()
+        self.fold(x, put)
+        (kept,) = ingest._kept_staging
+        inner = {}
+
+        def nested_put(a):
+            if not inner:
+                # a second fold of the same shape while the first holds the set
+                inner["states"] = self.states(self.fold(x, put)[1])
+            return put(a)
+
+        res, _ = self.fold(x, nested_put)
+        assert inner["states"] == {"reused": 1, "fresh": 1, "aliased": 0}
+        np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
+        assert len(ingest._kept_staging) == 1
+
+    def test_a_bisection_takes_a_set_of_the_new_shape(self, monkeypatch):
+        from spark_rapids_ml_tpu.resilience import faults
+
+        x = self.rows(3 * self.CHUNK)
+        faults.reset_faults()
+        monkeypatch.setenv(faults.FAULT_PLAN_VAR, "fold.dispatch:oom:1")
+        try:
+            res, moved = self.fold(x, self.copying_put(), min_chunk_rows=8)
+        finally:
+            monkeypatch.delenv(faults.FAULT_PLAN_VAR)
+            faults.reset_faults()
+        assert res.bisections == 1
+        np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
+        (kept,) = ingest._kept_staging
+        assert kept.key[0] == self.CHUNK // 2
+        assert self.states(moved)["fresh"] == 2
