@@ -16,12 +16,13 @@ This module replaces that with a streaming fill:
 - chunks drain from the DataFrame lazily (localspark partitions are
   generator-produced; real pyspark uses ``toLocalIterator`` which fetches
   one partition at a time);
-- each chunk is copied into a per-device shard buffer and ``device_put`` to
-  its device the moment it fills. The resident path (``stream_to_mesh``)
-  takes a new buffer for every shard, because ``device_put`` of a host
-  ndarray may alias rather than copy; the streamed fold (``stream_fold``)
-  keeps one staging set and rewrites it, under the buffer rule stated
-  there, which finds out from the arrays whether a put aliased;
+- each chunk is copied into a staging set (``_StagingSet``) in the dtype
+  the device holds and ``device_put`` the moment it fills: a device's whole
+  shard on the resident path (``stream_to_mesh``), one fold chunk on the
+  streamed one (``stream_fold``). Both keep the one set and rewrite it
+  under the buffer rule stated at ``_take_staging``, which finds out from
+  the arrays whether a put aliased (``device_put`` of a host ndarray may
+  alias rather than copy);
 - the global array is assembled zero-copy on device with
   ``jax.make_array_from_single_device_arrays``.
 
@@ -285,131 +286,164 @@ def stream_to_mesh(
     into data-sharded global arrays over the driver's device mesh.
 
     One extra ``count()`` pass sizes the shards up front (Spark recomputes
-    an uncached plan the same way); the data pass then fills per-device
-    buffers and ships each to its device as it fills. ``with_weights``
-    forces a ``ws`` vector even without a ``weight_col`` (1.0 true rows /
-    0.0 pads — the pad-mask convention masked mesh programs consume).
+    an uncached plan the same way); the data pass then stages each
+    device's shard in turn and ships it to its device as it fills.
+    ``with_weights`` forces a ``ws`` vector even without a ``weight_col``
+    (1.0 true rows / 0.0 pads — the pad-mask convention masked mesh
+    programs consume).
+
+    Span ``mesh.ingest`` covers both passes; its children carry the names
+    the streamed fold uses: ``ingest.chunk`` (the pull of a batch),
+    ``ingest.stage`` (the one host copy, into the kept staging set and in
+    the dtype the device holds), ``h2d.put`` (the transfer's issue) and
+    ``stage.reclaim`` (the wait for a transfer: before the set is written
+    again, and at the end, so the rows have landed on return and the set can
+    be kept for the next ingest); ``stage.buffers{state}`` counts the sets
+    taken, a shard each.
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from spark_rapids_ml_tpu.parallel import mesh as M
+    from spark_rapids_ml_tpu.telemetry import trace_range
 
     if mesh is None:
         mesh = M.create_mesh()
-    if rows is None:
-        rows = selected.count()
-    if rows == 0:
-        raise ValueError("empty dataset")
-    dt = wire_dtype()
-    n_eff = n + 1 if augment_intercept else n
-    ndev = mesh.size
-    shard = columnar.bucket_rows(-(-rows // ndev))
-    padded_rows = shard * ndev
-    _check_size(padded_rows, n_eff, dt, mesh)
-
-    x_sharding = M.data_sharding(mesh)
-    vec_sharding = NamedSharding(mesh, P(M.DATA_AXIS))
-    devmap = x_sharding.addressable_devices_indices_map((padded_rows, n_eff))
-    devices = sorted(devmap, key=lambda d: devmap[d][0].start or 0)
-
     want_y = label_col is not None
     want_w = with_weights or bool(weight_col)
     x_parts: list[Any] = []
     y_parts: list[Any] = []
     w_parts: list[Any] = []
-
-    def fresh():
-        return (
-            np.zeros((shard, n_eff), dt),
-            np.zeros(shard, dt) if want_y else None,
-            np.zeros(shard, dt) if want_w else None,
-        )
-
-    x_buf, y_buf, w_buf = fresh()
+    staged: _StagingSet | None = None  # the set being filled
     fill = 0
     seen = 0
 
-    def flush():
-        nonlocal x_buf, y_buf, w_buf, fill
-        d = devices[len(x_parts)]
-        nbytes = x_buf.nbytes
-        x_parts.append(jax.device_put(x_buf, d))
-        if want_y:
-            nbytes += y_buf.nbytes
-            y_parts.append(jax.device_put(y_buf, d))
-        if want_w:
-            nbytes += w_buf.nbytes
-            w_parts.append(jax.device_put(w_buf, d))
-        REGISTRY.counter_inc("h2d.bytes", nbytes, path="mesh")
-        x_buf, y_buf, w_buf = fresh()
-        fill = 0
+    with trace_range("mesh.ingest"):
+        if rows is None:
+            rows = selected.count()
+        if rows == 0:
+            raise ValueError("empty dataset")
+        dt = wire_dtype()
+        n_eff = n + 1 if augment_intercept else n
+        ndev = mesh.size
+        shard = columnar.bucket_rows(-(-rows // ndev))
+        padded_rows = shard * ndev
+        _check_size(padded_rows, n_eff, dt, mesh)
 
-    for xc, yc, wc in _iter_chunks(
-        selected, features_col, label_col, weight_col,
-        est_bytes=rows * n * 8,
-    ):
-        REGISTRY.counter_inc("ingest.rows", len(xc))
-        REGISTRY.counter_inc("ingest.bytes", xc.nbytes)
-        REGISTRY.histogram_record("ingest.chunk_rows", len(xc))
-        if xc.shape[1] != n:
-            raise ValueError(
-                f"feature dimension changed mid-stream: expected {n}, got "
-                f"{xc.shape[1]} in column {features_col!r}"
-            )
-        if wc is not None:
-            # the ONE weightCol contract enforcement point (all-zero is
-            # checked globally by callers, hence allow_all_zero)
-            wc = columnar.validate_weights(wc, len(xc), allow_all_zero=True)
-        if seen + len(xc) > rows:
-            raise ValueError(
-                f"dataset produced more rows while streaming than count() "
-                f"reported ({rows}); cache() the DataFrame if its source is "
-                "nondeterministic"
-            )
-        at = 0
-        while at < len(xc):
-            take = min(shard - fill, len(xc) - at)
-            x_buf[fill : fill + take, :n] = xc[at : at + take]
-            if augment_intercept:
-                x_buf[fill : fill + take, n] = 1.0
-            if want_y:
-                y_buf[fill : fill + take] = yc[at : at + take]
-            if want_w:
-                w_buf[fill : fill + take] = (
-                    1.0 if wc is None else wc[at : at + take]
+        x_sharding = M.data_sharding(mesh)
+        vec_sharding = NamedSharding(mesh, P(M.DATA_AXIS))
+        devmap = x_sharding.addressable_devices_indices_map((padded_rows, n_eff))
+        devices = sorted(devmap, key=lambda d: devmap[d][0].start or 0)
+
+        # each device's shard is staged in turn in the one staging set the
+        # streamed fold keeps too, in the dtype the device holds, and the
+        # set is written again under the same rule (``_take_staging``)
+        key = (
+            shard, n_eff, np.dtype(jax.dtypes.canonicalize_dtype(dt)), "row",
+            want_y,
+        )
+        spare = _borrow_staging(key)  # the set last put from
+
+        def flush():
+            nonlocal staged, spare, fill
+            if staged is None:  # an empty tail shard
+                staged, spare = _take_staging(key, spare), None
+            if fill < staged.dirty:
+                with trace_range("ingest.stage"):
+                    staged.zero_from(fill)
+            d = devices[len(x_parts)]
+            nbytes = 0
+            with trace_range("h2d.put"):
+                for buf, parts, wanted in (
+                    (staged.x, x_parts, True),
+                    (staged.y, y_parts, want_y),
+                    (staged.w, w_parts, want_w),
+                ):
+                    if wanted:
+                        parts.append(jax.device_put(buf, d))
+                        staged.placed.append(parts[-1])
+                        nbytes += buf.nbytes
+            REGISTRY.counter_inc("h2d.bytes", nbytes, path="mesh")
+            spare, staged = staged, None
+            fill = 0
+
+        try:
+            for xc, yc, wc in _timed_chunks(
+                _iter_chunks(
+                    selected, features_col, label_col, weight_col,
+                    est_bytes=rows * n * 8,
                 )
-            fill += take
-            at += take
-            seen += take
-            if fill == shard:
+            ):
+                REGISTRY.counter_inc("ingest.rows", len(xc))
+                REGISTRY.counter_inc("ingest.bytes", xc.nbytes)
+                REGISTRY.histogram_record("ingest.chunk_rows", len(xc))
+                if xc.shape[1] != n:
+                    raise ValueError(
+                        f"feature dimension changed mid-stream: expected {n}, "
+                        f"got {xc.shape[1]} in column {features_col!r}"
+                    )
+                if wc is not None:
+                    # the ONE weightCol contract enforcement point (all-zero
+                    # is checked globally by callers, hence allow_all_zero)
+                    wc = columnar.validate_weights(
+                        wc, len(xc), allow_all_zero=True
+                    )
+                if seen + len(xc) > rows:
+                    raise ValueError(
+                        f"dataset produced more rows while streaming than "
+                        f"count() reported ({rows}); cache() the DataFrame if "
+                        "its source is nondeterministic"
+                    )
+                at = 0
+                while at < len(xc):
+                    if staged is None:
+                        staged, spare = _take_staging(key, spare), None
+                    take = min(shard - fill, len(xc) - at)
+                    with trace_range("ingest.stage"):
+                        staged.write(
+                            fill,
+                            xc[at : at + take],
+                            yc[at : at + take] if want_y else None,
+                            wc[at : at + take] if wc is not None else None,
+                            augment_intercept=augment_intercept,
+                        )
+                    fill += take
+                    at += take
+                    seen += take
+                    if fill == shard:
+                        flush()
+            if seen != rows:
+                raise ValueError(
+                    f"dataset produced {seen} rows while streaming but "
+                    f"count() reported {rows}; cache() the DataFrame if its "
+                    "source is nondeterministic"
+                )
+            while len(x_parts) < ndev:  # zero-pad the partial + empty tail shards
                 flush()
-    if seen != rows:
-        raise ValueError(
-            f"dataset produced {seen} rows while streaming but count() "
-            f"reported {rows}; cache() the DataFrame if its source is "
-            "nondeterministic"
-        )
-    while len(x_parts) < ndev:  # zero-pad the partial + empty tail shards
-        flush()
+            # whoever asked for the rows needs them landed, and the set is
+            # kept for the next ingest only once they are
+            with trace_range("stage.reclaim"):
+                jax.block_until_ready([x_parts, y_parts, w_parts])
+        finally:
+            _return_staging(staged or spare)
 
-    xs = jax.make_array_from_single_device_arrays(
-        (padded_rows, n_eff), x_sharding, x_parts
-    )
-    ys = (
-        jax.make_array_from_single_device_arrays(
-            (padded_rows,), vec_sharding, y_parts
+        xs = jax.make_array_from_single_device_arrays(
+            (padded_rows, n_eff), x_sharding, x_parts
         )
-        if want_y
-        else None
-    )
-    ws = (
-        jax.make_array_from_single_device_arrays(
-            (padded_rows,), vec_sharding, w_parts
+        ys = (
+            jax.make_array_from_single_device_arrays(
+                (padded_rows,), vec_sharding, y_parts
+            )
+            if want_y
+            else None
         )
-        if want_w
-        else None
-    )
+        ws = (
+            jax.make_array_from_single_device_arrays(
+                (padded_rows,), vec_sharding, w_parts
+            )
+            if want_w
+            else None
+        )
     return MeshIngest(
         xs=xs, ys=ys, ws=ws, mesh=mesh, rows=rows, padded_rows=padded_rows
     )
@@ -616,8 +650,8 @@ class _StagingSet:
     """The host buffers one chunk is staged in — ``x`` [chunk_rows, n_eff],
     ``y`` (or None) and ``w`` — in the dtype their device arrays will have,
     with the arrays put from them since they were last reclaimed. Rows
-    ``[dirty:]`` are zero. ``stream_fold`` states when a set may be written
-    again; :meth:`reclaim` is that rule's check."""
+    ``[dirty:]`` are zero. ``_take_staging`` states when a set may be
+    written again; :meth:`reclaim` is that rule's check."""
 
     def __init__(self, key):
         chunk_rows, n_eff, dtype, layout, want_y = self.key = key
@@ -631,6 +665,20 @@ class _StagingSet:
 
     def buffers(self):
         return [b for b in (self.x, self.y, self.w) if b is not None]
+
+    def write(self, fill: int, xc, yc, wc, *, augment_intercept=False) -> None:
+        """Copy a slice of a batch to rows ``[fill : fill + len(xc)]``: the
+        one host copy of these rows, and their cast to the device's dtype
+        (numpy rounds to nearest, as ``device_put`` did). No ``wc`` means
+        weight 1."""
+        n, end = xc.shape[1], fill + len(xc)
+        self.x[fill:end, :n] = xc
+        if augment_intercept:
+            self.x[fill:end, n] = 1.0
+        if self.y is not None:
+            self.y[fill:end] = yc
+        self.w[fill:end] = 1.0 if wc is None else wc
+        self.dirty = max(self.dirty, end)
 
     def zero_from(self, fill: int) -> None:
         """Zero rows ``[fill:dirty]``: what an earlier chunk left past a
@@ -658,14 +706,14 @@ class _StagingSet:
         )
 
 
-# the one staging set kept between stream_folds (one set, not two in
-# rotation: PERF.md section 6, PR 27 has both readings)
+# the one staging set kept between ingests, streamed or resident (one set,
+# not two in rotation: PERF.md section 6, PR 27 has both readings)
 _kept_staging: list[_StagingSet] = []
 _kept_staging_lock = threading.Lock()
 
 
 def _borrow_staging(key) -> _StagingSet | None:
-    """Take the kept set out of the holder for one ``stream_fold``; None
+    """Take the kept set out of the holder for one ingest; None
     where it is lent out already or was made for another key."""
     with _kept_staging_lock:
         if _kept_staging and _kept_staging[0].key == key:
@@ -674,7 +722,7 @@ def _borrow_staging(key) -> _StagingSet | None:
 
 
 def _return_staging(staging: _StagingSet | None) -> None:
-    """Give back what a ``stream_fold`` ends with. The holder keeps the
+    """Give back what an ingest ends with. The holder keeps the
     newest and drops what it had; a set whose last transfer cannot be seen
     to have landed, or whose arrays share its memory, is not kept."""
     if staging is not None and staging.reclaim(wait=False):
@@ -682,9 +730,50 @@ def _return_staging(staging: _StagingSet | None) -> None:
             _kept_staging[:] = [staging]
 
 
+def _take_staging(key, candidate: _StagingSet | None) -> _StagingSet:
+    """The set the next chunk (or shard) is staged in: ``candidate``, the
+    set last put from, where it may be written again, else a new one. THE
+    BUFFER RULE, which holds on every backend and is found out from the
+    arrays, not from a platform's name: a set is written again only after
+    (1) every array put from it is ready — a runtime may read the host
+    buffer until the transfer completes, and not after — and (2) none of
+    those arrays shares memory with it (the CPU backend puts an aligned
+    ndarray with no copy, and an identity ``put_fn`` hands the buffer itself
+    on): such a buffer goes with its array, and a new one is taken. Rows
+    past a ragged tail are zeroed before the put (``zero_from``)."""
+    from spark_rapids_ml_tpu.telemetry import trace_range
+
+    state = "fresh"
+    # another key: a bisection changed the chunk's shape
+    if candidate is not None and candidate.key == key:
+        with trace_range("stage.reclaim"):
+            reusable = candidate.reclaim()
+        if reusable:
+            REGISTRY.counter_inc("stage.buffers", state="reused")
+            return candidate
+        state = "aliased"
+    REGISTRY.counter_inc("stage.buffers", state=state)
+    return _StagingSet(key)
+
+
+def _timed_chunks(it: Iterator) -> Iterator:
+    """``it``, with the pull of each next batch from the source alone under
+    span ``ingest.chunk``; the scan and the staging copy have their own
+    spans (``ingest.scan``, ``ingest.stage``)."""
+    from spark_rapids_ml_tpu.telemetry import trace_range
+
+    while True:
+        with trace_range("ingest.chunk"):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
+
+
 def release_staging() -> None:
-    """Drop the staging set kept between streamed folds (one chunk of host
-    memory, held so that the next fold of the same shape writes into pages
+    """Drop the staging set kept between ingests (one chunk or shard of host
+    memory, held so that the next ingest of the same shape writes into pages
     that are already mapped)."""
     with _kept_staging_lock:
         _kept_staging.clear()
@@ -833,18 +922,6 @@ def stream_fold(
                 x, y, w = np.asarray(item), None, None
             yield x, y, w
 
-    def timed_chunks():
-        it = chunks()
-        while True:
-            # the pull of the next batch from the source alone; the scan and
-            # the staging copy have their own spans (ingest.scan/.stage)
-            with trace_range("ingest.chunk"):
-                try:
-                    item = next(it)
-                except StopIteration:
-                    return
-            yield item
-
     carry = init() if callable(init) else init
 
     if tune_geometry:
@@ -913,31 +990,6 @@ def stream_fold(
     spare = _borrow_staging(staging_key())
     staged: _StagingSet | None = None  # the set being filled
     fill = 0
-
-    def take_staging() -> _StagingSet:
-        """The set the next chunk is staged in. THE BUFFER RULE, which
-        holds on every backend and is found out from the arrays, not from a
-        platform's name: a set is written again only after (1) every array
-        put from it is ready — a runtime may read the host buffer until the
-        transfer completes, and not after — and (2) none of those arrays
-        shares memory with it (the CPU backend puts an aligned ndarray with
-        no copy, and an identity ``put_fn`` hands the buffer itself on):
-        such a buffer goes with its array, and a new one is taken. Rows past
-        a ragged tail are zeroed before the put (``dispatch``)."""
-        nonlocal spare
-        key = staging_key()
-        candidate, spare = spare, None
-        state = "fresh"
-        # another key: a bisection changed the chunk's shape
-        if candidate is not None and candidate.key == key:
-            with trace_range("stage.reclaim"):
-                reusable = candidate.reclaim()
-            if reusable:
-                REGISTRY.counter_inc("stage.buffers", state="reused")
-                return candidate
-            state = "aliased"
-        REGISTRY.counter_inc("stage.buffers", state=state)
-        return _StagingSet(key)
 
     # live-health heartbeat: the monitor (telemetry.health) compares
     # stream.last_beat against time.monotonic() and flags the stream stale
@@ -1071,7 +1123,7 @@ def stream_fold(
         REGISTRY.gauge_set("stream.last_beat", time.monotonic())
 
     try:
-        for xc, yc, wc in timed_chunks():
+        for xc, yc, wc in _timed_chunks(chunks()):
             REGISTRY.counter_inc("ingest.rows", len(xc))
             REGISTRY.counter_inc("ingest.bytes", xc.nbytes)
             REGISTRY.histogram_record("ingest.chunk_rows", len(xc))
@@ -1143,21 +1195,17 @@ def stream_fold(
             at = 0
             while at < len(xc):
                 if staged is None:
-                    staged = take_staging()
+                    staged, spare = _take_staging(staging_key(), spare), None
                 take = min(chunk_rows - fill, len(xc) - at)
-                # the one host copy of these rows, and their cast to the
-                # device's dtype (numpy rounds to nearest, as device_put did)
                 with trace_range("ingest.stage"):
-                    staged.x[fill : fill + take, :n] = xc[at : at + take]
-                    if augment_intercept:
-                        staged.x[fill : fill + take, n] = 1.0
-                    if want_y:
-                        staged.y[fill : fill + take] = yc[at : at + take]
-                    staged.w[fill : fill + take] = (
-                        1.0 if wc is None else wc[at : at + take]
+                    staged.write(
+                        fill,
+                        xc[at : at + take],
+                        yc[at : at + take] if want_y else None,
+                        wc[at : at + take] if wc is not None else None,
+                        augment_intercept=augment_intercept,
                     )
                 fill += take
-                staged.dirty = max(staged.dirty, fill)
                 at += take
                 seen += take
                 if fill == chunk_rows:

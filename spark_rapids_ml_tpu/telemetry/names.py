@@ -45,6 +45,9 @@ METRICS: frozenset[str] = frozenset({
     # written again), "fresh" (none to reuse) or "aliased" (the arrays put
     # from the old one share its memory, so a new one was taken)
     "stage.buffers",
+    # Lloyd iterations a fit's program ran, by path (a loop that met its
+    # tolerance or a fixed point runs fewer than maxIter)
+    "kmeans.iterations",
     # spans: duration, and duration less what child spans covered
     "span.seconds",
     "span.self_seconds",
@@ -241,6 +244,7 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "ingest.scan",
     "ingest.stage",
     "stage.reclaim",
+    "mesh.ingest",
     "model.to_host",
     "autotune.search",
     "autotune.trial",
