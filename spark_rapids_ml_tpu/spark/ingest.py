@@ -17,9 +17,11 @@ This module replaces that with a streaming fill:
   generator-produced; real pyspark uses ``toLocalIterator`` which fetches
   one partition at a time);
 - each chunk is copied into a staging set (``_StagingSet``) in the dtype
-  the device holds and ``device_put`` the moment it fills: a device's whole
-  shard on the resident path (``stream_to_mesh``), one fold chunk on the
-  streamed one (``stream_fold``). Both keep the one set and rewrite it
+  the device holds (by row blocks over a small kept pool of threads where
+  the batch is large enough to cut) and ``device_put`` the moment it
+  fills: a device's whole shard on the resident path (``stream_to_mesh``),
+  one fold chunk on the streamed one (``stream_fold``). Both keep the one
+  set and rewrite it
   under the buffer rule stated at ``_take_staging``, which finds out from
   the arrays whether a put aliased (``device_put`` of a host ndarray may
   alias rather than copy);
@@ -42,9 +44,11 @@ from __future__ import annotations
 
 import logging
 import os
+import queue
 import sys
 import threading
 import time
+from concurrent import futures
 from dataclasses import dataclass
 from typing import Any, Iterator
 
@@ -630,6 +634,120 @@ def _split_chunk_buffers(bx, by, bw, size: int):
     return out
 
 
+# ---------------------------------------------------------------------------
+# The host pass's pool: a batch's per-byte work, cut by rows over a few threads
+# ---------------------------------------------------------------------------
+
+# The non-finite scan and the cast-copy into the staging set run at one
+# core's pace on one thread; NumPy's ufuncs, reductions and casting
+# assignments release the GIL, so row blocks of one batch run side by side.
+# The sizes are read off the chip host's curve (PERF.md section 6, PR 29: a
+# 256 MiB float64 batch, 13 cores): both passes stop gaining at 4-8 threads,
+# where memory sets the pace; blocks of 16 MiB are the fastest, and under
+# 4 MiB handing a block over costs what running it there saves.
+_POOL_BLOCK_BYTES = 16 << 20
+_POOL_MIN_BLOCK_BYTES = 4 << 20
+_POOL_MAX_WORKERS = 8
+
+
+def _pool_workers() -> int:
+    """Threads a batch may keep busy: half the cores this process may run
+    on, which leaves the rest to the runtime's transfer threads and the
+    source's decode, and no more than the curve above rewards."""
+    if hasattr(os, "sched_getaffinity"):
+        cores = len(os.sched_getaffinity(0))
+    else:  # not Linux
+        cores = os.cpu_count() or 1
+    return max(1, min(_POOL_MAX_WORKERS, cores // 2))
+
+
+def _row_blocks(rows: int, nbytes: int) -> list[tuple[int, int]]:
+    """The ``(start, stop)`` row ranges a batch of ``nbytes`` is cut into:
+    whole rounds of the workers, no block over ``_POOL_BLOCK_BYTES`` or
+    under ``_POOL_MIN_BLOCK_BYTES``. One block — every toy, every
+    row-iterator chunk, a host with one core to spare — means inline."""
+    workers = _pool_workers()
+    blocks = min(
+        rows,
+        nbytes // _POOL_MIN_BLOCK_BYTES,
+        workers * -(-nbytes // (workers * _POOL_BLOCK_BYTES)),
+    )
+    if workers < 2 or blocks < 2:
+        return [(0, rows)]
+    step = -(-rows // blocks)
+    return [(at, min(at + step, rows)) for at in range(0, rows, step)]
+
+
+def _run_task(done: futures.Future, fn, start: int, stop: int) -> None:
+    try:
+        done.set_result(fn(start, stop))
+    except BaseException as e:  # noqa: BLE001 — re-raised on the caller
+        done.set_exception(e)
+
+
+class _RowPool:
+    """Daemon threads that run ``(future, fn, start, stop)`` tasks off one
+    queue until each reads its end marker."""
+
+    def __init__(self, workers: int):
+        self.tasks: queue.SimpleQueue = queue.SimpleQueue()
+        self.threads = [
+            threading.Thread(
+                target=self._work, name=f"tpu-ml-host-pass-{i}", daemon=True
+            )
+            for i in range(workers)
+        ]
+        for t in self.threads:
+            t.start()
+
+    def _work(self) -> None:
+        # a task's names die with _run_task's frame, so an idle thread
+        # holds no batch alive
+        while (task := self.tasks.get()) is not None:
+            _run_task(*task)
+            del task
+
+    def close(self) -> None:
+        for _ in self.threads:
+            self.tasks.put(None)
+
+
+# the kept pool, made on first use
+_pool: list[_RowPool] = []
+_pool_lock = threading.Lock()
+
+
+def _run_blocks(fn, blocks: list[tuple[int, int]]) -> list:
+    """``[fn(start, stop) for start, stop in blocks]``: one block on the
+    caller's thread, more on the pool, the caller waiting (inside whatever
+    span it has open; workers open none) until EVERY block has ended, an
+    error among them or not, and then raising the first error. Callers on
+    several threads share the pool."""
+    if len(blocks) == 1:
+        return [fn(*blocks[0])]
+    pending = [futures.Future() for _ in blocks]
+    # under the lock, so that no task is queued behind the end markers of a
+    # pool that release_staging() drops meanwhile
+    with _pool_lock:
+        if not _pool:
+            _pool.append(_RowPool(_pool_workers()))
+        for done, (start, stop) in zip(pending, blocks):
+            _pool[0].tasks.put((done, fn, start, stop))
+    futures.wait(pending)
+    return [done.result() for done in pending]
+
+
+def _all_finite(x: np.ndarray) -> bool:
+    """``np.isfinite(x).all()``, answered block by block (a block's bool
+    temporary is an eighth of the block, not of the batch)."""
+    return all(
+        _run_blocks(
+            lambda start, stop: bool(np.isfinite(x[start:stop]).all()),
+            _row_blocks(len(x), x.nbytes),
+        )
+    )
+
+
 def _shares_memory(placed, buf: np.ndarray) -> bool:
     """Whether ``placed`` (what a ``put_fn`` returned) lives in ``buf``'s
     bytes: a host array by its bounds, a device array by where each
@@ -669,10 +787,23 @@ class _StagingSet:
     def write(self, fill: int, xc, yc, wc, *, augment_intercept=False) -> None:
         """Copy a slice of a batch to rows ``[fill : fill + len(xc)]``: the
         one host copy of these rows, and their cast to the device's dtype
-        (numpy rounds to nearest, as ``device_put`` did). No ``wc`` means
-        weight 1."""
+        (numpy rounds to nearest, as ``device_put`` did; each element is
+        cast alone, so how the rows are cut changes no byte). The features
+        go by row blocks over the host pass's pool (``_run_blocks``) and the
+        call returns once every block is written; labels, weights and the
+        intercept column, a row's 8 bytes each, on the caller's thread.
+        Books ``ingest.batches{path}``: ``pool`` or, for a slice too small
+        to cut, ``inline``. No ``wc`` means weight 1."""
         n, end = xc.shape[1], fill + len(xc)
-        self.x[fill:end, :n] = xc
+        blocks = _row_blocks(len(xc), xc.nbytes)
+
+        def copy(start: int, stop: int) -> None:
+            self.x[fill + start : fill + stop, :n] = xc[start:stop]
+
+        _run_blocks(copy, blocks)
+        REGISTRY.counter_inc(
+            "ingest.batches", path="pool" if len(blocks) > 1 else "inline"
+        )
         if augment_intercept:
             self.x[fill:end, n] = 1.0
         if self.y is not None:
@@ -774,9 +905,14 @@ def _timed_chunks(it: Iterator) -> Iterator:
 def release_staging() -> None:
     """Drop the staging set kept between ingests (one chunk or shard of host
     memory, held so that the next ingest of the same shape writes into pages
-    that are already mapped)."""
+    that are already mapped) and the host pass's pool (its threads end once
+    the blocks already handed to them have; the next batch large enough to
+    cut starts another)."""
     with _kept_staging_lock:
         _kept_staging.clear()
+    with _pool_lock:
+        while _pool:
+            _pool.pop().close()
 
 
 def stream_fold(
@@ -817,6 +953,17 @@ def stream_fold(
     whose transfer had not landed when their fold was enqueued, and
     ``stage.buffers{state}`` whether each chunk's set was ``reused``,
     ``fresh`` or taken anew because the old one was ``aliased``.
+
+    The scan and the copy are the host's per-byte work on a batch, and a
+    batch large enough to cut has both run by row blocks on the host pass's
+    pool of threads (``_row_blocks``, ``_run_blocks``): this thread hands the
+    blocks over and waits, inside ``ingest.scan`` and ``ingest.stage``, so
+    the spans stay one a batch and read the wall time of the pass. A batch
+    too small to cut runs here, through the same helpers
+    (``ingest.batches{path}`` says which). What a block of the scan answers
+    is only whether all of it is finite: a "no" sends the batch to the
+    per-row mask, the filter, the count and the error below, on this thread
+    and as they always were.
 
     ``source`` is either a DataFrame-shaped object (localspark / pyspark —
     drained via the same strategy-gated ``_iter_chunks`` the resident
@@ -1159,8 +1306,9 @@ def stream_fold(
                 with trace_range("ingest.scan"):
                     if not (
                         # scalar pre-check keeps the all-finite fast path
-                        # off the per-row mask allocation
-                        np.isfinite(xc).all()
+                        # off the per-row mask allocation; a "no" from any
+                        # block falls through to the rows, on this thread
+                        _all_finite(xc)
                         and (yc is None or np.isfinite(yc).all())
                         and (wc is None or np.isfinite(wc).all())
                     ):

@@ -45,6 +45,11 @@ METRICS: frozenset[str] = frozenset({
     # written again), "fresh" (none to reuse) or "aliased" (the arrays put
     # from the old one share its memory, so a new one was taken)
     "stage.buffers",
+    # one increment for each slice of a batch copied into a staging set
+    # (a batch, or each part of one that straddles a chunk's end), by the
+    # path its copy took: path="pool" (cut by rows over the host pass's
+    # threads) or "inline" (too small to cut: the caller's thread)
+    "ingest.batches",
     # Lloyd iterations a fit's program ran, by path (a loop that met its
     # tolerance or a fixed point runs fewer than maxIter)
     "kmeans.iterations",

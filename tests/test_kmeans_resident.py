@@ -274,3 +274,77 @@ class TestResidentStaging:
         assert self.states(moved) == {"reused": 1, "fresh": 0, "aliased": 0}
         assert ingest._kept_staging[0] is kept
         np.testing.assert_allclose(res.carry.xtx, mat.T @ mat, rtol=1e-12)
+
+
+class TestResidentHostPassPool:
+    """``stream_to_mesh`` gets the host pass's pool through the one
+    ``_StagingSet.write``: a shard staged by rows over threads is bit for bit
+    the shard staged inline, and a batch books the path its copy took."""
+
+    ROWS = 1500
+
+    @staticmethod
+    def frame(rows, labeled):
+        import pyarrow as pa
+
+        rng = np.random.default_rng(29)
+        mat = rng.normal(size=(rows, N)) * 1e3
+        table = data.to_table([mat], [0])
+        if labeled:
+            table = table.append_column("y", pa.array(rng.normal(size=rows)))
+            table = table.append_column("w", pa.array(rng.uniform(0.5, 2.0, size=rows)))
+
+        class Frame:
+            def count(self):
+                return rows
+
+            def _parts(self):
+                yield table.to_batches(max_chunksize=400)
+
+        return Frame()
+
+    def ingest(self, mesh, labeled):
+        kw = dict(label_col="y", weight_col="w", augment_intercept=True) if labeled else {}
+        before = REGISTRY.snapshot()
+        seq = TIMELINE.seq()
+        ing = ingest.stream_to_mesh(
+            self.frame(self.ROWS, labeled), features_col=data.COLUMN, n=N, mesh=mesh,
+            with_weights=True, **kw,
+        )
+        moved = REGISTRY.snapshot().delta(before)
+        arrays = [np.asarray(a) for a in (ing.xs, ing.ys, ing.ws) if a is not None]
+        stages = [
+            e for e in TIMELINE.events(seq)
+            if e["cat"] == "span" and e["name"] == "ingest.stage"
+        ]
+        return arrays, moved, stages
+
+    @pytest.mark.parametrize("ndev", [1, 4])
+    @pytest.mark.parametrize("labeled", [False, True])
+    def test_a_pooled_ingest_is_bitwise_the_inline_one(self, monkeypatch, ndev, labeled):
+        import threading
+
+        put = jax.device_put
+        # a put whose result owns its bytes, as a TPU's does: every shard
+        # rewrites the one set, in both ingests alike
+        monkeypatch.setattr(
+            jax, "device_put", lambda a, *rest, **kw: put(np.array(a), *rest, **kw)
+        )
+        mesh = M.create_mesh(devices=jax.devices()[:ndev])
+        inline, moved, inline_stages = self.ingest(mesh, labeled)
+        assert int(moved.counter("ingest.batches", path="pool")) == 0
+        assert int(moved.counter("ingest.batches", path="inline")) >= 4
+        assert not ingest._pool
+        # four workers whatever the host has, and blocks of 8 to 32 rows
+        monkeypatch.setattr(ingest, "_pool_workers", lambda: 4)
+        monkeypatch.setattr(ingest, "_POOL_MIN_BLOCK_BYTES", 8 * N * 8)
+        monkeypatch.setattr(ingest, "_POOL_BLOCK_BYTES", 32 * N * 8)
+        pooled, moved, pooled_stages = self.ingest(mesh, labeled)
+        assert int(moved.counter("ingest.batches", path="pool")) >= 4
+        assert len(ingest._pool) == 1
+        assert len(pooled) == (3 if labeled else 2)
+        for a, b in zip(inline, pooled):
+            assert a.dtype == b.dtype and a.tobytes() == b.tobytes()
+        # the spans a batch are what they were, on the caller's thread
+        assert len(pooled_stages) == len(inline_stages)
+        assert {e["tid"] for e in pooled_stages} == {threading.get_native_id()}

@@ -622,3 +622,292 @@ class TestStagingSet:
         (kept,) = ingest._kept_staging
         assert kept.key[0] == self.CHUNK // 2
         assert self.states(moved)["fresh"] == 2
+
+
+class TestHostPassPool:
+    """A batch's host pass — the non-finite scan and the cast-copy into the
+    staging set — is cut by rows over a kept pool of threads when the batch
+    is large enough to cut, and runs inline through the same helpers when it
+    is not. Whatever the path: the same bytes in the set, the same answers
+    from ``raise`` and ``skip``, the same spans a batch."""
+
+    N = 6
+    ROW_BYTES = N * 8
+
+    @pytest.fixture(autouse=True)
+    def empty_holder(self):
+        ingest.release_staging()
+        yield
+        ingest.release_staging()
+
+    @classmethod
+    def cut_small(cls, monkeypatch):
+        """Four workers whatever the host has, and blocks of 8 to 32 rows:
+        the toys below are cut as a 256 MiB batch is on the chip's host."""
+        monkeypatch.setattr(ingest, "_pool_workers", lambda: 4)
+        monkeypatch.setattr(ingest, "_POOL_MIN_BLOCK_BYTES", 8 * cls.ROW_BYTES)
+        monkeypatch.setattr(ingest, "_POOL_BLOCK_BYTES", 32 * cls.ROW_BYTES)
+
+    @pytest.fixture
+    def small_blocks(self, monkeypatch):
+        self.cut_small(monkeypatch)
+
+    def rows(self, n_rows, seed=5):
+        rng = np.random.default_rng(seed)
+        return np.asarray(rng.normal(size=(n_rows, self.N)), np.float64)
+
+    @staticmethod
+    def paths(moved):
+        return {
+            path: int(moved.counter("ingest.batches", path=path))
+            for path in ("pool", "inline")
+        }
+
+    def fold(self, batches, **kw):
+        from spark_rapids_ml_tpu.telemetry import REGISTRY
+
+        before = REGISTRY.snapshot()
+        res = ingest.stream_fold(
+            iter(batches),
+            L.gram_fold_step(),
+            n=self.N,
+            init=L.init_gram_carry(self.N, np.float64),
+            chunk_rows=kw.pop("chunk_rows", 512),
+            put_fn=TestStagingSet.copying_put(),
+            **kw,
+        )
+        return res, REGISTRY.snapshot().delta(before)
+
+    @pytest.mark.parametrize(
+        "rows, nbytes, workers, want",
+        [
+            (1000, 1000 * 48, 6, 1),            # a toy: one block, inline
+            (65536, (8 << 20) - 1, 6, 1),       # under two 4 MiB blocks
+            (65536, 8 << 20, 6, 2),             # two of 4 MiB
+            (78125, 78125 * 1024, 6, 6),        # the KMeans cell's batch
+            (16384, 256 << 20, 6, 18),          # the PCA cells' batch: 3 rounds
+            (65536, 256 << 20, 8, 16),
+            (16384, 256 << 20, 1, 1),           # one core to spare: inline
+            (3, 256 << 20, 6, 3),               # never more blocks than rows
+            (0, 0, 6, 1),
+        ],
+    )
+    def test_blocks_cover_every_row_once(self, monkeypatch, rows, nbytes, workers, want):
+        monkeypatch.setattr(ingest, "_pool_workers", lambda: workers)
+        blocks = ingest._row_blocks(rows, nbytes)
+        assert len(blocks) == want
+        assert blocks[0][0] == 0 and blocks[-1][1] == rows
+        assert all(a[1] == b[0] for a, b in zip(blocks, blocks[1:]))
+        sizes = [stop - start for start, stop in blocks]
+        assert max(sizes) - min(sizes) <= max(sizes) // 2 + 1  # even but for the last
+        if want > 1:
+            assert max(sizes) * (nbytes // rows) <= ingest._POOL_BLOCK_BYTES + nbytes // rows
+
+    def test_the_worker_rule_follows_the_cores_the_process_may_run_on(self, monkeypatch):
+        import os
+
+        for cores, want in ((1, 1), (2, 1), (4, 2), (8, 4), (13, 6), (16, 8), (96, 8)):
+            monkeypatch.setattr(
+                os, "sched_getaffinity", lambda pid, c=cores: set(range(c)), raising=False
+            )
+            assert ingest._pool_workers() == want
+
+    @pytest.mark.parametrize("layout", ["row", "col"])
+    @pytest.mark.parametrize("rows", [257, 300, 511])
+    @pytest.mark.parametrize("extras", [False, True])
+    def test_pooled_write_is_bitwise_the_inline_write(
+        self, monkeypatch, layout, rows, extras
+    ):
+        """float64 rows into a float32 set at a ragged fill, with and without
+        labels, weights and the intercept column."""
+        from spark_rapids_ml_tpu.telemetry import REGISTRY
+
+        x = self.rows(rows) * 1e3
+        y = self.rows(rows, seed=6)[:, 0] if extras else None
+        w = np.abs(self.rows(rows, seed=7)[:, 0]) if extras else None
+        n_eff = self.N + 1 if extras else self.N
+        key = (600, n_eff, np.dtype(np.float32), layout, extras)
+        sets = {}
+        for path in ("inline", "pool"):
+            if path == "pool":
+                self.cut_small(monkeypatch)
+            before = REGISTRY.snapshot()
+            s = sets[path] = ingest._StagingSet(key)
+            s.write(13, x, y, w, augment_intercept=extras)
+            moved = self.paths(REGISTRY.snapshot().delta(before))
+            assert moved == {"pool": int(path == "pool"), "inline": int(path == "inline")}
+            assert s.dirty == 13 + rows
+        for a, b in zip(sets["inline"].buffers(), sets["pool"].buffers()):
+            assert a.tobytes(order="A") == b.tobytes(order="A")
+            assert a.flags.f_contiguous == b.flags.f_contiguous
+        got = sets["pool"].x
+        np.testing.assert_array_equal(got[13 : 13 + rows, : self.N], x.astype(np.float32))
+        assert not got[:13].any() and not got[13 + rows :].any()
+        if extras:
+            assert (got[13 : 13 + rows, self.N] == 1.0).all()
+
+    @pytest.mark.parametrize("value", [np.nan, np.inf, -np.inf])
+    @pytest.mark.parametrize("where", ["first", "middle", "last"])
+    def test_a_bad_value_in_any_block_is_found(self, small_blocks, value, where):
+        x = self.rows(400)
+        blocks = ingest._row_blocks(len(x), x.nbytes)
+        assert len(blocks) >= 8
+        start, stop = {"first": blocks[0], "middle": blocks[len(blocks) // 2],
+                       "last": blocks[-1]}[where]
+        bad = x.copy()
+        bad[start, 1] = value
+        bad[stop - 1, self.N - 1] = value
+        assert ingest._all_finite(x) and not ingest._all_finite(bad)
+        with pytest.raises(ValueError, match=r"^2 non-finite input row\(s\) in a streamed chunk; "
+                           "set TPU_ML_NONFINITE_POLICY=skip"):
+            self.fold([bad], nonfinite="raise")
+        res, moved = self.fold([x[:100], bad, x[:50]], nonfinite="skip")
+        assert res.skipped_rows == 2 and res.rows == 100 + 398 + 50
+        assert int(moved.counter("rows.nonfinite_skipped")) == 2
+        keep = np.ones(len(x), bool)
+        keep[[start, stop - 1]] = False
+        kept = np.concatenate([x[:100], x[keep], x[:50]])
+        np.testing.assert_allclose(res.carry.xtx, kept.T @ kept, rtol=1e-12)
+
+    def test_a_bad_label_or_weight_is_still_found_on_the_callers_thread(self, small_blocks):
+        from spark_rapids_ml_tpu.ops import linear as LIN
+
+        x = self.rows(400)
+        y = x[:, 0].copy()
+        y[399] = np.inf
+        with pytest.raises(ValueError, match=r"^1 non-finite input row"):
+            ingest.stream_fold(
+                iter([(x, y)]), LIN.linear_fold_step(), n=self.N, label_col="y",
+                init=LIN.init_linear_carry(self.N, np.float64), chunk_rows=512,
+            )
+
+    @pytest.mark.parametrize("rows", [0, 1, 7, 400])
+    def test_all_finite_is_numpys_answer(self, small_blocks, rows):
+        x = self.rows(rows)
+        assert ingest._all_finite(x) is True
+        if rows:
+            x[rows // 2, 3] = np.nan
+            assert ingest._all_finite(x) is False
+
+    @pytest.mark.parametrize("nonfinite", ["raise", "allow"])
+    def test_a_workers_error_reaches_the_caller_and_the_set_goes_back(
+        self, small_blocks, nonfinite
+    ):
+        """An object column no cast can take: under ``raise`` the scan's
+        worker fails (TypeError), under ``allow`` the copy's (ValueError)."""
+        x = self.rows(400)
+        self.fold([x])
+        (kept,) = ingest._kept_staging
+        broken = x.astype(object)
+        broken[399, 0] = "not a number"
+        with pytest.raises(TypeError if nonfinite == "raise" else ValueError):
+            self.fold([x[:64], broken], nonfinite=nonfinite)
+        assert ingest._kept_staging == [kept] and not kept.placed
+        if nonfinite == "allow":
+            # every other block was written before the error came back
+            np.testing.assert_array_equal(kept.x[64 : 64 + 360], x[:360])
+        # and the pool still serves
+        res, moved = self.fold([x])
+        assert self.paths(moved)["pool"] == 1
+        np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
+
+    def test_a_batch_is_booked_by_the_path_its_copy_took(self, monkeypatch):
+        """The real sizes: under two blocks of 4 MiB a batch runs inline."""
+        from spark_rapids_ml_tpu.telemetry import REGISTRY, names
+
+        assert "ingest.batches" in names.METRICS
+        assert "ingest.batches" not in names.HISTOGRAMS | names.GAUGES
+        monkeypatch.setattr(ingest, "_pool_workers", lambda: 4)
+        rows = (8 << 20) // 8
+        s = ingest._StagingSet((rows, 1, np.dtype(np.float32), "row", False))
+        for take, path in ((rows - 1, "inline"), (rows, "pool")):
+            before = REGISTRY.snapshot()
+            s.write(0, np.ones((take, 1)), None, None)
+            moved = self.paths(REGISTRY.snapshot().delta(before))
+            assert moved == {"pool": int(path == "pool"), "inline": int(path == "inline")}
+        # a host with no core to spare never cuts
+        monkeypatch.setattr(ingest, "_pool_workers", lambda: 1)
+        before = REGISTRY.snapshot()
+        s.write(0, np.ones((rows, 1)), None, None)
+        assert self.paths(REGISTRY.snapshot().delta(before)) == {"pool": 0, "inline": 1}
+
+    def test_every_toy_runs_inline_and_starts_no_thread(self, data):
+        x, _, _ = data
+        res, moved = self.fold(np.array_split(x[:, : self.N], 5))
+        assert self.paths(moved) == {"pool": 0, "inline": 5 + res.chunks - 1}
+        assert not ingest._pool
+
+    @pytest.mark.parametrize("batches", [1, 3, 7])
+    def test_spans_a_batch_are_what_they_were(self, small_blocks, batches):
+        """One ``ingest.scan`` and one ``ingest.stage`` a batch, on the
+        caller's thread; the workers open none."""
+        import threading
+
+        from spark_rapids_ml_tpu.telemetry import TIMELINE
+
+        x = self.rows(1100)
+        reset_metrics()
+        seq = TIMELINE.seq()
+        res, moved = self.fold(np.array_split(x, batches))
+        m = metrics()
+        assert self.paths(moved)["pool"] >= batches
+        assert m["ingest.scan"]["count"] == batches
+        assert max(batches, res.chunks) <= m["ingest.stage"]["count"] <= batches + res.chunks
+        spans = [e for e in TIMELINE.events(seq) if e["cat"] == "span"]
+        assert {e["tid"] for e in spans} == {threading.get_native_id()}
+        np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
+
+    def test_folds_on_many_threads_share_one_pool_and_stay_exact(self, small_blocks):
+        """More callers than workers, the interpreter switching threads
+        every 10 us: a lost or crossed block would show in a Gram."""
+        import sys
+        import threading
+
+        xs = [self.rows(700 + 13 * i, seed=20 + i) for i in range(8)]
+        out = {}
+
+        def caller(i):
+            for _ in range(3):
+                res = ingest.stream_fold(
+                    iter(np.array_split(xs[i], 3)),
+                    L.gram_fold_step(),
+                    n=self.N,
+                    init=L.init_gram_carry(self.N, np.float64),
+                    chunk_rows=256,
+                    put_fn=TestStagingSet.copying_put(),
+                )
+                out.setdefault(i, []).append(np.asarray(res.carry.xtx))
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [threading.Thread(target=caller, args=(i,)) for i in range(8)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        (pool,) = ingest._pool
+        assert len(pool.threads) == 4 and all(t.daemon for t in pool.threads)
+        for i, x in enumerate(xs):
+            assert len(out[i]) == 3
+            for got in out[i]:
+                np.testing.assert_allclose(got, x.T @ x, rtol=1e-12)
+
+    def test_release_staging_drops_the_pool_and_the_next_batch_starts_another(
+        self, small_blocks
+    ):
+        x = self.rows(400)
+        self.fold([x])
+        (pool,) = ingest._pool
+        assert all(t.is_alive() and t.daemon for t in pool.threads)
+        ingest.release_staging()
+        assert not ingest._pool
+        for t in pool.threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in pool.threads)
+        res, moved = self.fold([x])
+        assert self.paths(moved)["pool"] == 1 and ingest._pool[0] is not pool
+        np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
