@@ -125,21 +125,14 @@ def _paired_slope(short_call, long_call, iter_delta: int, reps: int):
     ``reps`` times, take the median (r2 weak #4: min-of-N drifted 27%).
     Raises on a non-positive median — a noisy inversion must fail the
     metric loudly, never publish a negative throughput."""
-    from spark_rapids_ml_tpu import autotune
     from spark_rapids_ml_tpu.telemetry import reset_metrics
 
-    # timed reps must be geometry-deterministic: pin the tuner to read-only
-    # cache mode (no opportunistic searching inside a timed window) and
-    # clear any in-process winners an earlier stage searched, so every rep
-    # runs the same static-knob program
-    os.environ[knobs.AUTOTUNE.name] = "cache"
     slopes = []
     for _ in range(reps):
         # per-pair registry window: phase numbers in the embedded telemetry
         # snapshot attribute to the LAST (short, long) pair of the last
         # metric, never to the whole accumulated session
         reset_metrics()
-        autotune.reset()
         t0 = time.perf_counter()
         short_call()
         t_short = time.perf_counter() - t0
@@ -228,15 +221,14 @@ def _ledger_entry(record: dict) -> dict:
         },
     }
     # stamp the tuning signature ONLY when the run deviates from the
-    # defaults (tuner searching, or a non-f32 precision policy): default
-    # runs omit the key, so their sentinel signature stays "{}" and keeps
-    # matching pre-autotuner ledger history (tools/perf_sentinel.py)
-    from spark_rapids_ml_tpu import autotune
+    # default (a non-f32 precision policy): default runs omit the key, so
+    # their sentinel signature stays "{}" and keeps matching the ledger
+    # history from before the field (tools/perf_sentinel.py)
+    from spark_rapids_ml_tpu.ops.policy import resolve_policy
 
-    tuner_mode = autotune.mode()
-    policy = autotune.resolve_policy(None)
-    if tuner_mode != "cache" or policy != "f32":
-        entry["tuning"] = {"mode": tuner_mode, "policy": policy}
+    policy = resolve_policy(None)
+    if policy != "f32":
+        entry["tuning"] = {"policy": policy}
     return entry
 
 
@@ -438,18 +430,6 @@ def main() -> None:
         print(f"# streamed-fit bench skipped: {e!r}", file=sys.stderr)
         sf_rows_per_s = sf_overlapped = sf_overlap_fraction = None
 
-    # --- ledger-driven autotuner proof (this PR) --------------------------
-    # a bounded search must select a winner and make the repeat fit a pure
-    # cache hit; in --smoke this is a hard contract (the stage exists to
-    # catch tuner bitrot), on the real chip it is guarded like its siblings
-    try:
-        autotune_evidence = _bench_autotune()
-    except Exception as e:
-        if SMOKE:
-            raise
-        print(f"# autotune bench skipped: {e!r}", file=sys.stderr)
-        autotune_evidence = None
-
     # --- live health/SLO exporter proof (this PR) -------------------------
     # the exporter must serve a parse-clean scrape of the counters the
     # streamed-fit stage above just recorded, and /healthz must say OK on
@@ -604,13 +584,9 @@ def main() -> None:
                     "pairs": PAIRS,
                 },
                 "derived": derived,
-                # tuner evidence rides as a plain record field, NOT an
-                # extra_metric: its "trials" count would otherwise enter
-                # the sentinel's ratio checks and false-trip on budget
-                # changes
-                "autotune": autotune_evidence,
-                # exporter evidence likewise rides as a record field: the
-                # scrape byte count is diagnostics, not a perf metric
+                # exporter evidence rides as a plain record field, NOT an
+                # extra_metric: the scrape byte count is diagnostics, not
+                # a perf metric
                 "health": health_evidence,
                 # serving evidence rides as a record field for
                 # tools/serve_report.py; only its three headline numbers
@@ -1021,82 +997,6 @@ def _bench_streamed_fit() -> tuple[float, int, float | None]:
     ov = REGISTRY.snapshot().delta(reg0).hist("stream.overlap_fraction")
     overlap_fraction = (ov.total / ov.count) if ov.count else None
     return SF_ROWS / statistics.median(times), overlapped, overlap_fraction
-
-
-def _bench_autotune() -> dict:
-    """Prove the ledger-driven tuner end to end on this backend: a bounded
-    ``TPU_ML_AUTOTUNE=search`` run (<= 3 timing trials) over the streamed
-    Gram fold must select a winning TuningConfig, and an immediately
-    repeated fit of the same shape bucket must be a pure cache hit — zero
-    new search trials, counter-asserted. Returns the evidence dict that
-    rides the bench JSON line (non-metric: trial counts must never enter
-    the perf-sentinel ratio checks)."""
-    from spark_rapids_ml_tpu import autotune
-    from spark_rapids_ml_tpu.ops import linalg as L
-    from spark_rapids_ml_tpu.spark import ingest
-    from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
-
-    rng = np.random.default_rng(11)
-    n_chunks = max(2, (SF_ROWS // SF_CHUNK) // 4)
-    chunk = rng.normal(size=(SF_CHUNK, SF_N)).astype(ingest.wire_dtype())
-
-    saved = {
-        name: os.environ.get(name)
-        for name in (
-            knobs.AUTOTUNE.name,
-            knobs.AUTOTUNE_TRIALS.name,
-            knobs.STREAM_CHUNK_ROWS.name,
-        )
-    }
-    autotune.reset()
-    os.environ[knobs.AUTOTUNE.name] = "search"
-    os.environ[knobs.AUTOTUNE_TRIALS.name] = "3"
-    os.environ[knobs.STREAM_CHUNK_ROWS.name] = str(SF_CHUNK)
-    try:
-
-        def fit():
-            # chunk_rows deliberately unset: the tuner owns the geometry
-            return ingest.stream_fold(
-                (chunk for _ in range(n_chunks)),
-                L.gram_fold_step(),
-                n=SF_N,
-                init=L.init_gram_carry(SF_N, ingest.wire_dtype()),
-            )
-
-        snap0 = REGISTRY.snapshot()
-        fit()
-        mid = REGISTRY.snapshot()
-        first = mid.delta(snap0)
-        trials = first.counter("autotune.trials")
-        searches = first.counter("autotune.search_runs")
-        if searches != 1 or not 0 < trials <= 3:
-            raise RuntimeError(
-                f"autotune search contract broken: {searches:g} search "
-                f"run(s), {trials:g} trial(s) (expected 1 run, 1..3 trials)"
-            )
-        fit()
-        repeat = REGISTRY.snapshot().delta(mid)
-        repeat_trials = repeat.counter("autotune.trials")
-        repeat_hits = repeat.counter("autotune.cache_hits")
-        if repeat_trials or not repeat_hits:
-            raise RuntimeError(
-                f"repeat fit was not a pure cache hit: "
-                f"{repeat_trials:g} new trial(s), {repeat_hits:g} hit(s)"
-            )
-        key, entry = next(iter(autotune.cache.entries().items()))
-        return {
-            "searched_trials": int(trials),
-            "cache_key": key,
-            "winner": entry.get("config"),
-            "repeat_cache_hit": True,
-        }
-    finally:
-        for name, val in saved.items():
-            if val is None:
-                os.environ.pop(name, None)
-            else:
-                os.environ[name] = val
-        autotune.reset()
 
 
 def _bench_health() -> dict:

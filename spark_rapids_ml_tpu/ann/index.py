@@ -50,6 +50,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from spark_rapids_ml_tpu.models.base import Estimator
 from spark_rapids_ml_tpu.models.neighbors import (
@@ -114,10 +115,12 @@ _ASSIGN = jax.jit(KM.assign_clusters)
 
 @lru_cache(maxsize=None)
 def _lloyd_mesh_fold_prog(mesh):
-    """Mesh-sharded Lloyd fold: carry leaves are [ndev, ...] stacked
-    partials (parallel/gram stacked-partials protocol), each device folds
-    its chunk shard into its own slice collective-free; the per-iteration
-    allreduce happens once at finalize, not per chunk."""
+    """Mesh-sharded Lloyd fold: the carry is the ``KMeansStats`` of a pass as
+    [ndev, ...] stacked partials (parallel/gram stacked-partials protocol),
+    each device folds its chunk shard into its own slice collective-free;
+    the per-iteration allreduce happens once at finalize, not per chunk.
+    The centers are a replicated traced argument: they change every
+    iteration without recompiling the program."""
     from jax.sharding import PartitionSpec as P
 
     from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
@@ -125,43 +128,17 @@ def _lloyd_mesh_fold_prog(mesh):
     @partial(
         jax.shard_map,
         mesh=mesh,
-        in_specs=(P(DATA_AXIS), P(DATA_AXIS, None), P(DATA_AXIS)),
+        in_specs=(P(DATA_AXIS), P(), P(DATA_AXIS, None), P(DATA_AXIS)),
         out_specs=P(DATA_AXIS),
         check_vma=False,
     )
-    def _fold(carry, xl, wl):
-        st = KM.kmeans_stats(xl, carry.centers[0], weights=wl)
-        return _LloydCarry(
-            carry.sums + st.sums[None],
-            carry.counts + st.counts[None],
-            carry.cost + st.cost[None],
-            carry.centers,
-        )
+    def _fold(carry, centers, xl, wl):
+        st = KM.kmeans_stats(xl, centers, weights=wl)
+        return jax.tree.map(lambda c, s: c + s[None], carry, st)
 
     # one program per mesh, built through this lru_cache factory
     # (parallel/gram._chunk_fold_prog rationale)  # tpulint: disable=TPL003
     return jax.jit(_fold, donate_argnums=0)
-
-
-def _init_mesh_carry(centers: np.ndarray, mesh, dtype):
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
-
-    ndev = mesh.shape[DATA_AXIS]
-    k, n = centers.shape
-    shard = NamedSharding(mesh, P(DATA_AXIS))
-
-    def put(a):
-        return jax.device_put(a, shard)
-
-    return _LloydCarry(
-        sums=put(np.zeros((ndev, k, n), dtype)),
-        counts=put(np.zeros((ndev, k), dtype)),
-        cost=put(np.zeros((ndev,), dtype)),
-        # every device folds against its own full copy of the centers
-        centers=put(np.broadcast_to(centers, (ndev, k, n)).copy()),
-    )
 
 
 # -- host-side streaming helpers --------------------------------------------
@@ -465,12 +442,9 @@ class IVFFlatIndex(_ANNParams, Estimator):
         from spark_rapids_ml_tpu.spark import ingest
 
         mesh = self._mesh_or_none()
-        if mesh is not None:
-            from spark_rapids_ml_tpu.parallel import gram as G
-            from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+        k = centers.shape[0]
         for _ in range(self.getOrDefault("maxIter")):
             if mesh is None:
-                k = centers.shape[0]
                 res = ingest.stream_fold(
                     chunks(),
                     _LLOYD_FOLD_STEP,
@@ -487,24 +461,24 @@ class IVFFlatIndex(_ANNParams, Estimator):
                     res.carry.sums, res.carry.counts, res.carry.cost
                 )
             else:
-                res = ingest.stream_fold(
-                    chunks(),
-                    _lloyd_mesh_fold_prog(mesh),
-                    n=n,
-                    init=_init_mesh_carry(np.asarray(centers), mesh, dt),
-                    rows=rows,
-                    chunk_rows=G.stream_chunk_rows_for_mesh(
-                        mesh, n=n, rows=rows, dtype=dt
-                    ),
-                    put_fn=G.chunk_put(mesh),
-                    min_chunk_rows=mesh.shape[DATA_AXIS],
+                # every device folds against its own full copy of the centers
+                placed = jax.device_put(
+                    np.asarray(centers), NamedSharding(mesh, PartitionSpec())
                 )
-                stats = G.finalize_chunk_fold(
+                stats = ingest.stream_fold_over_mesh(
+                    chunks(),
+                    lambda c, x, w: _lloyd_mesh_fold_prog(mesh)(
+                        c, placed, x, w
+                    ),
                     KM.KMeansStats(
-                        res.carry.sums, res.carry.counts, res.carry.cost
+                        sums=jax.ShapeDtypeStruct((k, n), dt),
+                        counts=jax.ShapeDtypeStruct((k,), dt),
+                        cost=jax.ShapeDtypeStruct((), dt),
                     ),
                     mesh,
-                )
+                    n=n,
+                    rows=rows,
+                ).carry
             old = jnp.asarray(centers)
             new = KM.update_centers(stats, old)
             shift = float(KM.center_shift_sq(old, new))

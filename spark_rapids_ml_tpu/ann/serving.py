@@ -34,8 +34,8 @@ from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 
 
 @functools.lru_cache(maxsize=None)
-def _query_kernel(k: int, nprobe: int, policy: str):
-    """The pure ``kernel(params, x)`` for one (k, nprobe, policy) operating
+def _query_kernel(k: int, nprobe: int):
+    """The pure ``kernel(params, x)`` for one (k, nprobe) operating
     point — cached so every registered index at the same point shares one
     traceable, and the registry's AOT cache keys stay stable."""
     import jax.numpy as jnp
@@ -53,7 +53,6 @@ def _query_kernel(k: int, nprobe: int, policy: str):
             nprobe,
             spill_items=params["spill_items"],
             spill_ids=params["spill_ids"],
-            policy=policy,
         )
         if scores.dtype == jnp.float32:
             enc = lax.bitcast_convert_type(idx, jnp.float32)
@@ -136,7 +135,6 @@ def servable_from_index(name: str, model) -> "ServableEntry":
     nprobe = min(model.getNprobe(), nlist)
     metric = model.getMetric()
     x_dtype = R._device_dtype()
-    policy = R._consult_policy("ann", n)
     spill_items = model.spillItems
     spill_ids = model.spillIds
     if spill_items is None:
@@ -154,12 +152,11 @@ def servable_from_index(name: str, model) -> "ServableEntry":
         family="ann",
         model_cls=type(model).__name__,
         n_features=n,
-        kernel=_query_kernel(k, nprobe, policy),
+        kernel=_query_kernel(k, nprobe),
         params=params,
         prepare=_make_prepare(metric),
         finalize=_make_finalize(k, metric, np.asarray(model.itemIds)),
         x_dtype=x_dtype,
-        policy=policy,
         model=model,
     )
 

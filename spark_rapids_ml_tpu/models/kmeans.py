@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from spark_rapids_ml_tpu.autotune.policy import resolve_policy
+from spark_rapids_ml_tpu.ops.policy import resolve_policy
 from spark_rapids_ml_tpu.models.base import Estimator, Model
 from spark_rapids_ml_tpu.models.params import HasInputCol, HasOutputCol, Param
 from spark_rapids_ml_tpu.ops import kmeans as KM

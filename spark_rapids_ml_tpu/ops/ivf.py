@@ -21,7 +21,7 @@ cluster's members contiguously, and answer queries by scanning only the
   (``einsum('qn,qcn->qc')``), merging into a running top-k with the same
   tournament primitive exact k-NN uses (ops/neighbors.merge_topk). The
   spill list is scored with one reused [q, n]×[n, spill] MXU matmul;
-- the distance cross terms honor the autotune ``PrecisionPolicy``
+- the distance cross terms honor the ``PrecisionPolicy``
   vocabulary exactly like exact k-NN (ops/neighbors._block_scores):
   ``bf16_f32acc`` casts operands to bfloat16 with f32 MXU accumulation,
   ``int8_dist`` runs the symmetric per-tensor int8 quantized cross term.
@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 import numpy as np
 
-from spark_rapids_ml_tpu.autotune.policy import PrecisionPolicy
+from spark_rapids_ml_tpu.ops.policy import PrecisionPolicy
 from spark_rapids_ml_tpu.ops.linalg import (
     DEFAULT_PRECISION,
     DEFAULT_POLICY,

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from spark_rapids_ml_tpu.autotune.policy import PrecisionPolicy
+from spark_rapids_ml_tpu.ops.policy import PrecisionPolicy
 from spark_rapids_ml_tpu.ops.linalg import (
     DEFAULT_PRECISION,
     DEFAULT_POLICY,

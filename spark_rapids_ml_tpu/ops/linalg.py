@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from spark_rapids_ml_tpu.autotune.policy import (
+from spark_rapids_ml_tpu.ops.policy import (
     FOLD_POLICIES,
     PrecisionPolicy,
     resolve_policy,

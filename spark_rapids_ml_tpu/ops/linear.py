@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from spark_rapids_ml_tpu.autotune.policy import FOLD_POLICIES, resolve_policy
+from spark_rapids_ml_tpu.ops.policy import FOLD_POLICIES, resolve_policy
 from spark_rapids_ml_tpu.ops.linalg import (
     DEFAULT_PRECISION,
     DEFAULT_POLICY,

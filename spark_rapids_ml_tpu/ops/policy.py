@@ -1,13 +1,10 @@
-"""Precision policies and tuning configurations — the *vocabulary* of the
-autotuner.
+"""Precision policies — the kernels' mixed-precision vocabulary.
 
 A :class:`PrecisionPolicy` names how a kernel's matmuls treat operand and
-accumulator dtypes; a :class:`TuningConfig` bundles everything the tuner may
-vary for one kernel signature (chunk geometry, staging layout, precision
-policy, donation arrangement). Both are plain data: the numeric behavior
-lives in the ops kernels, which accept ``policy=`` and branch on the policy
-string, and the search/caching machinery (:mod:`.search`, :mod:`.cache`)
-only ever moves these objects around.
+accumulator dtypes. It is plain data: the numeric behavior lives in the ops
+kernels, which accept ``policy=`` and branch on the policy string
+(``ops.linalg.policy_matmul``, the distance cross terms of ``ops.kmeans``,
+``ops.neighbors`` and ``ops.ivf``).
 
 The invariant every policy must preserve: **accumulators stay in the carry
 dtype** (f32/f64). ``bf16_f32acc`` casts only the matmul *operands* to
@@ -18,15 +15,14 @@ term of kmeans/knn candidate scoring. The donated-carry fold contract
 under every policy — a checkpoint written under ``bf16_f32acc`` resumes
 bitwise-identically because the carry never changes dtype.
 
-Import-pure apart from :mod:`utils.knobs` (no jax) so the linter, the CLI,
-and jax-free worker processes can load it.
+Import-pure apart from :mod:`utils.knobs` (no jax) so the linter and
+jax-free worker processes can load it.
 """
 
 from __future__ import annotations
 
 import enum
 import os
-from dataclasses import dataclass
 
 from spark_rapids_ml_tpu.utils import knobs
 
@@ -63,8 +59,6 @@ FOLD_POLICIES: tuple[str, ...] = (
     PrecisionPolicy.BF16_F32ACC.value,
 )
 
-LAYOUTS: tuple[str, ...] = ("row", "col")
-
 
 def validate_policy(policy: str, *, allowed: tuple[str, ...] = POLICIES) -> str:
     """Canonicalize ``policy`` (str or :class:`PrecisionPolicy`) or raise."""
@@ -88,53 +82,3 @@ def resolve_policy(policy: str | None,
     if policy is None:
         policy = os.environ.get(PRECISION_POLICY_VAR, PrecisionPolicy.F32.value)
     return validate_policy(policy, allowed=allowed)
-
-
-@dataclass(frozen=True)
-class TuningConfig:
-    """One point in the tuner's search space for one kernel signature.
-
-    ``chunk_rows=None`` means "keep the static knob" — a config that only
-    pins layout/policy. ``donate_carry`` records the donation arrangement
-    for the ledger; every shipped fold donates (TPL001), so search grids
-    only emit ``True``, but the field keeps tuned ledger entries
-    self-describing.
-    """
-
-    chunk_rows: int | None = None
-    layout: str = "row"
-    policy: str = PrecisionPolicy.F32.value
-    donate_carry: bool = True
-
-    def __post_init__(self) -> None:
-        if self.layout not in LAYOUTS:
-            raise ValueError(f"layout {self.layout!r} must be one of {LAYOUTS}")
-        validate_policy(self.policy)
-        if self.chunk_rows is not None and self.chunk_rows < 1:
-            raise ValueError(f"chunk_rows must be >= 1, got {self.chunk_rows}")
-
-    def to_dict(self) -> dict:
-        return {
-            "chunk_rows": self.chunk_rows,
-            "layout": self.layout,
-            "policy": self.policy,
-            "donate_carry": self.donate_carry,
-        }
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "TuningConfig":
-        return cls(
-            chunk_rows=d.get("chunk_rows"),
-            layout=d.get("layout", "row"),
-            policy=d.get("policy", PrecisionPolicy.F32.value),
-            donate_carry=bool(d.get("donate_carry", True)),
-        )
-
-    def key(self) -> str:
-        """Stable compact identity — ledger stamping and sentinel keying."""
-        chunk = "knob" if self.chunk_rows is None else str(self.chunk_rows)
-        donate = "1" if self.donate_carry else "0"
-        return (
-            f"chunk={chunk}|layout={self.layout}|policy={self.policy}"
-            f"|donate={donate}"
-        )

@@ -27,6 +27,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from spark_rapids_ml_tpu.ops import linalg as L
+from spark_rapids_ml_tpu.ops.policy import FOLD_POLICIES, resolve_policy
 from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, FEAT_AXIS
 from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 from spark_rapids_ml_tpu.telemetry.spans import trace_range
@@ -304,8 +305,8 @@ def sharded_histogram(
 def chunk_put(mesh: Mesh):
     """Chunk placement for mesh-sharded stream folds: [c, n] matrices shard
     as P(data, None), [c] vectors as P(data). Pass as ``put_fn`` to
-    ``stream_fold`` (chunk_rows must divide by the data-axis size —
-    :func:`stream_chunk_rows_for_mesh`)."""
+    ``stream_fold`` (chunk_rows must divide by the data-axis size:
+    ``spark.ingest.stream_fold_over_mesh`` sees to it)."""
     mat = NamedSharding(mesh, P(DATA_AXIS, None))
     vec = NamedSharding(mesh, P(DATA_AXIS))
 
@@ -313,32 +314,6 @@ def chunk_put(mesh: Mesh):
         return jax.device_put(a, mat if a.ndim == 2 else vec)
 
     return put
-
-
-def stream_chunk_rows_for_mesh(mesh: Mesh, *, n: int | None = None,
-                               rows: int | None = None,
-                               dtype=None) -> int:
-    """The streamed chunk size rounded up to a data-axis multiple so every
-    chunk shards evenly (power-of-two buckets already divide power-of-two
-    meshes; this covers odd device counts too).
-
-    With the fit shape (``n``, optionally ``rows``/``dtype``) the tuning
-    cache is consulted first (``TPU_ML_AUTOTUNE=cache|search``; cache
-    lookups only here — mesh programs never search inline) and a blessed
-    winner's chunk geometry replaces the static knob; a miss falls back to
-    ``TPU_ML_STREAM_CHUNK_ROWS`` exactly as before."""
-    from spark_rapids_ml_tpu.spark.ingest import stream_chunk_rows
-
-    ndev = mesh.shape[DATA_AXIS]
-    base = stream_chunk_rows()
-    if n is not None:
-        from spark_rapids_ml_tpu import autotune
-
-        tuned = autotune.resolve("stream.fold_step", n=n, rows=rows,
-                                 dtype=dtype)
-        if tuned is not None and tuned.chunk_rows:
-            base = int(tuned.chunk_rows)
-    return -(-base // ndev) * ndev
 
 
 def init_chunk_carry(example, mesh: Mesh):
@@ -433,11 +408,6 @@ def sharded_gram_fold(
     partials (init_chunk_carry), ``x``/``w`` one sharded chunk. Donated —
     reassign the carry and never touch the old one. ``policy=None``
     resolves ``TPU_ML_PRECISION_POLICY`` before the program-cache lookup."""
-    from spark_rapids_ml_tpu.autotune.policy import (
-        FOLD_POLICIES,
-        resolve_policy,
-    )
-
     policy = resolve_policy(policy, allowed=FOLD_POLICIES)
     return _gram_chunk_fold_prog(mesh, precision, policy)(carry, x, w)
 
@@ -480,10 +450,5 @@ def sharded_linear_fold(
     """One streamed LinearStats fold over a sharded labeled chunk (donated
     carry; ``w`` is the instance-weight/pad mask). ``policy=None`` resolves
     ``TPU_ML_PRECISION_POLICY`` before the program-cache lookup."""
-    from spark_rapids_ml_tpu.autotune.policy import (
-        FOLD_POLICIES,
-        resolve_policy,
-    )
-
     policy = resolve_policy(policy, allowed=FOLD_POLICIES)
     return _linear_chunk_fold_prog(mesh, precision, policy)(carry, x, y, w)

@@ -19,7 +19,6 @@ DEVICE_INIT = "device.init"       # backend/device initialization
 FOLD_DISPATCH = "fold.dispatch"   # streamed-fit chunk dispatch
 FOLD_WAIT = "fold.wait"           # streamed-fit terminal device wait
 INGEST_CHUNK = "ingest.chunk"     # streamed-fit chunk staging
-AUTOTUNE_TRIAL = "autotune.trial"  # one timing trial of an autotune search
 # driver-side elastic-scheduler gates: unlike worker.task (which every
 # worker process counts independently), these count in the DRIVER, so a
 # plan can fail exactly one dispatch / one rank of one epoch
@@ -42,7 +41,6 @@ FAULT_SITES: frozenset[str] = frozenset({
     FOLD_DISPATCH,
     FOLD_WAIT,
     INGEST_CHUNK,
-    AUTOTUNE_TRIAL,
     SCHEDULER_TASK,
     SCHEDULER_RANK,
     SERVE_DISPATCH,

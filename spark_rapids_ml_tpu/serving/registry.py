@@ -30,11 +30,6 @@ dwarfs a single-row matmul. This module strips both away:
   keeps even the fastest kernels — a fresh process re-registering the same
   models warms from disk (``compile.cache_hits > 0``) instead of
   recompiling.
-
-- **Tuning-cache consult.** The registry asks the PR 7 tuning cache for a
-  blessed serve-kernel precision policy (key ``serve.<family>``); an
-  explicit ``bf16_f32acc`` entry swaps in the bf16-operand matmul variant
-  for the matmul families. Default stays ``f32`` — the eager-parity path.
 """
 
 from __future__ import annotations
@@ -114,36 +109,11 @@ def _pca_kernel(params, x):
     return L.project(x, pc)
 
 
-def _pca_kernel_bf16(params, x):
-    import jax.numpy as jnp
-
-    (pc,) = params
-    return jnp.matmul(
-        x.astype(jnp.bfloat16),
-        pc.astype(jnp.bfloat16),
-        preferred_element_type=jnp.float32,
-    )
-
-
 def _linear_kernel(params, x):
     from spark_rapids_ml_tpu.ops import linear as LIN
 
     coef, intercept = params
     return LIN.predict_linear(x, coef, intercept)
-
-
-def _linear_kernel_bf16(params, x):
-    import jax.numpy as jnp
-
-    coef, intercept = params
-    return (
-        jnp.matmul(
-            x.astype(jnp.bfloat16),
-            coef.astype(jnp.bfloat16),
-            preferred_element_type=jnp.float32,
-        )
-        + intercept
-    )
 
 
 def _scaler_kernel(params, x, *, with_mean, with_std):
@@ -279,24 +249,6 @@ def _device_dtype() -> Any:
     return jnp.asarray(np.zeros((), np.float64)).dtype
 
 
-def _consult_policy(family: str, n_features: int) -> str:
-    """Ask the PR 7 tuning cache for a blessed serve-kernel precision
-    policy. Only an explicit cache entry deviates from f32 — the tuner's
-    accuracy gates, not this registry, decide when bf16 operands are safe."""
-    try:
-        from spark_rapids_ml_tpu.autotune import cache as tuning_cache
-
-        cfg = tuning_cache.lookup(
-            tuning_cache.cache_key(f"serve.{family}", n=n_features)
-        )
-    except Exception:  # noqa: BLE001 - a tuner problem must not block serving
-        logger.exception("tuning-cache consult failed for serve.%s", family)
-        return "f32"
-    if cfg is not None and cfg.policy == "bf16_f32acc":
-        return cfg.policy
-    return "f32"
-
-
 def _identity_prepare(mat: np.ndarray) -> np.ndarray:
     return mat
 
@@ -327,19 +279,16 @@ def servable_from_model(name: str, model: Any) -> ServableEntry:
             # padding so pad rows stay zero (models/pca.py)
             return columnar.standardize_host(mat, _mean, _std)
 
-        policy = _consult_policy("pca", int(model.pc.shape[0]))
-        kernel = _pca_kernel_bf16 if policy == "bf16_f32acc" else _pca_kernel
         return ServableEntry(
             name=name,
             family="pca",
             model_cls=type(model).__name__,
             n_features=int(model.pc.shape[0]),
-            kernel=kernel,
+            kernel=_pca_kernel,
             params=(pc,),
             prepare=prepare,
             finalize=_identity_finalize,
             x_dtype=x_dtype,
-            policy=policy,
             model=model,
         )
 
@@ -351,16 +300,12 @@ def servable_from_model(name: str, model: Any) -> ServableEntry:
                 "serve contract covers [n]-coefficient GLMs"
             )
         n = int(coef.shape[0])
-        policy = _consult_policy("linear", n)
-        kernel = (
-            _linear_kernel_bf16 if policy == "bf16_f32acc" else _linear_kernel
-        )
         return ServableEntry(
             name=name,
             family="linear",
             model_cls=type(model).__name__,
             n_features=n,
-            kernel=kernel,
+            kernel=_linear_kernel,
             params=(
                 jnp.asarray(coef, dtype=x_dtype),
                 jnp.asarray(model.intercept, dtype=x_dtype),
@@ -368,7 +313,6 @@ def servable_from_model(name: str, model: Any) -> ServableEntry:
             prepare=_identity_prepare,
             finalize=_identity_finalize,
             x_dtype=x_dtype,
-            policy=policy,
             model=model,
         )
 
@@ -388,7 +332,6 @@ def servable_from_model(name: str, model: Any) -> ServableEntry:
             prepare=_identity_prepare,
             finalize=_identity_finalize,
             x_dtype=x_dtype,
-            policy="f32",
             model=model,
         )
 
@@ -422,7 +365,6 @@ def servable_from_model(name: str, model: Any) -> ServableEntry:
             prepare=_identity_prepare,
             finalize=finalize,
             x_dtype=x_dtype,
-            policy="f32",
             row_axis=1,
             model=model,
         )

@@ -431,7 +431,6 @@ class SparkPCA(_HasDistribution, PCA):
         import jax.numpy as jnp
 
         from spark_rapids_ml_tpu.parallel import gram as G
-        from spark_rapids_ml_tpu.parallel import mesh as M
         from spark_rapids_ml_tpu.spark import ingest
 
         precision = L.PRECISIONS[self.getOrDefault("precision")]
@@ -459,25 +458,20 @@ class SparkPCA(_HasDistribution, PCA):
                 col_sum=jax.ShapeDtypeStruct((n,), dt),
                 count=jax.ShapeDtypeStruct((), dt),
             )
-            res = ingest.stream_fold(
+            # weighted count == Σ true-row weights == rows; no override needed
+            return ingest.stream_fold_over_mesh(
                 selected,
                 lambda c, x, w: G.sharded_gram_fold(
                     c, x, w, mesh, precision=precision
                 ),
+                example,
+                mesh,
                 features_col=input_col,
                 n=n,
-                init=G.init_chunk_carry(example, mesh),
                 rows=rows,
-                chunk_rows=G.stream_chunk_rows_for_mesh(
-                    mesh, n=n, rows=rows, dtype=dt
-                ),
-                put_fn=G.chunk_put(mesh),
                 checkpointer=ckpt,
                 checkpoint_every=checkpoint_every,
-                min_chunk_rows=mesh.shape[M.DATA_AXIS],
-            )
-            # weighted count == Σ true-row weights == rows; no override needed
-            return G.finalize_chunk_fold(res.carry, mesh)
+            ).carry
         if checkpoint_dir is not None:
             raise NotImplementedError(
                 "checkpoint_dir applies to the out-of-core streamed fit; "
@@ -736,7 +730,6 @@ class SparkLinearRegression(_HasDistribution, LinearRegression):
 
                     from spark_rapids_ml_tpu.ops import linear as LIN
                     from spark_rapids_ml_tpu.parallel import gram as G
-                    from spark_rapids_ml_tpu.parallel import mesh as M
                     from spark_rapids_ml_tpu.utils.checkpoint import (
                         TrainingCheckpointer,
                     )
@@ -770,26 +763,21 @@ class SparkLinearRegression(_HasDistribution, LinearRegression):
                             y_sq=jax.ShapeDtypeStruct((), dt),
                             count=jax.ShapeDtypeStruct((), dt),
                         )
-                        res = ingest.stream_fold(
+                        stats = ingest.stream_fold_over_mesh(
                             selected,
                             lambda c, x, y, w: G.sharded_linear_fold(
                                 c, x, y, w, mesh
                             ),
+                            example,
+                            mesh,
                             features_col=feats,
                             n=n,
                             label_col=label,
                             weight_col=weight_col,
-                            init=G.init_chunk_carry(example, mesh),
                             rows=rows,
-                            chunk_rows=G.stream_chunk_rows_for_mesh(
-                                mesh, n=n, rows=rows, dtype=dt
-                            ),
-                            put_fn=G.chunk_put(mesh),
                             checkpointer=ckpt,
                             checkpoint_every=checkpoint_every,
-                            min_chunk_rows=mesh.shape[M.DATA_AXIS],
-                        )
-                        stats = G.finalize_chunk_fold(res.carry, mesh)
+                        ).carry
                 elif checkpoint_dir is not None:
                     raise NotImplementedError(
                         "checkpoint_dir applies to the out-of-core streamed "
@@ -1758,19 +1746,15 @@ class SparkStandardScaler(_HasDistribution, StandardScaler):
                         total=jax.ShapeDtypeStruct((n,), dt),
                         total_sq=jax.ShapeDtypeStruct((n,), dt),
                     )
-                    res = ingest.stream_fold(
+                    mstats = ingest.stream_fold_over_mesh(
                         selected,
                         lambda c, x, w: G.sharded_moment_fold(c, x, w, mesh),
+                        example,
+                        mesh,
                         features_col=input_col,
                         n=n,
-                        init=G.init_chunk_carry(example, mesh),
                         rows=rows,
-                        chunk_rows=G.stream_chunk_rows_for_mesh(
-                            mesh, n=n, rows=rows, dtype=dt
-                        ),
-                        put_fn=G.chunk_put(mesh),
-                    )
-                    mstats = G.finalize_chunk_fold(res.carry, mesh)
+                    ).carry
                     arrays = {
                         # count = Σw: 1.0 true rows / 0.0 pads, so it IS
                         # the true row count — no override needed

@@ -16,15 +16,16 @@ This module replaces that with a streaming fill:
 - chunks drain from the DataFrame lazily (localspark partitions are
   generator-produced; real pyspark uses ``toLocalIterator`` which fetches
   one partition at a time);
-- each chunk is copied into a staging set (``_StagingSet``) in the dtype
-  the device holds (by row blocks over a small kept pool of threads where
-  the batch is large enough to cut) and ``device_put`` the moment it
+- one stager (``_Stager``) copies each chunk into a staging set
+  (``_StagingSet``) in the dtype the device holds (by row blocks over a
+  small kept pool of threads where the batch is large enough to cut) and
+  hands the set to its consumer, which ``device_put``s it, the moment it
   fills: a device's whole shard on the resident path (``stream_to_mesh``),
-  one fold chunk on the streamed one (``stream_fold``). Both keep the one
-  set and rewrite it
-  under the buffer rule stated at ``_take_staging``, which finds out from
-  the arrays whether a put aliased (``device_put`` of a host ndarray may
-  alias rather than copy);
+  one fold chunk on the streamed one (``stream_fold``;
+  ``stream_fold_over_mesh`` owns its geometry over a mesh). The one set is
+  kept and rewritten under the buffer rule stated at ``_take_staging``,
+  which finds out from the arrays whether a put aliased (``device_put`` of
+  a host ndarray may alias rather than copy);
 - the global array is assembled zero-copy on device with
   ``jax.make_array_from_single_device_arrays``.
 
@@ -296,14 +297,12 @@ def stream_to_mesh(
     (1.0 true rows / 0.0 pads — the pad-mask convention masked mesh
     programs consume).
 
-    Span ``mesh.ingest`` covers both passes; its children carry the names
-    the streamed fold uses: ``ingest.chunk`` (the pull of a batch),
-    ``ingest.stage`` (the one host copy, into the kept staging set and in
-    the dtype the device holds), ``h2d.put`` (the transfer's issue) and
-    ``stage.reclaim`` (the wait for a transfer: before the set is written
-    again, and at the end, so the rows have landed on return and the set can
-    be kept for the next ingest); ``stage.buffers{state}`` counts the sets
-    taken, a shard each.
+    Span ``mesh.ingest`` covers both passes. The data pass is the one
+    staging pipeline (:class:`_Stager` has its spans, counters and buffer
+    rule); what is this function's own is the consumer: a full set is a
+    device's shard, put to that device under ``h2d.put``, and the closing
+    ``stage.reclaim`` waits for every shard, so the rows have landed on
+    return and the set can be kept for the next ingest.
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -318,9 +317,6 @@ def stream_to_mesh(
     x_parts: list[Any] = []
     y_parts: list[Any] = []
     w_parts: list[Any] = []
-    staged: _StagingSet | None = None  # the set being filled
-    fill = 0
-    seen = 0
 
     with trace_range("mesh.ingest"):
         if rows is None:
@@ -339,22 +335,7 @@ def stream_to_mesh(
         devmap = x_sharding.addressable_devices_indices_map((padded_rows, n_eff))
         devices = sorted(devmap, key=lambda d: devmap[d][0].start or 0)
 
-        # each device's shard is staged in turn in the one staging set the
-        # streamed fold keeps too, in the dtype the device holds, and the
-        # set is written again under the same rule (``_take_staging``)
-        key = (
-            shard, n_eff, np.dtype(jax.dtypes.canonicalize_dtype(dt)), "row",
-            want_y,
-        )
-        spare = _borrow_staging(key)  # the set last put from
-
-        def flush():
-            nonlocal staged, spare, fill
-            if staged is None:  # an empty tail shard
-                staged, spare = _take_staging(key, spare), None
-            if fill < staged.dirty:
-                with trace_range("ingest.stage"):
-                    staged.zero_from(fill)
+        def put_shard(staged: _StagingSet, fill: int) -> None:
             d = devices[len(x_parts)]
             nbytes = 0
             with trace_range("h2d.put"):
@@ -368,68 +349,40 @@ def stream_to_mesh(
                         staged.placed.append(parts[-1])
                         nbytes += buf.nbytes
             REGISTRY.counter_inc("h2d.bytes", nbytes, path="mesh")
-            spare, staged = staged, None
-            fill = 0
 
-        try:
-            for xc, yc, wc in _timed_chunks(
+        # a set is a device's shard, in the dtype the device holds
+        key = (
+            shard, n_eff, np.dtype(jax.dtypes.canonicalize_dtype(dt)), want_y
+        )
+        with _Stager(
+            lambda: key, put_shard, augment_intercept=augment_intercept
+        ) as stager:
+            for xc, yc, wc in _batches(
                 _iter_chunks(
                     selected, features_col, label_col, weight_col,
                     est_bytes=rows * n * 8,
-                )
+                ),
+                n, features_col,
             ):
-                REGISTRY.counter_inc("ingest.rows", len(xc))
-                REGISTRY.counter_inc("ingest.bytes", xc.nbytes)
-                REGISTRY.histogram_record("ingest.chunk_rows", len(xc))
-                if xc.shape[1] != n:
-                    raise ValueError(
-                        f"feature dimension changed mid-stream: expected {n}, "
-                        f"got {xc.shape[1]} in column {features_col!r}"
-                    )
-                if wc is not None:
-                    # the ONE weightCol contract enforcement point (all-zero
-                    # is checked globally by callers, hence allow_all_zero)
-                    wc = columnar.validate_weights(
-                        wc, len(xc), allow_all_zero=True
-                    )
-                if seen + len(xc) > rows:
+                if stager.rows + len(xc) > rows:
                     raise ValueError(
                         f"dataset produced more rows while streaming than "
                         f"count() reported ({rows}); cache() the DataFrame if "
                         "its source is nondeterministic"
                     )
-                at = 0
-                while at < len(xc):
-                    if staged is None:
-                        staged, spare = _take_staging(key, spare), None
-                    take = min(shard - fill, len(xc) - at)
-                    with trace_range("ingest.stage"):
-                        staged.write(
-                            fill,
-                            xc[at : at + take],
-                            yc[at : at + take] if want_y else None,
-                            wc[at : at + take] if wc is not None else None,
-                            augment_intercept=augment_intercept,
-                        )
-                    fill += take
-                    at += take
-                    seen += take
-                    if fill == shard:
-                        flush()
-            if seen != rows:
+                stager.feed(xc, yc, wc)
+            if stager.rows != rows:
                 raise ValueError(
-                    f"dataset produced {seen} rows while streaming but "
+                    f"dataset produced {stager.rows} rows while streaming but "
                     f"count() reported {rows}; cache() the DataFrame if its "
                     "source is nondeterministic"
                 )
             while len(x_parts) < ndev:  # zero-pad the partial + empty tail shards
-                flush()
+                stager.flush()
             # whoever asked for the rows needs them landed, and the set is
             # kept for the next ingest only once they are
             with trace_range("stage.reclaim"):
                 jax.block_until_ready([x_parts, y_parts, w_parts])
-        finally:
-            _return_staging(staged or spare)
 
         xs = jax.make_array_from_single_device_arrays(
             (padded_rows, n_eff), x_sharding, x_parts
@@ -478,6 +431,16 @@ def stream_chunk_rows() -> int:
     if rows < 1:
         raise ValueError(f"{STREAM_CHUNK_VAR}={rows} must be >= 1")
     return columnar.bucket_rows(rows)
+
+
+def stream_chunk_rows_for_mesh(mesh) -> int:
+    """:func:`stream_chunk_rows` rounded up to a multiple of the data axis,
+    so every chunk shards evenly (power-of-two buckets already divide
+    power-of-two meshes; this covers odd device counts too)."""
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+
+    ndev = mesh.shape[DATA_AXIS]
+    return -(-stream_chunk_rows() // ndev) * ndev
 
 
 def progress_interval() -> float:
@@ -772,10 +735,8 @@ class _StagingSet:
     written again; :meth:`reclaim` is that rule's check."""
 
     def __init__(self, key):
-        chunk_rows, n_eff, dtype, layout, want_y = self.key = key
-        self.x = np.zeros(
-            (chunk_rows, n_eff), dtype, order="F" if layout == "col" else "C"
-        )
+        chunk_rows, n_eff, dtype, want_y = self.key = key
+        self.x = np.zeros((chunk_rows, n_eff), dtype)
         self.y = np.zeros(chunk_rows, dtype) if want_y else None
         self.w = np.zeros(chunk_rows, dtype)
         self.dirty = 0
@@ -887,19 +848,119 @@ def _take_staging(key, candidate: _StagingSet | None) -> _StagingSet:
     return _StagingSet(key)
 
 
-def _timed_chunks(it: Iterator) -> Iterator:
-    """``it``, with the pull of each next batch from the source alone under
-    span ``ingest.chunk``; the scan and the staging copy have their own
-    spans (``ingest.scan``, ``ingest.stage``)."""
+class _Stager:
+    """The one staging pipeline behind the resident ingest and the streamed
+    fold: batches in, full staging sets out.
+
+    :meth:`feed` copies a batch ``(xc, yc, wc)`` slice by slice into the set
+    being filled — each slice under span ``ingest.stage``, the one host copy
+    of those rows and their cast to the device's dtype
+    (``_StagingSet.write``) — and hands every set that fills to
+    ``consume(staged, fill)``; :meth:`flush` hands over the partial one,
+    rows ``[fill:]`` zeroed first where an earlier chunk left any
+    (``zero_from``, under ``ingest.stage`` too). The consumer puts the
+    set's buffers and appends what it put to ``staged.placed``: the set it
+    was handed becomes the candidate for the next
+    (``_take_staging`` has THE BUFFER RULE, span ``stage.reclaim`` and
+    counter ``stage.buffers{state}``). ``key()`` is asked for each new set,
+    because a consumer may change its shape in mid-stream (the fold's OOM
+    bisection); a set of another key is never rewritten.
+
+    As a context manager it borrows the set kept from the last ingest on
+    entry and gives back the newest on every exit, errors included
+    (``_borrow_staging``, ``_return_staging``). ``rows`` counts the rows
+    staged so far."""
+
+    def __init__(self, key, consume, *, augment_intercept: bool = False):
+        self.key = key
+        self.consume = consume
+        self.augment_intercept = augment_intercept
+        self.staged: _StagingSet | None = None  # the set being filled
+        self.spare: _StagingSet | None = None  # the set last put from
+        self.fill = 0
+        self.rows = 0
+
+    def __enter__(self):
+        self.spare = _borrow_staging(self.key())
+        return self
+
+    def __exit__(self, *exc):
+        _return_staging(self.staged or self.spare)
+
+    def _take(self) -> _StagingSet:
+        if self.staged is None:
+            self.staged = _take_staging(self.key(), self.spare)
+            self.spare = None
+        return self.staged
+
+    def feed(self, xc, yc, wc) -> None:
+        from spark_rapids_ml_tpu.telemetry import trace_range
+
+        if wc is not None:
+            # the ONE weightCol contract enforcement point (all-zero is
+            # checked globally by callers, hence allow_all_zero)
+            wc = columnar.validate_weights(wc, len(xc), allow_all_zero=True)
+        at = 0
+        while at < len(xc):
+            staged = self._take()
+            take = min(len(staged.x) - self.fill, len(xc) - at)
+            with trace_range("ingest.stage"):
+                staged.write(
+                    self.fill,
+                    xc[at : at + take],
+                    yc[at : at + take] if staged.y is not None else None,
+                    wc[at : at + take] if wc is not None else None,
+                    augment_intercept=self.augment_intercept,
+                )
+            self.fill += take
+            self.rows += take
+            at += take
+            if self.fill == len(staged.x):
+                self.flush()
+
+    def flush(self) -> None:
+        """Hand the set being filled to the consumer as it stands (a ragged
+        tail, or nothing but zeros: the resident ingest's empty tail
+        shards)."""
+        from spark_rapids_ml_tpu.telemetry import trace_range
+
+        staged = self._take()
+        if self.fill < staged.dirty:
+            # a new set's rows past a ragged tail were zero for nothing; a
+            # rewritten one's are an earlier chunk's, and under
+            # nonfinite="allow" a stale row times w=0 is not zero
+            with trace_range("ingest.stage"):
+                staged.zero_from(self.fill)
+        try:
+            self.consume(staged, self.fill)
+        finally:
+            self.spare, self.staged = staged, None
+            self.fill = 0
+
+
+def _batches(chunks: Iterator, n: int, features_col: str | None) -> Iterator:
+    """The head of both ingests: each next ``(x, y, w)`` batch of ``chunks``
+    pulled from the source alone under span ``ingest.chunk`` (the scan and
+    the staging copy have their own, ``ingest.scan`` and ``ingest.stage``),
+    booked in ``ingest.rows`` / ``ingest.bytes`` / ``ingest.chunk_rows`` and
+    held to the width ``n``."""
     from spark_rapids_ml_tpu.telemetry import trace_range
 
     while True:
         with trace_range("ingest.chunk"):
             try:
-                item = next(it)
+                xc, yc, wc = next(chunks)
             except StopIteration:
                 return
-        yield item
+        REGISTRY.counter_inc("ingest.rows", len(xc))
+        REGISTRY.counter_inc("ingest.bytes", xc.nbytes)
+        REGISTRY.histogram_record("ingest.chunk_rows", len(xc))
+        if xc.ndim != 2 or xc.shape[1] != n:
+            raise ValueError(
+                f"feature dimension changed mid-stream: expected {n}, "
+                f"got {xc.shape[1:]} in column {features_col!r}"
+            )
+        yield xc, yc, wc
 
 
 def release_staging() -> None:
@@ -946,13 +1007,11 @@ def stream_fold(
     ``device_put``-ing chunk i+1. Each phase is traced, so the overlap is
     observable and the host's seconds have names (telemetry.metrics()):
     ``ingest.chunk`` (the pull), ``ingest.scan`` (the non-finite check),
-    ``ingest.stage`` (the one host copy, into the staging set and in the
-    dtype the device holds), ``stage.reclaim`` (the wait before a set is
-    written again), ``fold.dispatch`` with ``h2d.put`` and ``fold.enqueue``
-    inside it, and ``fold.wait``; ``fold.input_in_flight`` counts the chunks
-    whose transfer had not landed when their fold was enqueued, and
-    ``stage.buffers{state}`` whether each chunk's set was ``reused``,
-    ``fresh`` or taken anew because the old one was ``aliased``.
+    the staging pipeline's ``ingest.stage`` and ``stage.reclaim``
+    (:class:`_Stager`: a full set is one fold chunk here),
+    ``fold.dispatch`` with ``h2d.put`` and ``fold.enqueue`` inside it, and
+    ``fold.wait``; ``fold.input_in_flight`` counts the chunks whose transfer
+    had not landed when their fold was enqueued.
 
     The scan and the copy are the host's per-byte work on a batch, and a
     batch large enough to cut has both run by row blocks on the host pass's
@@ -1020,12 +1079,8 @@ def stream_fold(
     # cast and device_put finds nothing left to canonicalise on the host
     stage_dt = np.dtype(jax.dtypes.canonicalize_dtype(dt))
     n_eff = n + 1 if augment_intercept else n
-    # a caller-pinned chunk_rows (mesh paths, tests) wins outright; only the
-    # unpinned path consults the ledger-driven tuner below
-    tune_geometry = chunk_rows is None
     if chunk_rows is None:
         chunk_rows = stream_chunk_rows()
-    layout = "row"  # staging-buffer memory order; the tuner may pick "col"
     if min_chunk_rows is None:
         min_chunk_rows = max(
             1,
@@ -1071,32 +1126,6 @@ def stream_fold(
 
     carry = init() if callable(init) else init
 
-    if tune_geometry:
-        # ledger-driven autotuner (TPU_ML_AUTOTUNE): a blessed/searched
-        # winner overrides chunk geometry + staging layout for this shape
-        # bucket; a miss (or mode=off) keeps the static knobs untouched.
-        # Search trials fold synthetic chunks into throwaway zero carries,
-        # so the real carry above is never consumed.
-        from spark_rapids_ml_tpu import autotune
-
-        tuned = autotune.resolve(
-            "stream.fold_step",
-            n=n_eff,
-            rows=rows,
-            dtype=dt,
-            measure=autotune.stream_fold_measure(
-                fold_fn, carry, n_eff, stage_dt, put, want_y=want_y
-            ),
-            candidates=autotune.candidate_grid(
-                chunk_rows, floor=min_chunk_rows
-            ),
-        )
-        if tuned is not None:
-            if tuned.chunk_rows:
-                chunk_rows = max(
-                    min_chunk_rows, columnar.bucket_rows(int(tuned.chunk_rows))
-                )
-            layout = tuned.layout
     seen = 0
     skipped = 0
     n_chunks = 0
@@ -1128,15 +1157,6 @@ def stream_fold(
                 "resuming streamed fit from checkpoint (chunk %d, %d rows "
                 "already folded)", n_chunks, seen,
             )
-
-    def staging_key():
-        return (chunk_rows, n_eff, stage_dt, layout, want_y)
-
-    # the set last put from, which the next chunk rewrites: at first what
-    # the holder kept from the last fold of this shape
-    spare = _borrow_staging(staging_key())
-    staged: _StagingSet | None = None  # the set being filled
-    fill = 0
 
     # live-health heartbeat: the monitor (telemetry.health) compares
     # stream.last_beat against time.monotonic() and flags the stream stale
@@ -1177,7 +1197,7 @@ def stream_fold(
             flush=True,
         )
 
-    def attempt_fold(xb, yb, wb):
+    def attempt_fold(staged, xb, yb, wb):
         nonlocal carry, n_chunks, overlapped, max_put
         busy = any(
             not leaf.is_ready()
@@ -1218,18 +1238,18 @@ def stream_fold(
         REGISTRY.counter_inc("h2d.bytes", nbytes, path="stream")
         n_chunks += 1
 
-    def dispatch_buffers(xb, yb, wb):
+    def dispatch_buffers(staged):
         """Fold one staged chunk, retrying transients and bisecting OOMs:
         a RESOURCE_EXHAUSTED-classified failure re-stages the chunk as
         smaller fixed-shape chunks (w=0 pads keep it exact) and drops
         ``chunk_rows`` for the rest of the stream."""
         nonlocal chunk_rows, bisections
-        queue = [(xb, yb, wb)]
+        queue = [(staged.x, staged.y, staged.w)]
         while queue:
             bx, by, bw = queue.pop(0)
             try:
                 R.call_with_retry(
-                    lambda: attempt_fold(bx, by, bw),
+                    lambda: attempt_fold(staged, bx, by, bw),
                     site="fold.dispatch",
                     policy=policy,
                     retry_on=transient_only,
@@ -1254,138 +1274,110 @@ def stream_fold(
                 queue[:0] = _split_chunk_buffers(bx, by, bw, new)
                 chunk_rows = min(chunk_rows, new)
 
-    def dispatch():
-        nonlocal staged, spare, fill
-        if fill < staged.dirty:
-            # a new buffer's rows past a ragged tail were zero for nothing;
-            # a rewritten one's are an earlier chunk's, and under
-            # nonfinite="allow" a stale row times w=0 is not zero
-            with trace_range("ingest.stage"):
-                staged.zero_from(fill)
-        try:
-            dispatch_buffers(staged.x, staged.y, staged.w)
-        finally:
-            spare, staged = staged, None
-            fill = 0
+    def fold_set(staged, fill):
+        """The stager's consumer: a set is one fold chunk. It names nothing
+        that holds the stager, so no cycle outlives the fold with a carry
+        in it."""
+        nonlocal seen, last_ckpt
+        seen += fill
+        dispatch_buffers(staged)
         REGISTRY.gauge_set("stream.last_beat", time.monotonic())
+        if fill < len(staged.x):
+            return  # the ragged tail: the stream ends here
+        maybe_heartbeat()
+        if (
+            checkpointer is not None
+            and n_chunks - last_ckpt >= checkpoint_every
+        ):
+            _save_stream_checkpoint(
+                checkpointer, carry, chunks=n_chunks, seen=seen,
+                skipped=skipped, chunk_rows=chunk_rows,
+            )
+            last_ckpt = n_chunks
 
+    # the key is asked for each new set: a bisection changes chunk_rows
+    stager = _Stager(
+        lambda: (chunk_rows, n_eff, stage_dt, want_y), fold_set,
+        augment_intercept=augment_intercept,
+    )
     try:
-        for xc, yc, wc in _timed_chunks(chunks()):
-            REGISTRY.counter_inc("ingest.rows", len(xc))
-            REGISTRY.counter_inc("ingest.bytes", xc.nbytes)
-            REGISTRY.histogram_record("ingest.chunk_rows", len(xc))
-            TIMELINE.record_instant(
-                "stream.chunk", rows=len(xc), nbytes=int(xc.nbytes)
-            )
-            if xc.ndim != 2 or xc.shape[1] != n:
-                raise ValueError(
-                    f"feature dimension changed mid-stream: expected {n}, "
-                    f"got {xc.shape[1:]} in column {features_col!r}"
+        with stager:
+            for xc, yc, wc in _batches(chunks(), n, features_col):
+                TIMELINE.record_instant(
+                    "stream.chunk", rows=len(xc), nbytes=int(xc.nbytes)
                 )
-            if want_y and yc is None:
-                raise ValueError("label column missing from a streamed chunk")
-            if resume_skip:
-                # replaying an already-checkpointed prefix: drop the raw
-                # rows a prior run consumed (counted BEFORE any filtering,
-                # so the cursor is exact regardless of the non-finite
-                # policy)
-                drop = min(resume_skip, len(xc))
-                resume_skip -= drop
-                xc = xc[drop:]
-                yc = yc[drop:] if yc is not None else None
-                wc = wc[drop:] if wc is not None else None
-                if not len(xc):
-                    continue
-            xc = R.call_with_retry(
-                lambda: faults.inject("ingest.chunk", xc),
-                site="ingest.chunk",
-                policy=policy,
-                retry_on=transient_only,
-            )
-            if nonfinite != "allow":
-                with trace_range("ingest.scan"):
-                    if not (
-                        # scalar pre-check keeps the all-finite fast path
-                        # off the per-row mask allocation; a "no" from any
-                        # block falls through to the rows, on this thread
-                        _all_finite(xc)
-                        and (yc is None or np.isfinite(yc).all())
-                        and (wc is None or np.isfinite(wc).all())
-                    ):
-                        bad = ~np.isfinite(xc).all(axis=1)
-                        if yc is not None:
-                            bad |= ~np.isfinite(yc)
-                        if wc is not None:
-                            bad |= ~np.isfinite(wc)
-                        n_bad = int(bad.sum())
-                        if n_bad:
-                            if nonfinite == "raise":
-                                raise ValueError(
-                                    f"{n_bad} non-finite input row(s) in a "
-                                    "streamed chunk; set "
-                                    "TPU_ML_NONFINITE_POLICY=skip to drop "
-                                    "and count them instead"
+                if want_y and yc is None:
+                    raise ValueError("label column missing from a streamed chunk")
+                if resume_skip:
+                    # replaying an already-checkpointed prefix: drop the raw
+                    # rows a prior run consumed (counted BEFORE any filtering,
+                    # so the cursor is exact regardless of the non-finite
+                    # policy)
+                    drop = min(resume_skip, len(xc))
+                    resume_skip -= drop
+                    xc = xc[drop:]
+                    yc = yc[drop:] if yc is not None else None
+                    wc = wc[drop:] if wc is not None else None
+                    if not len(xc):
+                        continue
+                xc = R.call_with_retry(
+                    lambda: faults.inject("ingest.chunk", xc),
+                    site="ingest.chunk",
+                    policy=policy,
+                    retry_on=transient_only,
+                )
+                if nonfinite != "allow":
+                    with trace_range("ingest.scan"):
+                        if not (
+                            # scalar pre-check keeps the all-finite fast path
+                            # off the per-row mask allocation; a "no" from any
+                            # block falls through to the rows, on this thread
+                            _all_finite(xc)
+                            and (yc is None or np.isfinite(yc).all())
+                            and (wc is None or np.isfinite(wc).all())
+                        ):
+                            bad = ~np.isfinite(xc).all(axis=1)
+                            if yc is not None:
+                                bad |= ~np.isfinite(yc)
+                            if wc is not None:
+                                bad |= ~np.isfinite(wc)
+                            n_bad = int(bad.sum())
+                            if n_bad:
+                                if nonfinite == "raise":
+                                    raise ValueError(
+                                        f"{n_bad} non-finite input row(s) in a "
+                                        "streamed chunk; set "
+                                        "TPU_ML_NONFINITE_POLICY=skip to drop "
+                                        "and count them instead"
+                                    )
+                                keep = ~bad
+                                xc = xc[keep]
+                                yc = yc[keep] if yc is not None else None
+                                wc = wc[keep] if wc is not None else None
+                                skipped += n_bad
+                                REGISTRY.counter_inc(
+                                    "rows.nonfinite_skipped", n_bad
                                 )
-                            keep = ~bad
-                            xc = xc[keep]
-                            yc = yc[keep] if yc is not None else None
-                            wc = wc[keep] if wc is not None else None
-                            skipped += n_bad
-                            REGISTRY.counter_inc(
-                                "rows.nonfinite_skipped", n_bad
-                            )
-                            if not len(xc):
-                                continue
-            if wc is not None:
-                wc = columnar.validate_weights(
-                    wc, len(xc), allow_all_zero=True
+                                if not len(xc):
+                                    continue
+                stager.feed(xc, yc, wc)
+            if stager.fill:
+                stager.flush()  # ragged tail: pads ride the w=0 mask, exactly
+            if seen == 0:
+                raise ValueError("empty dataset")
+            if rows is not None and seen + skipped != rows:
+                raise ValueError(
+                    f"dataset produced {seen + skipped} rows while streaming "
+                    f"but count() reported {rows}; cache() the DataFrame if "
+                    "its source is nondeterministic"
                 )
-            at = 0
-            while at < len(xc):
-                if staged is None:
-                    staged, spare = _take_staging(staging_key(), spare), None
-                take = min(chunk_rows - fill, len(xc) - at)
-                with trace_range("ingest.stage"):
-                    staged.write(
-                        fill,
-                        xc[at : at + take],
-                        yc[at : at + take] if want_y else None,
-                        wc[at : at + take] if wc is not None else None,
-                        augment_intercept=augment_intercept,
-                    )
-                fill += take
-                at += take
-                seen += take
-                if fill == chunk_rows:
-                    dispatch()
-                    maybe_heartbeat()
-                    if (
-                        checkpointer is not None
-                        and n_chunks - last_ckpt >= checkpoint_every
-                    ):
-                        _save_stream_checkpoint(
-                            checkpointer, carry, chunks=n_chunks, seen=seen,
-                            skipped=skipped, chunk_rows=chunk_rows,
-                        )
-                        last_ckpt = n_chunks
-        if fill:
-            dispatch()  # ragged tail: pads ride the w=0 mask, exactly
-        if seen == 0:
-            raise ValueError("empty dataset")
-        if rows is not None and seen + skipped != rows:
-            raise ValueError(
-                f"dataset produced {seen + skipped} rows while streaming "
-                f"but count() reported {rows}; cache() the DataFrame if "
-                "its source is nondeterministic"
-            )
-        with trace_range("fold.wait"):
-            carry = _bounded_wait(carry, fold_wait_timeout_s)
+            with trace_range("fold.wait"):
+                carry = _bounded_wait(carry, fold_wait_timeout_s)
     finally:
         # clear on EVERY exit (raises included): the monitor treats an
         # inactive stream as OK regardless of beat age, so a dead stream
         # must not read as "wedged" forever
         REGISTRY.gauge_set("stream.active", 0)
-        _return_staging(staged or spare)
     # per-stream H2D↔compute overlap evidence: fraction of dispatches
     # issued while the prior fold was still on device. Recorded as a
     # histogram so end_fit's snapshot delta reads a per-fit mean into
@@ -1403,3 +1395,55 @@ def stream_fold(
         bisections=bisections,
         resumed=resumed,
     )
+
+
+def stream_fold_over_mesh(
+    source,
+    step,
+    example,
+    mesh,
+    *,
+    n: int,
+    rows: int | None = None,
+    features_col: str | None = None,
+    label_col: str | None = None,
+    weight_col: str | None = None,
+    checkpointer=None,
+    checkpoint_every: int | None = None,
+    nonfinite: str | None = None,
+) -> StreamFold:
+    """:func:`stream_fold` over a device mesh, with the geometry of the
+    stacked-partials protocol (``parallel.gram``) owned here and nowhere
+    else: chunks of :func:`stream_chunk_rows_for_mesh` rows sharded over the
+    data axis (``chunk_put``), a zero carry of ``example``'s statistics
+    stacked one slice a device (``init_chunk_carry``; ``example`` is the
+    pytree of the UNSTACKED statistics, arrays or ShapeDtypeStructs), an OOM
+    bisection that stops at the data-axis size and keeps to its multiples,
+    and the one allreduce at the end (``finalize_chunk_fold``): the result's
+    ``carry`` is the replicated total.
+
+    ``step(carry, x, w)`` — ``step(carry, x, y, w)`` with a ``label_col`` —
+    is a donated collective-free fold of one sharded chunk into the stacked
+    carry (``parallel.gram.sharded_gram_fold`` and friends). The other
+    arguments are :func:`stream_fold`'s."""
+    from spark_rapids_ml_tpu.parallel import gram as G
+    from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+
+    res = stream_fold(
+        source,
+        step,
+        n=n,
+        init=G.init_chunk_carry(example, mesh),
+        features_col=features_col,
+        label_col=label_col,
+        weight_col=weight_col,
+        rows=rows,
+        chunk_rows=stream_chunk_rows_for_mesh(mesh),
+        put_fn=G.chunk_put(mesh),
+        checkpointer=checkpointer,
+        checkpoint_every=checkpoint_every,
+        min_chunk_rows=mesh.shape[DATA_AXIS],
+        nonfinite=nonfinite,
+    )
+    res.carry = G.finalize_chunk_fold(res.carry, mesh)
+    return res

@@ -151,12 +151,6 @@ METRICS: frozenset[str] = frozenset({
     "transform.batches",
     "transform.partitions",
     "transform.partition_seconds",
-    # autotune (tuning-cache consults and searches)
-    "autotune.cache_hits",
-    "autotune.cache_misses",
-    "autotune.search_runs",
-    "autotune.trials",
-    "autotune.trial_failures",
     # cost model
     "costmodel.calls",
     "costmodel.flops",
@@ -167,7 +161,6 @@ METRICS: frozenset[str] = frozenset({
     "fit.wall_seconds",
     "transforms",
     "transform.wall_seconds",
-    "autotune.decisions",
 })
 
 # Metric families minted with a dynamic suffix (one registered prefix per
@@ -251,8 +244,6 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "stage.reclaim",
     "mesh.ingest",
     "model.to_host",
-    "autotune.search",
-    "autotune.trial",
     "transform.plan",
     "transform.dispatch",
     # cross-process timeline span events
@@ -349,7 +340,6 @@ INSTANTS: frozenset[str] = frozenset({
     "collective.dispatch",
     "retry",
     "fault.injected",
-    "autotune.decision",
     "health.transition",
     "slo.breach",
     "scheduler.hedge",

@@ -33,14 +33,14 @@ from spark_rapids_ml_tpu.utils.config import enable_compilation_cache
 # v2: + fit_id (log↔report correlation) and overlap_fraction (H2D↔compute
 # overlap evidence from the streamed fold). v3: + cost_model (analytical
 # FLOPs/bytes + roofline utilization from telemetry.costmodel). v4: + tuning
-# (the autotuner decisions drained from the per-fit journal — which
-# TuningConfig the fit actually ran with, and whether it was a cache hit).
-# v5: + health (the live monitor's component rollup at fit end — empty when
-# no monitor runs). v6: + admission (the health-driven admission-control
-# decision taken at fit start — policy/action/health_state/reason; empty
-# when no check ran). Readers must tolerate other versions
-# (tools/trace_report.py skips-with-note rather than KeyError).
-SCHEMA_VERSION = 6
+# (an autotuner's decisions). v5: + health (the live monitor's component
+# rollup at fit end — empty when no monitor runs). v6: + admission (the
+# health-driven admission-control decision taken at fit start —
+# policy/action/health_state/reason; empty when no check ran). v7: - tuning
+# (the autotuner is gone; ``from_dict`` reads a v4-v6 record and drops the
+# field). Readers must tolerate other versions (tools/trace_report.py
+# skips-with-note rather than KeyError).
+SCHEMA_VERSION = 7
 
 # TransformReport wire schema (independent of the fit schema above).
 TRANSFORM_SCHEMA_VERSION = 1
@@ -81,11 +81,6 @@ class FitReport:
     # per-kernel calls + per-call FLOPs/bytes, window totals, roofline
     # utilization. Empty when no captured kernel dispatched in the window.
     cost_model: dict = field(default_factory=dict)
-    # autotuner resolutions journaled inside this fit's window (v4): the
-    # chosen config + source (cache/search/default) per decision, plus the
-    # last decision hoisted for at-a-glance reads. Empty when the tuner
-    # never ran (mode=off, resident path, caller-pinned geometry).
-    tuning: dict = field(default_factory=dict)
     # live health rollup at fit end (v5): overall + per-component states,
     # poll/transition counts and the window's SLO breach total from the
     # background HealthMonitor. Empty when no monitor was running.
@@ -124,7 +119,6 @@ class FitReport:
             "peak_device_bytes": self.peak_device_bytes,
             "counters": self.counters,
             "cost_model": self.cost_model,
-            "tuning": self.tuning,
             "health": self.health,
             "admission": self.admission,
         }
@@ -147,7 +141,6 @@ class FitReport:
             fit_id=d.get("fit_id", ""),
             overlap_fraction=d.get("overlap_fraction"),
             cost_model=d.get("cost_model", {}) or {},
-            tuning=d.get("tuning", {}) or {},
             health=d.get("health", {}) or {},
             admission=d.get("admission", {}) or {},
             schema=int(d.get("schema", SCHEMA_VERSION)),
@@ -157,12 +150,12 @@ class FitReport:
 class _FitCapture:
     __slots__ = (
         "estimator", "uid", "token", "snap", "t0", "t_unix",
-        "fit_id", "fit_id_token", "tl_seq", "tuning_seq", "admission",
+        "fit_id", "fit_id_token", "tl_seq", "admission",
     )
 
     def __init__(
         self, estimator: str, uid: str, token, snap, t0: float,
-        fit_id: str, fit_id_token, tl_seq: int, tuning_seq: int = 0,
+        fit_id: str, fit_id_token, tl_seq: int,
         admission: dict | None = None,
     ):
         self.estimator = estimator
@@ -174,7 +167,6 @@ class _FitCapture:
         self.fit_id = fit_id
         self.fit_id_token = fit_id_token
         self.tl_seq = tl_seq
-        self.tuning_seq = tuning_seq
         self.admission = admission or {}
 
 
@@ -207,10 +199,6 @@ def begin_fit(estimator: str, uid: str = "") -> _FitCapture:
     if admission["action"] == "degrade":
         health_mod.begin_degrade_window()
     fit_id = uuid.uuid4().hex[:12]
-    # lazy: telemetry must stay importable before/without the autotune
-    # package (which itself imports telemetry.registry)
-    from spark_rapids_ml_tpu.autotune import cache as autotune_cache
-
     return _FitCapture(
         estimator=estimator,
         uid=uid,
@@ -220,7 +208,6 @@ def begin_fit(estimator: str, uid: str = "") -> _FitCapture:
         fit_id=fit_id,
         fit_id_token=spans.set_current_fit_id(fit_id),
         tl_seq=TIMELINE.seq(),
-        tuning_seq=autotune_cache.decision_seq(),
         admission=admission,
     )
 
@@ -264,19 +251,6 @@ def end_fit(cap: _FitCapture) -> FitReport:
         health_mod.end_degrade_window()
     device_memory = compilemon.sample_device_memory()
     delta = REGISTRY.snapshot().delta(cap.snap)
-
-    from spark_rapids_ml_tpu.autotune import cache as autotune_cache
-
-    decisions = autotune_cache.decisions_since(cap.tuning_seq)
-    tuning: dict = {}
-    if decisions:
-        last = decisions[-1]
-        tuning = {
-            "decisions": decisions,
-            "source": last["source"],
-            "cache_hit": last["cache_hit"],
-            "config": last["config"],
-        }
 
     # mean per-stream overlap fraction recorded by stream_fold; None when
     # the fit never streamed (resident path, plain array fits)
@@ -332,7 +306,6 @@ def end_fit(cap: _FitCapture) -> FitReport:
         fit_id=cap.fit_id,
         overlap_fraction=overlap_fraction,
         cost_model=costmodel.window_summary(delta, wall),
-        tuning=tuning,
         health=health,
         admission=cap.admission,
     )

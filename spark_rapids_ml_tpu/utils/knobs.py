@@ -148,20 +148,10 @@ _DECLARATIONS = (
     Knob("TPU_ML_PERF_SENTINEL", "flag", "",
          "`1`: bench runs tools/perf_sentinel.py --strict after appending "
          "the ledger entry", "bench.py"),
-    # -- autotune (spark_rapids_ml_tpu.autotune) ----------------------------
-    Knob("TPU_ML_AUTOTUNE", "enum", "cache",
-         "`off`/`cache`/`search` tuner mode: ignore the tuning cache, "
-         "consult it read-only, or search unseen shape buckets on first "
-         "fit", "autotune.search"),
-    Knob("TPU_ML_AUTOTUNE_TRIALS", "int", "9",
-         "total timing-trial budget of one successive-halving search",
-         "autotune.search"),
-    Knob("TPU_ML_TUNING_CACHE_PATH", "path", "",
-         "persistent JSON tuning cache of blessed search winners (empty = "
-         "in-process only)", "autotune.cache"),
+    # -- kernel precision (spark_rapids_ml_tpu.ops.policy) -------------------
     Knob("TPU_ML_PRECISION_POLICY", "enum", "f32",
          "`f32`/`bf16_f32acc`/`int8_dist` mixed-precision kernel policy "
-         "default (accumulators stay f32)", "autotune.policy"),
+         "default (accumulators stay f32)", "ops.policy"),
     # -- ANN vector search (spark_rapids_ml_tpu.ann + ops.ivf) --------------
     Knob("TPU_ML_ANN_CAP_PERCENTILE", "float", "99.0",
          "IVF bucket-cap percentile over cluster sizes; members beyond the "
@@ -329,9 +319,6 @@ WORKER_PROBE_TIMEOUT = KNOBS["TPU_ML_WORKER_PROBE_TIMEOUT"]
 WORKER_SCRUB_VARS = KNOBS["TPU_ML_WORKER_SCRUB_VARS"]
 PERF_LEDGER_PATH = KNOBS["TPU_ML_PERF_LEDGER_PATH"]
 PERF_SENTINEL = KNOBS["TPU_ML_PERF_SENTINEL"]
-AUTOTUNE = KNOBS["TPU_ML_AUTOTUNE"]
-AUTOTUNE_TRIALS = KNOBS["TPU_ML_AUTOTUNE_TRIALS"]
-TUNING_CACHE_PATH = KNOBS["TPU_ML_TUNING_CACHE_PATH"]
 PRECISION_POLICY = KNOBS["TPU_ML_PRECISION_POLICY"]
 ANN_CAP_PERCENTILE = KNOBS["TPU_ML_ANN_CAP_PERCENTILE"]
 ANN_SAMPLE_ROWS = KNOBS["TPU_ML_ANN_SAMPLE_ROWS"]

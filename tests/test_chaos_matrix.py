@@ -240,7 +240,7 @@ class TestCollectiveRetry:
             lambda c, xd, wd: G.sharded_gram_fold(c, xd, wd, mesh),
             n=12,
             init=G.init_chunk_carry(example, mesh),
-            chunk_rows=G.stream_chunk_rows_for_mesh(mesh),
+            chunk_rows=ingest.stream_chunk_rows_for_mesh(mesh),
             put_fn=G.chunk_put(mesh),
         )
         monkeypatch.setenv(faults.FAULT_PLAN_VAR, "collective:io:1")

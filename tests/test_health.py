@@ -13,7 +13,7 @@ Covers the ISSUE-8 acceptance scenarios without hardware:
   live ``stream.active`` gauge and rolling SLO percentiles;
 - the monitor thread (and any straggling probe thread) shuts down
   cleanly — no dangling named threads after ``stop()``;
-- FitReport schema 6 carries the monitor's ``health`` summary.
+- FitReport (schema >= 5) carries the monitor's ``health`` summary.
 """
 
 from __future__ import annotations
@@ -419,15 +419,15 @@ class TestHttpExporter:
         assert "tpu-ml-health-monitor" not in alive
 
 
-# -- FitReport schema 6 stamping ---------------------------------------------
+# -- FitReport health stamping -----------------------------------------------
 
 
 class TestFitReportHealthStamp:
     def test_fit_report_carries_health_summary(self):
         from spark_rapids_ml_tpu.models.pca import PCA
-        from spark_rapids_ml_tpu.telemetry.report import SCHEMA_VERSION
+        from spark_rapids_ml_tpu.telemetry.report import SCHEMA_VERSION, FitReport
 
-        assert SCHEMA_VERSION == 6
+        assert SCHEMA_VERSION == 7
         health.start_monitor(
             interval_s=3600.0, probe_mode="inline",
             probe_fn=lambda: (True, "ok"),
@@ -440,7 +440,11 @@ class TestFitReportHealthStamp:
         assert rep.health["polls"] >= 1
         assert "slo_breaches" in rep.health
         d = rep.to_dict()
-        assert d["schema"] == 6 and d["health"] == rep.health
+        assert d["schema"] == 7 and d["health"] == rep.health
+        # a v6 record (with the autotuner's stamp of then) still reads
+        old = FitReport.from_dict({**d, "schema": 6, "tuning": {"source": "cache"}})
+        assert old.schema == 6 and old.health == rep.health
+        assert "tuning" not in old.to_dict()
 
     def test_fit_report_health_empty_without_monitor(self):
         from spark_rapids_ml_tpu.models.pca import PCA

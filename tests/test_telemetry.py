@@ -447,7 +447,7 @@ class TestJsonlSink:
         assert [r["estimator"] for r in records] == ["PCA", "StandardScaler"]
         for r in records:
             assert r["type"] == "fit_report"
-            assert r["schema"] == 6
+            assert r["schema"] == 7
             assert len(r["fit_id"]) == 12  # log<->report join key
             assert r["wall_seconds"] > 0
             assert isinstance(r["phases"], dict)

@@ -28,9 +28,7 @@ declaration (or a dump renderer) fails CI.
 The report's dedicated fields re-emit as counters (``rows_ingested``,
 ``h2d_bytes``, ``collective.count``, the full ``compile.*`` family from
 ``telemetry.compilemon`` — count / cache hits+misses / cache time saved —
-and the cost model's ``costmodel.flops`` / ``costmodel.bytes``; the
-autotuner decision trail re-emits as ``autotune.decisions`` labeled by
-kernel and source) and
+and the cost model's ``costmodel.flops`` / ``costmodel.bytes``) and
 per-record scalars (``fit.wall_seconds``, ``transform.wall_seconds``,
 ``compile.seconds`` / ``trace_seconds`` / ``lower_seconds``) as
 one-sample-per-record histograms, all labeled by estimator/transformer.
@@ -171,27 +169,12 @@ def main(argv=None) -> int:
             if comp.get(k):
                 reg.histogram_record(name, comp[k], estimator=est)
         _aggregate_cost_model(reg, rec, estimator=est)
-        _aggregate_tuning(reg, rec, estimator=est)
         ov = rec.get("overlap_fraction")
         if ov is not None:
             reg.histogram_record("stream.overlap_fraction", ov, estimator=est)
 
     sys.stdout.write(reg.to_prometheus())
     return 0
-
-
-def _aggregate_tuning(reg, rec: dict, **labels) -> None:
-    """Re-emit the autotuner decision trail (fit_report schema >= 4
-    ``tuning`` field) as an ``autotune.decisions`` counter labeled by
-    kernel and source. The raw window counters already pass the unlabeled
-    ``autotune.cache_hits``/``cache_misses``/``trials`` family through the
-    generic loop above; this adds the per-kernel attribution those lack."""
-    for d in (rec.get("tuning") or {}).get("decisions") or []:
-        reg.counter_inc(
-            "autotune.decisions", 1,
-            kernel=d.get("kernel", ""), source=d.get("source", ""),
-            **labels,
-        )
 
 
 def _aggregate_cost_model(reg, rec: dict, **labels) -> None:

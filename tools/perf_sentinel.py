@@ -17,11 +17,10 @@ becomes a perf gate (``TPU_ML_PERF_SENTINEL=1`` makes the bench invoke this
 itself after appending). A fresh ledger (fewer than 2 entries) always
 passes — there is no history to regress against. Smoke and full-shape runs
 are never compared with each other (filtered on the entry's ``smoke``
-flag), tuned and untuned runs likewise (filtered on the entry's ``tuning``
-signature, so a bench run under a different autotuner config never judges
-— or poisons — the default-config history), autotuner search-trial
-entries (``search_trial`` flag) are excluded from history outright, and
-metrics absent from history are reported as new, not judged.
+flag), runs under different precision policies likewise (filtered on the
+entry's ``tuning`` signature, so a bench run under ``bf16_f32acc`` never
+judges — or poisons — the default-config history), and metrics absent
+from history are reported as new, not judged.
 
 Two gates stack on top of the history comparison:
 
@@ -82,11 +81,12 @@ def lower_is_better(unit: str) -> bool:
 
 
 def tuning_signature(entry: dict) -> str:
-    """Canonical form of an entry's autotuner configuration.
+    """Canonical form of an entry's ``tuning`` stamp (bench.py writes the
+    precision policy there when it is not ``f32``).
 
-    Entries written before the ``tuning`` field existed — and entries from
+    Entries written before the field existed — and entries from
     default-config runs, which omit it — normalize to the same ``"{}"``
-    signature, so pre-autotuner history keeps judging default runs."""
+    signature, so that history keeps judging default runs."""
     return json.dumps(entry.get("tuning") or {}, sort_keys=True)
 
 
@@ -214,13 +214,10 @@ def main(argv=None) -> int:
 
     current = entries[-1]
     # never judge a smoke run against full-shape history or vice versa,
-    # never cross-compare runs under different tuning configs, and never
-    # let autotuner search trials (transient, intentionally varied
-    # geometry) into the baseline median
+    # and never cross-compare runs under different tuning stamps
     history = [
         e for e in entries[:-1]
         if bool(e.get("smoke")) == bool(current.get("smoke"))
-        and not e.get("search_trial")
         and tuning_signature(e) == tuning_signature(current)
     ]
     if args.last > 0:

@@ -66,7 +66,7 @@ import sys
 # highest fit_report schema this renderer understands (telemetry.report
 # .SCHEMA_VERSION); newer records are skipped with a note, older ones
 # render with defaults for the fields they predate
-SUPPORTED_SCHEMA = 6
+SUPPORTED_SCHEMA = 7
 
 # highest transform_report schema understood
 # (telemetry.report.TRANSFORM_SCHEMA_VERSION)
@@ -320,29 +320,6 @@ def _print_cost_model(rec: dict, out) -> None:
         print(detail, file=out)
 
 
-def _print_tuning(rec: dict, out) -> None:
-    """The autotuner decision line (fit_report schema >= 4): which
-    TuningConfig the fit actually ran with and where it came from."""
-    tuning = rec.get("tuning") or {}
-    if not tuning:
-        return
-    source = tuning.get("source", "?")
-    config = tuning.get("config")
-    if config:
-        desc = (
-            f"chunk_rows={config.get('chunk_rows')}, "
-            f"layout={config.get('layout')}, policy={config.get('policy')}"
-        )
-    else:
-        desc = "static knobs (no tuned config)"
-    n_dec = len(tuning.get("decisions") or [])
-    hit = "cache hit" if tuning.get("cache_hit") else f"source={source}"
-    print(
-        f"autotune: {desc} ({hit}; {n_dec} decision(s) this fit)",
-        file=out,
-    )
-
-
 def _print_admission(rec: dict, out) -> None:
     """The admission-control decision stamped at fit start (fit_report
     schema >= 6): which policy ran and what it decided. Only non-plain
@@ -434,7 +411,6 @@ def render_record(rec: dict, out=sys.stdout) -> list[str]:
             file=out,
         )
     _print_cost_model(rec, out)
-    _print_tuning(rec, out)
     _print_health(rec, out)
     _print_admission(rec, out)
     peak = rec.get("peak_device_bytes", 0)
