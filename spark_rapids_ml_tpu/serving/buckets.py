@@ -2,8 +2,10 @@
 
 The fit path already buckets partition rows (``utils.columnar.bucket_rows``,
 floor ``TPU_ML_MIN_BUCKET=128``) so XLA compiles one program per bucket
-instead of one per batch. Serving needs the same idea with different
-constants: a scoring request is often ONE row, and padding it to 128 wastes
+instead of one per batch (a shard held resident through an iterative fit is
+sized finer, ``utils.columnar.shard_rows``: its padding is paid every
+iteration, a serve rung's once a request). Serving needs the same idea with
+different constants: a scoring request is often ONE row, and padding it to 128 wastes
 latency-path FLOPs, so the serve ladder starts at ``TPU_ML_SERVE_MIN_BUCKET``
 (default 8) and is capped at ``TPU_ML_SERVE_MAX_BATCH_ROWS`` (default 4096).
 The cap matters twice over: it bounds one micro-batched dispatch AND it makes

@@ -291,8 +291,11 @@ def stream_to_mesh(
     into data-sharded global arrays over the driver's device mesh.
 
     One extra ``count()`` pass sizes the shards up front (Spark recomputes
-    an uncached plan the same way); the data pass then stages each
-    device's shard in turn and ships it to its device as it fills.
+    an uncached plan the same way): a device's shard is
+    ``columnar.shard_rows`` of its share of the rows, an eighth of an octave
+    and not a power of two, and what that pads is booked in
+    ``mesh.pad_rows``. The data pass then stages each device's shard in turn
+    and ships it to its device as it fills.
     ``with_weights`` forces a ``ws`` vector even without a ``weight_col``
     (1.0 true rows / 0.0 pads — the pad-mask convention masked mesh
     programs consume).
@@ -326,7 +329,7 @@ def stream_to_mesh(
         dt = wire_dtype()
         n_eff = n + 1 if augment_intercept else n
         ndev = mesh.size
-        shard = columnar.bucket_rows(-(-rows // ndev))
+        shard = columnar.shard_rows(-(-rows // ndev))
         padded_rows = shard * ndev
         _check_size(padded_rows, n_eff, dt, mesh)
 
@@ -383,6 +386,8 @@ def stream_to_mesh(
             # kept for the next ingest only once they are
             with trace_range("stage.reclaim"):
                 jax.block_until_ready([x_parts, y_parts, w_parts])
+        # zero rows of weight 0 that every pass of the fit's programs walks
+        REGISTRY.counter_inc("mesh.pad_rows", padded_rows - rows)
 
         xs = jax.make_array_from_single_device_arrays(
             (padded_rows, n_eff), x_sharding, x_parts

@@ -230,9 +230,10 @@ class _MeshReducePartitionFn:
             from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS, create_mesh
 
             ldc = len(jax.local_devices())
-            # common shard shape: bucket for compile stability, then round to
-            # the per-process device count so the shard splits evenly
-            shard_rows = columnar.bucket_rows(max(max_rows, 1))
+            # common shard shape: the resident shard's rule for compile
+            # stability, then round to the per-process device count so the
+            # shard splits evenly
+            shard_rows = columnar.shard_rows(max_rows)
             shard_rows = ((shard_rows + ldc - 1) // ldc) * ldc
             padded = _pad_to(local, shard_rows)
 

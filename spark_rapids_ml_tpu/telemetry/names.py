@@ -23,6 +23,9 @@ METRICS: frozenset[str] = frozenset({
     "ingest.bytes",
     "ingest.chunk_rows",
     "h2d.bytes",
+    # rows the resident ingest padded its shards with (padded_rows - rows,
+    # once an ingest): zero rows of weight 0 that every pass walks
+    "mesh.pad_rows",
     "columnar.rows",
     "columnar.bytes",
     # collectives / distributed aggregation

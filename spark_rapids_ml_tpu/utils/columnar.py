@@ -499,12 +499,58 @@ def bucket_rows(rows: int, *, min_bucket: int | None = None) -> int:
     reduction we run (Gram, column sums, scaler moments): padded rows
     contribute zero, and true counts ride in ``GramStats.count``.
     The bucket floor comes from the runtime config (TPU_ML_MIN_BUCKET).
+
+    Who rounds to the power of two: a partition of a ``driver-merge`` pass
+    (:func:`pad_rows`: partitions come in every size, each is walked once,
+    and one shape an octave keeps the workers' compiles few), the serve
+    ladder (``serving/buckets.py``, its own floor and cap: every rung is
+    compiled ahead of time) and a streamed fold's chunk
+    (``spark.ingest.stream_chunk_rows``: a geometry the caller states, and
+    one static fold shape is the point of it). A shard held resident on a
+    device is not one of them: see :func:`shard_rows`.
     """
     if min_bucket is None:
         from spark_rapids_ml_tpu.utils.config import get_config
 
         min_bucket = get_config().min_bucket
     return max(min_bucket, 1 << math.ceil(math.log2(max(rows, 1))))
+
+
+# A resident shard is rounded up to a multiple of this share of its octave
+# (the octave of r rows is the half of pow2ceil(r) that r lies in, so a step
+# is pow2ceil(r) / 16). A constant, not a knob, weighed once: a shape costs
+# one compile, paid once with the persistent cache (the k-means|| seeding:
+# some 50 s cold), while a padded row is walked at full price by every
+# iteration of every fit (a zero weight masks a row's result, not its
+# FLOPs). Eight steps pad a shard by under an eighth of its rows, some 4%
+# in the mean, where the power of two pads by up to 100% and 39% in the
+# mean; and a table that grows 1% a night meets a new shape every week or
+# two. Sixteen steps would halve the padding that is left and double the
+# shapes.
+_SHARD_STEPS_PER_OCTAVE = 8
+
+
+def shard_rows(rows: int, *, min_bucket: int | None = None) -> int:
+    """The padded rows of one device's resident shard of ``rows`` true rows
+    (``spark.ingest.stream_to_mesh``, and its ``mesh-barrier`` twin in
+    ``spark.spmd``): ``rows`` rounded up to a multiple of an eighth of its
+    octave, and of ``min_bucket`` where that is more.
+
+    The rows of a resident fit stay on the device through every pass of an
+    iterative program, so they are padded by under an eighth and not up to
+    doubled (``_SHARD_STEPS_PER_OCTAVE`` has the reasons). Over 65,536 rows a
+    step is a multiple of the 8,192-row block the row-blocked programs scan
+    by (``ops.kmeans.kmeans_stats``, ``ops.neighbors``, ``ops.dbscan`` at
+    2,048), so none of them pads again inside itself. Padded rows are zero
+    rows of weight 0, as under :func:`bucket_rows`.
+    """
+    if min_bucket is None:
+        from spark_rapids_ml_tpu.utils.config import get_config
+
+        min_bucket = get_config().min_bucket
+    octave_end = bucket_rows(rows, min_bucket=min_bucket)
+    step = max(min_bucket, octave_end // (2 * _SHARD_STEPS_PER_OCTAVE))
+    return -(-max(rows, 1) // step) * step
 
 
 def pad_rows(x: np.ndarray, *, min_bucket: int | None = None) -> tuple[np.ndarray, int]:

@@ -7,7 +7,9 @@ module is tier 2 for the TPU build — process-level knobs read from
 ``TPU_ML_*`` environment variables once at first use, overridable in code:
 
 - ``TPU_ML_MIN_BUCKET``      (int, default 128)  — row-bucket floor for
-  static-shape padding (utils.columnar.bucket_rows).
+  static-shape padding: of a partition's power-of-two bucket
+  (utils.columnar.bucket_rows) and of a resident shard's step
+  (utils.columnar.shard_rows, an eighth of an octave).
 - ``TPU_ML_MAX_WORKERS``     (int, default 4)    — partition executor pool.
 - ``TPU_ML_TASK_RETRIES``    (int, default 3)    — per-task retry budget
   (the ``spark.task.maxFailures`` analog).

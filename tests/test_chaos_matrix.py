@@ -401,6 +401,9 @@ class TestElasticScheduler:
         instead of respawning forever."""
         monkeypatch.setenv("TPU_ML_WORKER_BREAKER_THRESHOLD", "2")
         monkeypatch.setenv("TPU_ML_WORKER_RESPAWN_BACKOFF_S", "0.01")
+        # on a loaded machine the warm slot hedges slot 0's cold start, and a
+        # crash under a live hedge twin is not counted as a reassignment
+        monkeypatch.setenv("TPU_ML_HEDGE_FACTOR", "0")
 
         def die_on_slot0(batches):
             import os as wos

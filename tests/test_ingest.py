@@ -15,6 +15,7 @@ import pytest
 
 from spark_rapids_ml_tpu.parallel import mesh as M
 from spark_rapids_ml_tpu.spark import ingest
+from spark_rapids_ml_tpu.utils import columnar
 
 
 def _features_batch(mat: np.ndarray, extra: dict | None = None) -> pa.RecordBatch:
@@ -69,11 +70,40 @@ def test_stream_matches_collect_then_pad():
     mesh = M.create_mesh()
     ing = ingest.stream_to_mesh(df, features_col="features", n=n, mesh=mesh)
     assert ing.rows == rows
-    assert ing.padded_rows % mesh.size == 0
+    # a device's shard is an eighth of its octave, not the next power of two
+    shard = columnar.shard_rows(-(-rows // mesh.size))
+    assert ing.padded_rows == shard * mesh.size
     got = np.asarray(ing.xs)
     assert got.shape == (ing.padded_rows, n)
     np.testing.assert_array_equal(got[:rows], df.dense())
     assert not got[rows:].any()  # zero pads
+
+
+@pytest.mark.parametrize("rows", [1_300, 2_048, 2_049, 90])
+def test_stream_pads_to_the_shard_rule_and_books_it(rows):
+    """Two virtual devices: ``padded_rows`` is twice the rule's shard of
+    half the rows, the tail rows are zero rows of weight 0, and
+    ``mesh.pad_rows`` moved by what was padded."""
+    import jax
+
+    from spark_rapids_ml_tpu.telemetry import REGISTRY
+
+    n = 3
+    df = _LazyFrame(rows, n, n_parts=3)
+    mesh = M.create_mesh(devices=jax.devices()[:2])
+    before = REGISTRY.snapshot()
+    ing = ingest.stream_to_mesh(
+        df, features_col="features", n=n, mesh=mesh, with_weights=True
+    )
+    moved = REGISTRY.snapshot().delta(before)
+    shard = columnar.shard_rows(-(-rows // 2))
+    assert ing.padded_rows == 2 * shard and ing.rows == rows
+    assert moved.counter("mesh.pad_rows") == 2 * shard - rows
+    got, w = np.asarray(ing.xs), np.asarray(ing.ws)
+    assert got.shape == (2 * shard, n) and w.shape == (2 * shard,)
+    np.testing.assert_array_equal(got[:rows], df.dense())
+    assert not got[rows:].any() and not w[rows:].any()
+    assert (w[:rows] == 1.0).all()
 
 
 def test_stream_labeled_weighted_and_intercept():

@@ -35,6 +35,7 @@ from spark_rapids_ml_tpu.telemetry import (  # noqa: E402
     metrics,
     reset_metrics,
 )
+from spark_rapids_ml_tpu.utils import columnar  # noqa: E402
 from spark_rapids_ml_tpu.utils.config import get_config, set_config  # noqa: E402
 
 
@@ -169,7 +170,8 @@ def resident_consumer(monkeypatch, batches, extras: bool, fail_after=None):
         weight_col="w" if extras else None,
         with_weights=True, augment_intercept=extras,
     )
-    assert ing.padded_rows == 2 * SET_ROWS
+    # up to 256 rows on two devices: one step of the shard's rule a device
+    assert ing.padded_rows == 2 * columnar.shard_rows(SET_ROWS) == 2 * SET_ROWS
 
     def shards(a):
         if a is None:

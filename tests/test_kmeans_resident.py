@@ -90,6 +90,68 @@ def test_fit_agrees_with_the_plain_reference(session, monkeypatch, init, rows, n
     assert read["center_gap"] < 1e-9 and read["cost_gap"] < 1e-9, read
 
 
+class TestARaggedShard:
+    """3,300 rows on a mesh of two: a device's 1,650 lie five eighths into
+    their octave, so a shard is 1,664 rows where the power of two made it
+    2,048 (dropped: zero rows of weight 0 and whole blocks of them)."""
+
+    ROWS = 3_300
+
+    def ingested(self, session, monkeypatch, rule=None):
+        if rule is not None:
+            monkeypatch.setattr(ingest.columnar, "shard_rows", rule)
+        monkeypatch.setenv(ingest.WIRE_DTYPE_VAR, "float32")
+        mesh = M.create_mesh(devices=jax.devices()[:2])
+        blocks, order = blocks_of(self.ROWS)
+        df = session.createDataFrame(data.to_table(blocks, order))
+        ing = ingest.stream_to_mesh(
+            df, features_col=data.COLUMN, n=N, mesh=mesh, with_weights=True
+        )
+        return ing, np.concatenate(blocks)
+
+    def test_lloyd_from_the_same_centres_agrees_with_the_power_of_two_shard(
+        self, session, monkeypatch
+    ):
+        """The same true rows in the same 256-row blocks, fewer zero blocks
+        behind them: centres and cost equal to float32's rounding (the two
+        shards cut the rows between the devices at other rows, so the
+        ``psum`` adds the same terms in another order)."""
+        ragged, rows = self.ingested(session, monkeypatch)
+        padded, _ = self.ingested(session, monkeypatch, rule=ingest.columnar.bucket_rows)
+        assert (ragged.padded_rows, padded.padded_rows) == (2 * 1_664, 2 * 2_048)
+        assert ragged.xs.dtype == np.float32
+        centres0 = rows[:: self.ROWS // K][:K].astype(np.float32)
+        run = PK.make_distributed_kmeans_chunk(
+            ragged.mesh, chunk_iters=MAX_ITER, tol=0.0, block_rows=256
+        )
+        got = {}
+        for name, ing in (("ragged", ragged), ("padded", padded)):
+            centres, cost, done, _ = run(
+                ing.xs, ing.ws, jax.numpy.asarray(centres0), np.int32(MAX_ITER)
+            )
+            assert int(done) == MAX_ITER
+            got[name] = np.asarray(centres), float(cost)
+        np.testing.assert_allclose(got["ragged"][0], got["padded"][0], rtol=2e-6, atol=2e-6)
+        np.testing.assert_allclose(got["ragged"][1], got["padded"][1], rtol=2e-6)
+
+    def test_the_seeding_draws_true_rows_alone(self, session, monkeypatch):
+        """``k-means||`` through ``SparkKMeans.fit(df)``: 2 x 48 candidates a
+        round from shards that end in 14 and 28 pad rows, and every initial
+        centre is a row of the data, none a pad row, none a row twice."""
+        on_devices(monkeypatch, 2)
+        blocks, order = blocks_of(self.ROWS)
+        df = session.createDataFrame(data.to_table(blocks, order))
+        before = REGISTRY.snapshot()
+        model = estimator(k=24, maxIter=0).fit(df)
+        moved = REGISTRY.snapshot().delta(before)
+        assert moved.counter("mesh.pad_rows") == 2 * 1_664 - self.ROWS
+        assert model.fit_report.counters["mesh.pad_rows"] == 2 * 1_664 - self.ROWS
+        centres0 = np.asarray(model.clusterCenters)
+        assert centres0.shape == (24, N) and np.abs(centres0).sum(axis=1).min() > 0
+        held = reference_kmeans.seeding(blocks, order, centres0, seed=5)
+        assert held["seed_rows_off"] == 0
+
+
 def test_a_loop_that_reaches_its_tolerance_counts_fewer_iterations(session):
     blocks, order = blocks_of(512, seed=3)
     df = session.createDataFrame(data.to_table(blocks, order))
@@ -228,8 +290,9 @@ class TestResidentStaging:
         assert self.states(moved) == {"reused": 0, "fresh": 1, "aliased": 0}
         (kept,) = ingest._kept_staging
         assert kept.x.dtype == first.xs.dtype and kept.dirty == self.ROWS
-        # fewer rows, other values: the set is rewritten, the stale tail zeroed
-        second, mat2, moved = self.ingest(self.ROWS - 100, mesh, scale=-2.0)
+        # fewer rows of the same shard (641 to 768 rows share one), other
+        # values: the set is rewritten, the stale tail zeroed
+        second, mat2, moved = self.ingest(self.ROWS - 50, mesh, scale=-2.0)
         assert self.states(moved) == {"reused": 1, "fresh": 0, "aliased": 0}
         assert ingest._kept_staging[0] is kept
         np.testing.assert_array_equal(np.asarray(first.xs)[: self.ROWS], mat)

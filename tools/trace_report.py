@@ -390,6 +390,9 @@ def render_record(rec: dict, out=sys.stdout) -> list[str]:
             line += f" ({rows_in / wall:,.0f} rows/s)"
         if rec.get("h2d_bytes"):
             line += f"; h2d {_fmt_bytes(rec['h2d_bytes'])}"
+        pad_rows = rec.get("counters", {}).get("mesh.pad_rows")
+        if pad_rows:
+            line += f"; {pad_rows:g} pad rows on the mesh"
         print(line, file=out)
     coll = rec.get("collectives", {})
     if coll.get("count") or coll.get("tree_combines"):
