@@ -339,7 +339,12 @@ def finalize_chunk_fold(carry, mesh: Mesh):
 
     The carry is deliberately NOT donated here, so a transient collective
     failure (site ``collective``) is safe to retry in place — the partials
-    are still valid."""
+    are still valid.
+
+    Span ``fold.finalize`` closes on the total being ready, not on its
+    dispatch: the eager decomposition that follows needs it at once, so no
+    overlap is lost, and the span reads the collective's exposed time on the
+    host (otherwise whoever touches the total next would pay for it)."""
     from spark_rapids_ml_tpu.parallel.backend import allreduce
     from spark_rapids_ml_tpu.resilience import faults
     from spark_rapids_ml_tpu.resilience import retry as _retry
@@ -356,10 +361,12 @@ def finalize_chunk_fold(carry, mesh: Mesh):
         return jax.tree.map(lambda v: allreduce(v, mesh, DATA_AXIS), carry)
 
     with trace_range("fold.finalize"):
-        return _retry.call_with_retry(
-            run,
-            site="collective",
-            retry_on=frozenset({_retry.ErrorClass.TRANSIENT}),
+        return jax.block_until_ready(
+            _retry.call_with_retry(
+                run,
+                site="collective",
+                retry_on=frozenset({_retry.ErrorClass.TRANSIENT}),
+            )
         )
 
 

@@ -1241,6 +1241,11 @@ def stream_fold(
             overlapped += 1
         max_put = max(max_put, nbytes)
         REGISTRY.counter_inc("h2d.bytes", nbytes, path="stream")
+        # one device's share each: the data axis of a mesh, 1 without one
+        REGISTRY.counter_inc(
+            "h2d.shards", len(getattr(xd, "addressable_shards", (xd,))),
+            path="stream",
+        )
         n_chunks += 1
 
     def dispatch_buffers(staged):

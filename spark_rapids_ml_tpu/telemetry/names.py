@@ -23,6 +23,9 @@ METRICS: frozenset[str] = frozenset({
     "ingest.bytes",
     "ingest.chunk_rows",
     "h2d.bytes",
+    # addressable shards the streamed fold put, once a chunk (path="stream"):
+    # the data axis of the mesh, so chunks x devices over a fit
+    "h2d.shards",
     # rows the resident ingest padded its shards with (padded_rows - rows,
     # once an ingest): zero rows of weight 0 that every pass walks
     "mesh.pad_rows",

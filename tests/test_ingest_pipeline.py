@@ -401,6 +401,7 @@ COUNTERS = (
         ("fold.input_in_flight", {}),
         ("h2d.bytes", {"path": "stream"}),
         ("h2d.bytes", {"path": "mesh"}),
+        ("h2d.shards", {"path": "stream"}),
         ("kmeans.iterations", {"path": "mesh-local"}),
         ("ingest.rows", {}),
         ("ingest.bytes", {}),
@@ -411,6 +412,7 @@ COUNTERS = (
 # the second of two fits of 1,650 rows x 8 in three Arrow batches of 550 on a
 # mesh of four. PCA streams chunks of 512 rows: 4 chunks, the last ragged.
 # KMeans holds a shard of 512 rows a device: 4 shards, the last mostly pad.
+# Since then: h2d.shards{path=stream}, 4 chunks x 4 devices.
 CENSUS = {
     "pca_streamed": (
         {
@@ -431,6 +433,7 @@ CENSUS = {
             "stage.buffers{state=reused}": 4,
             "ingest.batches{path=inline}": 7,
             "h2d.bytes{path=stream}": 147456,
+            "h2d.shards{path=stream}": 16,
             "ingest.rows": 1650,
             "ingest.bytes": 105600,
         },
