@@ -17,7 +17,7 @@ WORKER_TASK = "worker.task"       # localspark worker / executor task entry
 COLLECTIVE = "collective"         # cross-device collective dispatch
 DEVICE_INIT = "device.init"       # backend/device initialization
 FOLD_DISPATCH = "fold.dispatch"   # streamed-fit chunk dispatch
-FOLD_WAIT = "fold.wait"           # streamed-fit terminal device wait
+FOLD_WAIT = "fold.wait"           # streamed-fit device waits: a chunk landing, the terminal one
 INGEST_CHUNK = "ingest.chunk"     # streamed-fit chunk staging
 # driver-side elastic-scheduler gates: unlike worker.task (which every
 # worker process counts independently), these count in the DRIVER, so a

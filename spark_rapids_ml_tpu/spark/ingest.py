@@ -43,6 +43,7 @@ alternatives.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import queue
@@ -606,13 +607,14 @@ def _split_chunk_buffers(bx, by, bw, size: int):
 # The host pass's pool: a batch's per-byte work, cut by rows over a few threads
 # ---------------------------------------------------------------------------
 
-# The non-finite scan and the cast-copy into the staging set run at one
-# core's pace on one thread; NumPy's ufuncs, reductions and casting
-# assignments release the GIL, so row blocks of one batch run side by side.
-# The sizes are read off the chip host's curve (PERF.md section 6, PR 29: a
-# 256 MiB float64 batch, 13 cores): both passes stop gaining at 4-8 threads,
-# where memory sets the pace; blocks of 16 MiB are the fastest, and under
-# 4 MiB handing a block over costs what running it there saves.
+# The cast-copy into the staging set (and a non-finite check, where the host
+# is the one to make it) runs at one core's pace on one thread; NumPy's
+# ufuncs, reductions and casting assignments release the GIL, so row blocks
+# of one batch run side by side. The sizes are read off the chip host's curve
+# (PERF.md section 6, PR 29: a 256 MiB float64 batch, 13 cores): both passes
+# stop gaining at 4-8 threads, where memory sets the pace; blocks of 16 MiB
+# are the fastest, and under 4 MiB handing a block over costs what running
+# it there saves.
 _POOL_BLOCK_BYTES = 16 << 20
 _POOL_MIN_BLOCK_BYTES = 4 << 20
 _POOL_MAX_WORKERS = 8
@@ -706,14 +708,50 @@ def _run_blocks(fn, blocks: list[tuple[int, int]]) -> list:
 
 
 def _all_finite(x: np.ndarray) -> bool:
-    """``np.isfinite(x).all()``, answered block by block (a block's bool
-    temporary is an eighth of the block, not of the batch)."""
+    """``np.isfinite(x).all()`` of a staged buffer, in the set's dtype,
+    answered block by block (a block's bool temporary is an eighth of the
+    block, not of the buffer): the host's verdict on a chunk whose ``put_fn``
+    handed back host arrays."""
     return all(
         _run_blocks(
             lambda start, stop: bool(np.isfinite(x[start:stop]).all()),
             _row_blocks(len(x), x.nbytes),
         )
     )
+
+
+def _bad_rows(bufs: list[np.ndarray]) -> np.ndarray:
+    """The rows of a staged chunk (``x`` first, then its vectors) with a
+    non-finite feature, label or weight, as a mask (pads are zeros, so they
+    are never among them); by the same row blocks. Only a chunk whose
+    verdict was "no" pays for it."""
+
+    def bad(start: int, stop: int) -> np.ndarray:
+        mask = np.zeros(stop - start, bool)
+        for b in bufs:
+            finite = np.isfinite(b[start:stop])
+            mask |= ~(finite.all(axis=1) if finite.ndim == 2 else finite)
+        return mask
+
+    return np.concatenate(_run_blocks(bad, _row_blocks(len(bufs[0]), bufs[0].nbytes)))
+
+
+@functools.cache
+def _chunk_finite_prog():
+    """The device's verdict on a chunk that was put: one program (module
+    ``jit__chunk_finite``; a test pins the name) that answers whether every
+    element of every array is finite. ``isfinite`` fuses into the reduction,
+    so nothing the size of the chunk is written; over a mesh the shards'
+    answers meet in one replicated bool."""
+    import jax
+    import jax.numpy as jnp
+
+    def _chunk_finite(arrays):
+        return functools.reduce(
+            jnp.logical_and, [jnp.isfinite(a).all() for a in arrays]
+        )
+
+    return jax.jit(_chunk_finite)
 
 
 def _shares_memory(placed, buf: np.ndarray) -> bool:
@@ -945,8 +983,8 @@ class _Stager:
 
 def _batches(chunks: Iterator, n: int, features_col: str | None) -> Iterator:
     """The head of both ingests: each next ``(x, y, w)`` batch of ``chunks``
-    pulled from the source alone under span ``ingest.chunk`` (the scan and
-    the staging copy have their own, ``ingest.scan`` and ``ingest.stage``),
+    pulled from the source alone under span ``ingest.chunk`` (the staging
+    copy has its own, ``ingest.stage``),
     booked in ``ingest.rows`` / ``ingest.bytes`` / ``ingest.chunk_rows`` and
     held to the width ``n``."""
     from spark_rapids_ml_tpu.telemetry import trace_range
@@ -1011,23 +1049,41 @@ def stream_fold(
     chunk i's fold executes on the MXU, the host is already extracting and
     ``device_put``-ing chunk i+1. Each phase is traced, so the overlap is
     observable and the host's seconds have names (telemetry.metrics()):
-    ``ingest.chunk`` (the pull), ``ingest.scan`` (the non-finite check),
-    the staging pipeline's ``ingest.stage`` and ``stage.reclaim``
-    (:class:`_Stager`: a full set is one fold chunk here),
-    ``fold.dispatch`` with ``h2d.put`` and ``fold.enqueue`` inside it, and
-    ``fold.wait``; ``fold.input_in_flight`` counts the chunks whose transfer
-    had not landed when their fold was enqueued.
+    ``ingest.chunk`` (the pull), the staging pipeline's ``ingest.stage``
+    and ``stage.reclaim`` (:class:`_Stager`: a full set is one fold chunk
+    here), ``fold.dispatch`` with ``h2d.put``, the chunk's verdict
+    (``fold.wait``, ``ingest.scan``) and ``fold.enqueue`` inside it, and the
+    terminal ``fold.wait``; ``fold.input_in_flight`` counts the chunks whose
+    transfer had not landed when their fold was enqueued (under
+    ``nonfinite="allow"`` alone: a chunk that is asked about has landed).
 
-    The scan and the copy are the host's per-byte work on a batch, and a
-    batch large enough to cut has both run by row blocks on the host pass's
-    pool of threads (``_row_blocks``, ``_run_blocks``): this thread hands the
-    blocks over and waits, inside ``ingest.scan`` and ``ingest.stage``, so
-    the spans stay one a batch and read the wall time of the pass. A batch
-    too small to cut runs here, through the same helpers
-    (``ingest.batches{path}`` says which). What a block of the scan answers
-    is only whether all of it is finite: a "no" sends the batch to the
-    per-row mask, the filter, the count and the error below, on this thread
-    and as they always were.
+    The copy is the host's per-byte work on a batch, and a batch large enough
+    to cut has it run by row blocks on the host pass's pool of threads
+    (``_row_blocks``, ``_run_blocks``): this thread hands the blocks over and
+    waits, inside ``ingest.stage``, so the span stays one a slice and reads
+    the wall time of the pass. A batch too small to cut runs here, through
+    the same helpers (``ingest.batches{path}`` says which).
+
+    THE VERDICT. Whether a chunk is finite is asked once a chunk, of the
+    chunk that was put and in the dtype the device holds, after ``put`` and
+    before its fold is enqueued, so a chunk that is not clean never reaches
+    the carry or a checkpoint. What ``put`` returned decides where: a
+    ``jax.Array`` is waited for (span ``fold.wait``, bounded as the terminal
+    wait is: with one staging set the host would wait for this landing
+    before it writes the next chunk anyway, and the fold cannot start before
+    it) and asked on its device by one fused reduction over ``x``, ``w`` and
+    ``y`` (program ``jit__chunk_finite``; span ``ingest.scan`` is its
+    dispatch and the read of its answer, nothing else); a host array (an
+    identity ``put_fn``) is asked on the host of the staged buffers
+    (``_all_finite``). Counter ``ingest.verdicts{where, clean}`` books each.
+    After a "no" the staging buffers are still the host's, so the rows are
+    found there (``_bad_rows``): ``raise`` ends the fit with their count;
+    ``skip`` zeroes them with weight 0 (the framework's mask, exact as pads
+    are), moves them from ``rows`` to ``skipped_rows`` (the resume cursor,
+    their sum, does not move), puts the chunk again, asks again and folds
+    it, unless no row of weight is left. The OOM bisection asks of each
+    piece it puts, and the retry of a transient asks again. A float64 beyond
+    float32's range is ``inf`` on a float32 device, and so a non-finite row.
 
     ``source`` is either a DataFrame-shaped object (localspark / pyspark —
     drained via the same strategy-gated ``_iter_chunks`` the resident
@@ -1062,10 +1118,10 @@ def stream_fold(
       the fold continues from the restored carry — bitwise-identical to
       the uninterrupted fit (same chunks, same fold order);
     - non-finite input rows follow ``nonfinite``
-      (``TPU_ML_NONFINITE_POLICY``): ``raise`` (default), ``skip`` (drop +
-      count ``rows.nonfinite_skipped``), or ``allow`` (no scan);
-    - the terminal wait is bounded (``TPU_ML_FOLD_WAIT_TIMEOUT_S``): a
-      hung device surfaces a ``FoldHangTimeout`` diagnosis, not a block.
+      (``TPU_ML_NONFINITE_POLICY``): ``raise`` (default), ``skip`` (mask +
+      count ``rows.nonfinite_skipped``), or ``allow`` (nothing is asked);
+    - the waits are bounded (``TPU_ML_FOLD_WAIT_TIMEOUT_S``): a hung
+      device surfaces a ``FoldHangTimeout`` diagnosis, not a block.
     """
     import jax
 
@@ -1202,6 +1258,54 @@ def stream_fold(
             flush=True,
         )
 
+    def chunk_is_finite(arrays, bufs) -> bool:
+        """The verdict on one chunk that was put, asked where ``put``
+        left it: ``arrays`` on their device(s), once they have landed (the
+        wait is span ``fold.wait``'s, bounded as the terminal one is; span
+        ``ingest.scan`` holds the program's dispatch and the read of its
+        answer alone), or, where ``put`` handed back host arrays, the staged
+        ``bufs`` on the host."""
+        on_device = isinstance(arrays[0], jax.Array)
+        if on_device:
+            with trace_range("fold.wait"):
+                _bounded_wait(arrays, fold_wait_timeout_s)
+        with trace_range("ingest.scan"):
+            if on_device:
+                clean = bool(_chunk_finite_prog()(arrays))
+            else:
+                clean = all(_all_finite(b) for b in bufs)
+        REGISTRY.counter_inc(
+            "ingest.verdicts",
+            where="device" if on_device else "host",
+            clean="yes" if clean else "no",
+        )
+        return clean
+
+    def mask_bad_rows(bufs) -> None:
+        """What a "no" costs: the host finds the rows in the staged
+        buffers, which are still its own. ``raise`` ends the fit; ``skip``
+        zeroes them with weight 0 (the framework's mask, exact as pads are)
+        and moves them from ``seen`` to ``skipped``."""
+        nonlocal seen, skipped
+        bad = _bad_rows(bufs)
+        n_bad = int(bad.sum())
+        if not n_bad:
+            raise ValueError(
+                "a streamed chunk is not finite where put_fn left it, and "
+                "every staged row of it is: put_fn may place a chunk, not "
+                "change its values"
+            )
+        if nonfinite == "raise":
+            raise ValueError(
+                f"{n_bad} non-finite input row(s) in a streamed chunk; set "
+                "TPU_ML_NONFINITE_POLICY=skip to drop and count them instead"
+            )
+        for b in bufs:
+            b[bad] = 0
+        seen -= n_bad
+        skipped += n_bad
+        REGISTRY.counter_inc("rows.nonfinite_skipped", n_bad)
+
     def attempt_fold(staged, xb, yb, wb):
         nonlocal carry, n_chunks, overlapped, max_put
         busy = any(
@@ -1209,6 +1313,7 @@ def stream_fold(
             for leaf in jax.tree_util.tree_leaves(carry)
             if hasattr(leaf, "is_ready")
         )
+        bufs = [b for b in (xb, wb, yb) if b is not None]
         with trace_range("fold.dispatch"):
             # inject BEFORE the donated fold consumes its buffers, so the
             # carry is still valid when the retry re-enters
@@ -1216,29 +1321,36 @@ def stream_fold(
             # arrays put from the staging set are kept until it is reclaimed
             placed = staged.placed if xb is staged.x else []
 
-            def place(buf):
-                arr = put(buf)
-                placed.append(arr)
-                return arr
+            def put_chunk():
+                with trace_range("h2d.put"):
+                    arrays = [put(b) for b in bufs]
+                placed.extend(arrays)
+                return arrays
 
-            with trace_range("h2d.put"):
-                xd = place(xb)
-                wd = place(wb)
-                yd = place(yb) if yb is not None else None
-            nbytes = xb.nbytes + wb.nbytes
-            if yb is not None:
-                nbytes += yb.nbytes
+            arrays = put_chunk()
+            # the verdict, before the fold: a chunk that is not clean never
+            # reaches the carry. The arrays have landed by then, so the
+            # buffers they were put from may be written (THE BUFFER RULE; an
+            # array that shares their memory changes with them)
+            while nonfinite != "allow" and not chunk_is_finite(arrays, bufs):
+                mask_bad_rows(bufs)
+                if not wb.any():
+                    return  # no true row left: nothing to fold
+                # put again, the stale arrays let go first: one chunk resident
+                del placed[-len(arrays):]
+                del arrays
+                arrays = put_chunk()
+            xd, wd = arrays[:2]
             # the fold's device time holds a wait for its chunk's DMA when
-            # the chunk has not landed by now (is_ready: no sync)
+            # the chunk has not landed by now (is_ready: no sync), which
+            # only a chunk nothing was asked of can be
             if hasattr(xd, "is_ready") and not xd.is_ready():
                 REGISTRY.counter_inc("fold.input_in_flight")
             with trace_range("fold.enqueue"):
-                if yb is not None:
-                    carry = fold_fn(carry, xd, yd, wd)
-                else:
-                    carry = fold_fn(carry, xd, wd)
+                carry = fold_fn(carry, xd, *arrays[2:], wd)
         if busy:
             overlapped += 1
+        nbytes = sum(b.nbytes for b in bufs)
         max_put = max(max_put, nbytes)
         REGISTRY.counter_inc("h2d.bytes", nbytes, path="stream")
         # one device's share each: the data axis of a mesh, 1 without one
@@ -1320,9 +1432,9 @@ def stream_fold(
                     raise ValueError("label column missing from a streamed chunk")
                 if resume_skip:
                     # replaying an already-checkpointed prefix: drop the raw
-                    # rows a prior run consumed (counted BEFORE any filtering,
-                    # so the cursor is exact regardless of the non-finite
-                    # policy)
+                    # rows a prior run consumed (seen + skipped: a masked row
+                    # counts among them, so the cursor is exact whatever the
+                    # non-finite policy)
                     drop = min(resume_skip, len(xc))
                     resume_skip -= drop
                     xc = xc[drop:]
@@ -1336,40 +1448,6 @@ def stream_fold(
                     policy=policy,
                     retry_on=transient_only,
                 )
-                if nonfinite != "allow":
-                    with trace_range("ingest.scan"):
-                        if not (
-                            # scalar pre-check keeps the all-finite fast path
-                            # off the per-row mask allocation; a "no" from any
-                            # block falls through to the rows, on this thread
-                            _all_finite(xc)
-                            and (yc is None or np.isfinite(yc).all())
-                            and (wc is None or np.isfinite(wc).all())
-                        ):
-                            bad = ~np.isfinite(xc).all(axis=1)
-                            if yc is not None:
-                                bad |= ~np.isfinite(yc)
-                            if wc is not None:
-                                bad |= ~np.isfinite(wc)
-                            n_bad = int(bad.sum())
-                            if n_bad:
-                                if nonfinite == "raise":
-                                    raise ValueError(
-                                        f"{n_bad} non-finite input row(s) in a "
-                                        "streamed chunk; set "
-                                        "TPU_ML_NONFINITE_POLICY=skip to drop "
-                                        "and count them instead"
-                                    )
-                                keep = ~bad
-                                xc = xc[keep]
-                                yc = yc[keep] if yc is not None else None
-                                wc = wc[keep] if wc is not None else None
-                                skipped += n_bad
-                                REGISTRY.counter_inc(
-                                    "rows.nonfinite_skipped", n_bad
-                                )
-                                if not len(xc):
-                                    continue
                 stager.feed(xc, yc, wc)
             if stager.fill:
                 stager.flush()  # ragged tail: pads ride the w=0 mask, exactly

@@ -56,6 +56,11 @@ METRICS: frozenset[str] = frozenset({
     # path its copy took: path="pool" (cut by rows over the host pass's
     # threads) or "inline" (too small to cut: the caller's thread)
     "ingest.batches",
+    # one increment for each chunk the streamed fold asked about (whether
+    # it is finite everywhere, before its fold): where="device" (what put
+    # returned is a jax.Array: program jit__chunk_finite) or "host" (a host
+    # array: the staged buffers), clean="yes" or "no"
+    "ingest.verdicts",
     # Lloyd iterations a fit's program ran, by path (a loop that met its
     # tolerance or a fixed point runs fewer than maxIter)
     "kmeans.iterations",

@@ -55,8 +55,9 @@ module is tier 2 for the TPU build — process-level knobs read from
   on the streamed fit's terminal device wait; a wedged device surfaces as
   a diagnosable ``FoldHangTimeout`` instead of blocking forever.
 - ``TPU_ML_NONFINITE_POLICY`` ('raise'|'skip'|'allow', default 'raise') —
-  streamed-fit handling of non-finite input rows: fail the fit, drop and
-  count them (``rows.nonfinite_skipped``), or skip the scan entirely.
+  streamed-fit handling of non-finite input rows, asked once a chunk of the
+  chunk that was put (on its device, in the dtype the device holds): fail
+  the fit, mask and count them (``rows.nonfinite_skipped``), or ask nothing.
 - ``TPU_ML_FAULT_PLAN`` (read by ``resilience.faults``, not cached here) —
   deterministic fault-injection plan for chaos testing; see the Resilience
   README section. Never set in production.

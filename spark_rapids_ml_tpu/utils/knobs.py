@@ -76,11 +76,14 @@ _DECLARATIONS = (
          "checkpoint the streamed-fit carry every K full chunks (with a "
          "checkpoint_dir)", "utils.config"),
     Knob("TPU_ML_FOLD_WAIT_TIMEOUT_S", "int", "600",
-         "bound on the streamed fit's terminal device wait (0 = unbounded)",
+         "bound on each of the streamed fit's device waits, a chunk's "
+         "landing and the terminal one (0 = unbounded)",
          "utils.config"),
     Knob("TPU_ML_NONFINITE_POLICY", "enum", "raise",
          "`raise`/`skip`/`allow` for non-finite input rows in streamed "
-         "fits", "utils.config"),
+         "fits: asked once a chunk, of the chunk as put (on its device, in "
+         "the device's dtype); `skip` masks and counts, `allow` asks "
+         "nothing", "utils.config"),
     Knob("TPU_ML_FAULT_PLAN", "str", "",
          "`site:kind:nth[:arg]` comma list of deterministic synthetic "
          "faults (chaos tests only — never production)",

@@ -1200,3 +1200,75 @@ class TestFleetTraceChaos:
             )
         finally:
             fleet.stop()
+
+
+class TestVerdictUnderFaults:
+    """The chunk's verdict (asked of the chunk that was put, before its
+    fold) under the faults the fold heals: a bad chunk never reaches the
+    carry or a checkpoint, and the OOM bisection asks of each piece."""
+
+    PUTS = {"device": None, "identity": staticmethod(lambda a: a)}
+
+    @staticmethod
+    def verdicts(d, where):
+        return {
+            clean: int(d.counter("ingest.verdicts", where=where, clean=clean))
+            for clean in ("yes", "no")
+        }
+
+    @pytest.mark.parametrize("put", ["device", "identity"])
+    def test_a_bad_chunk_after_a_saved_one_leaves_a_finite_checkpoint(
+        self, data, tmp_path, snap, put
+    ):
+        x = data[0].copy()
+        bad_rows = [300, 301, 700]  # chunks 3 and 6 of 128 rows
+        x[bad_rows, 2] = np.nan
+        put_fn = self.PUTS[put]
+        clean = _gram_stream(x, nonfinite="skip", put_fn=put_fn)
+        ckpt = TrainingCheckpointer(tmp_path / "ck")
+        with pytest.raises(ValueError, match=r"^2 non-finite input row\(s\)"):
+            _gram_stream(
+                x, nonfinite="raise", put_fn=put_fn,
+                checkpointer=ckpt, checkpoint_every=1,
+            )
+        # chunks 1 and 2 were saved; the third never reached the carry
+        assert snap.delta().counter("stream.checkpoints") == 2
+        step, arrays, state = ckpt.latest()
+        assert state["chunks"] == 2 and state["rows_seen"] == 256
+        assert all(np.isfinite(a).all() for a in arrays.values())
+        res = _gram_stream(
+            x, nonfinite="skip", put_fn=put_fn,
+            checkpointer=ckpt, checkpoint_every=1,
+        )
+        assert res.resumed and res.chunks == clean.chunks
+        assert res.skipped_rows == len(bad_rows)
+        assert res.rows == len(x) - len(bad_rows)
+        # bitwise: the same chunks, masked alike, in the same order
+        for got, want in zip(res.carry, clean.carry):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        _assert_gram_equal(res.carry, np.delete(x, bad_rows, axis=0))
+        # and a checkpoint taken after a masked chunk carries its count on
+        step, arrays, state = ckpt.latest()
+        assert state["rows_seen"] + state["skipped_rows"] == 1024
+        assert state["skipped_rows"] == len(bad_rows)
+
+    @pytest.mark.parametrize("put", ["device", "identity"])
+    @pytest.mark.parametrize("nonfinite", ["raise", "skip"])
+    def test_the_bisection_asks_of_each_piece(
+        self, data, monkeypatch, snap, put, nonfinite
+    ):
+        x = data[0][:128].copy()
+        x[100, 5] = np.inf  # the second piece of the one chunk
+        where = "device" if put == "device" else "host"
+        kw = dict(nonfinite=nonfinite, put_fn=self.PUTS[put], min_chunk_rows=64)
+        if nonfinite == "raise":
+            with pytest.raises(ValueError, match=r"^1 non-finite input row\(s\)"):
+                _gram_stream(x, "fold.dispatch:oom:1", monkeypatch, **kw)
+            # the first piece was clean and folded; the second said no
+            assert self.verdicts(snap.delta(), where) == {"yes": 1, "no": 1}
+            return
+        res = _gram_stream(x, "fold.dispatch:oom:1", monkeypatch, **kw)
+        assert res.bisections == 1 and res.chunks == 2
+        assert res.skipped_rows == 1 and res.rows == 127
+        assert self.verdicts(snap.delta(), where) == {"yes": 2, "no": 1}
+        _assert_gram_equal(res.carry, np.delete(x, 100, axis=0))

@@ -398,6 +398,10 @@ COUNTERS = (
     [("stage.buffers", {"state": s}) for s in ("fresh", "reused", "aliased")]
     + [("ingest.batches", {"path": p}) for p in ("pool", "inline")]
     + [
+        ("ingest.verdicts", {"where": w, "clean": c})
+        for w in ("device", "host") for c in ("yes", "no")
+    ]
+    + [
         ("fold.input_in_flight", {}),
         ("h2d.bytes", {"path": "stream"}),
         ("h2d.bytes", {"path": "mesh"}),
@@ -412,7 +416,9 @@ COUNTERS = (
 # the second of two fits of 1,650 rows x 8 in three Arrow batches of 550 on a
 # mesh of four. PCA streams chunks of 512 rows: 4 chunks, the last ragged.
 # KMeans holds a shard of 512 rows a device: 4 shards, the last mostly pad.
-# Since then: h2d.shards{path=stream}, 4 chunks x 4 devices.
+# Since then: h2d.shards{path=stream}, 4 chunks x 4 devices; and the
+# non-finite check asked once a chunk of the chunk that was put (fold.wait for
+# its landing, then ingest.scan, both inside fold.dispatch), not once a batch.
 CENSUS = {
     "pca_streamed": (
         {
@@ -420,11 +426,12 @@ CENSUS = {
             ("eigh", None): 1,
             ("model.to_host", None): 1,
             ("ingest.chunk", "compute cov"): 5,
-            ("ingest.scan", "compute cov"): 4,
             ("ingest.stage", "compute cov"): 8,
             ("stage.reclaim", "compute cov"): 4,
             ("fold.dispatch", "compute cov"): 4,
             ("h2d.put", "fold.dispatch"): 4,
+            ("fold.wait", "fold.dispatch"): 4,
+            ("ingest.scan", "fold.dispatch"): 4,
             ("fold.enqueue", "fold.dispatch"): 4,
             ("fold.wait", "compute cov"): 1,
             ("fold.finalize", "compute cov"): 1,
@@ -434,6 +441,7 @@ CENSUS = {
             "ingest.batches{path=inline}": 7,
             "h2d.bytes{path=stream}": 147456,
             "h2d.shards{path=stream}": 16,
+            "ingest.verdicts{where=device,clean=yes}": 4,
             "ingest.rows": 1650,
             "ingest.bytes": 105600,
         },

@@ -206,7 +206,8 @@ class TestStreamedOverlap:
         )
         m = metrics()
         assert m["fold.dispatch"]["count"] == res.chunks
-        assert m["fold.wait"]["count"] == 1
+        # each chunk's landing before its verdict, and the terminal wait
+        assert m["fold.wait"]["count"] == res.chunks + 1
         # one span per source pull (3 partitions) + the exhausting pull
         assert m["ingest.chunk"]["count"] == 4
 
@@ -231,9 +232,9 @@ class TestStreamedOverlap:
 
 class TestStreamedSpans:
     """The spans and the counter that name a streamed fit's host seconds:
-    ingest.scan and ingest.stage for every source batch, h2d.put and
-    fold.enqueue once a chunk inside fold.dispatch, fold.finalize around the
-    one collective (the benchmark's per-layer metrics read them)."""
+    ingest.stage for every source batch, h2d.put, the verdict's ingest.scan
+    and fold.enqueue once a chunk inside fold.dispatch, fold.finalize around
+    the one collective (the benchmark's per-layer metrics read them)."""
 
     @staticmethod
     def fold(x, batches, **kw):
@@ -253,26 +254,29 @@ class TestStreamedSpans:
         return res, metrics(), spans, REGISTRY.snapshot()
 
     @pytest.mark.parametrize("batches", [1, 3, 7])
-    def test_scan_and_stage_for_every_batch(self, data, batches):
+    def test_scan_once_a_chunk_and_stage_for_every_batch(self, data, batches):
         x, _, _ = data
         res, m, _, _ = self.fold(x, batches)
-        assert m["ingest.scan"]["count"] == batches
+        assert m["ingest.scan"]["count"] == res.chunks == 3
         # one slice a batch and one more for every chunk boundary inside a
         # batch; a rewritten set's ragged tail is zeroed under one more.
         # Taking a set books no span of its own
         assert m["ingest.stage"]["count"] >= max(batches, res.chunks)
         assert m["ingest.stage"]["count"] <= batches + res.chunks
 
-    def test_put_and_enqueue_once_a_chunk_inside_dispatch(self, data):
+    @pytest.mark.parametrize("nonfinite", ["raise", "allow"])
+    def test_put_and_enqueue_once_a_chunk_inside_dispatch(self, data, nonfinite):
         x, _, _ = data
-        res, m, spans, snap = self.fold(x, 3)
+        res, m, spans, snap = self.fold(x, 3, nonfinite=nonfinite)
         assert res.chunks == 3
         for phase in ("h2d.put", "fold.enqueue", "fold.dispatch"):
             assert m[phase]["count"] == res.chunks, phase
         for e in spans:
-            if e["name"] in ("h2d.put", "fold.enqueue"):
+            if e["name"] in ("h2d.put", "fold.enqueue", "ingest.scan"):
                 assert e["args"]["parent"] == "fold.dispatch"
-        # so fold.dispatch's own seconds are what neither covers
+        if nonfinite != "allow":
+            return
+        # nothing asked: fold.dispatch's own seconds are what neither covers
         own = snap.hist("span.self_seconds", phase="fold.dispatch").total
         assert own == pytest.approx(
             m["fold.dispatch"]["seconds"]
@@ -296,17 +300,20 @@ class TestStreamedSpans:
         assert "ingest.scan" not in m
         assert m["ingest.stage"]["count"] >= 3
 
-    def test_scan_covers_the_filter_when_it_trips(self, data):
+    def test_scan_is_once_a_chunk_and_once_more_after_a_mask(self, data):
         x, _, _ = data
         bad = x.copy()
         bad[5, 2] = np.nan
         res, m, _, _ = self.fold(bad, 3, nonfinite="skip")
         assert res.skipped_rows == 1 and res.rows == len(x) - 1
-        assert m["ingest.scan"]["count"] == 3
+        # the first chunk is asked, masked, put again and asked again
+        assert m["ingest.scan"]["count"] == res.chunks + 1 == 4
+        assert m["h2d.put"]["count"] == res.chunks + 1
         with pytest.raises(ValueError, match="non-finite"):
             self.fold(bad, 3, nonfinite="raise")
-        # the scan that raised still booked its seconds
+        # the verdict that raised booked its seconds, and no fold followed
         assert metrics()["ingest.scan"]["count"] == 1
+        assert "fold.enqueue" not in metrics()
 
     def test_input_in_flight_is_declared_and_bounded(self, data):
         from spark_rapids_ml_tpu.telemetry import names
@@ -625,11 +632,12 @@ class TestStagingSet:
 
 
 class TestHostPassPool:
-    """A batch's host pass — the non-finite scan and the cast-copy into the
-    staging set — is cut by rows over a kept pool of threads when the batch
-    is large enough to cut, and runs inline through the same helpers when it
-    is not. Whatever the path: the same bytes in the set, the same answers
-    from ``raise`` and ``skip``, the same spans a batch."""
+    """A batch's host pass — the cast-copy into the staging set, and the
+    non-finite check of a chunk where the host is the one to make it — is cut
+    by rows over a kept pool of threads when there is enough to cut, and runs
+    inline through the same helpers when there is not. Whatever the path: the
+    same bytes in the set, the same answers from ``raise`` and ``skip``, the
+    same spans."""
 
     N = 6
     ROW_BYTES = N * 8
@@ -791,19 +799,18 @@ class TestHostPassPool:
     def test_a_workers_error_reaches_the_caller_and_the_set_goes_back(
         self, small_blocks, nonfinite
     ):
-        """An object column no cast can take: under ``raise`` the scan's
-        worker fails (TypeError), under ``allow`` the copy's (ValueError)."""
+        """An object column no cast can take: the copy's worker fails
+        (ValueError) whatever the policy, before anything is asked."""
         x = self.rows(400)
         self.fold([x])
         (kept,) = ingest._kept_staging
         broken = x.astype(object)
         broken[399, 0] = "not a number"
-        with pytest.raises(TypeError if nonfinite == "raise" else ValueError):
+        with pytest.raises(ValueError):
             self.fold([x[:64], broken], nonfinite=nonfinite)
         assert ingest._kept_staging == [kept] and not kept.placed
-        if nonfinite == "allow":
-            # every other block was written before the error came back
-            np.testing.assert_array_equal(kept.x[64 : 64 + 360], x[:360])
+        # every other block was written before the error came back
+        np.testing.assert_array_equal(kept.x[64 : 64 + 360], x[:360])
         # and the pool still serves
         res, moved = self.fold([x])
         assert self.paths(moved)["pool"] == 1
@@ -837,8 +844,8 @@ class TestHostPassPool:
 
     @pytest.mark.parametrize("batches", [1, 3, 7])
     def test_spans_a_batch_are_what_they_were(self, small_blocks, batches):
-        """One ``ingest.scan`` and one ``ingest.stage`` a batch, on the
-        caller's thread; the workers open none."""
+        """One ``ingest.stage`` a batch and one ``ingest.scan`` a chunk, on
+        the caller's thread; the workers (and the bounded wait's) open none."""
         import threading
 
         from spark_rapids_ml_tpu.telemetry import TIMELINE
@@ -849,7 +856,7 @@ class TestHostPassPool:
         res, moved = self.fold(np.array_split(x, batches))
         m = metrics()
         assert self.paths(moved)["pool"] >= batches
-        assert m["ingest.scan"]["count"] == batches
+        assert m["ingest.scan"]["count"] == res.chunks
         assert max(batches, res.chunks) <= m["ingest.stage"]["count"] <= batches + res.chunks
         spans = [e for e in TIMELINE.events(seq) if e["cat"] == "span"]
         assert {e["tid"] for e in spans} == {threading.get_native_id()}
@@ -909,3 +916,300 @@ class TestHostPassPool:
         res, moved = self.fold([x])
         assert self.paths(moved)["pool"] == 1 and ingest._pool[0] is not pool
         np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
+
+
+class TestChunkVerdict:
+    """Whether a chunk is finite is asked once a chunk, of the chunk that was
+    put and before its fold: on its device where ``put`` returned a
+    ``jax.Array`` (program ``jit__chunk_finite``), on the host of the staged
+    buffers where it handed back host arrays. ``raise``, ``skip`` and
+    ``allow`` give what the per-batch host scan gave."""
+
+    N = 5
+    CHUNK = 64
+    ROWS = 3 * CHUNK + 21  # three full chunks and a ragged fourth
+    # bad rows in the first chunk (two), a middle one and the ragged last
+    BAD = (3, 40, 2 * CHUNK + 7, 3 * CHUNK + 20)
+    PUTS = {"device": None, "identity": staticmethod(lambda a: a)}
+
+    @pytest.fixture(autouse=True)
+    def empty_holder(self):
+        ingest.release_staging()
+        yield
+        ingest.release_staging()
+
+    def rows(self, seed=11):
+        rng = np.random.default_rng(seed)
+        x = np.asarray(rng.normal(size=(self.ROWS, self.N)), np.float64)
+        y = x @ np.arange(1.0, self.N + 1) + 0.1 * rng.normal(size=self.ROWS)
+        w = rng.uniform(0.5, 2.0, size=self.ROWS)
+        return x, y, w
+
+    @staticmethod
+    def plant(x, y, w, fault, rows):
+        x, y, w = x.copy(), y.copy(), w.copy()
+        for i in rows:
+            if fault == "label":
+                y[i] = np.nan
+            elif fault == "weight":
+                w[i] = np.inf
+            else:
+                x[i, i % x.shape[1]] = {"nan": np.nan, "+inf": np.inf, "-inf": -np.inf}[fault]
+        return x, y, w
+
+    def fold(self, x, y, w, *, put="device", batches=3, fold_fn=None, **kw):
+        from spark_rapids_ml_tpu.ops import linear as LIN
+        from spark_rapids_ml_tpu.telemetry import REGISTRY, TIMELINE
+
+        reset_metrics()
+        seq = TIMELINE.seq()
+        before = REGISTRY.snapshot()
+        cuts = np.array_split(np.arange(len(x)), batches)
+        res = ingest.stream_fold(
+            iter([(x[c], y[c], w[c]) for c in cuts]),
+            fold_fn or LIN.linear_fold_step(),
+            n=self.N,
+            init=LIN.init_linear_carry(self.N, np.float64),
+            label_col="y",
+            weight_col="w",
+            chunk_rows=kw.pop("chunk_rows", self.CHUNK),
+            put_fn=self.PUTS[put],
+            **kw,
+        )
+        spans = [e for e in TIMELINE.events(seq) if e["cat"] == "span"]
+        return res, REGISTRY.snapshot().delta(before), spans
+
+    @staticmethod
+    def verdicts(moved):
+        return {
+            (where, clean): int(moved.counter("ingest.verdicts", where=where, clean=clean))
+            for where in ("device", "host") for clean in ("yes", "no")
+            if moved.counter("ingest.verdicts", where=where, clean=clean)
+        }
+
+    @staticmethod
+    def assert_stats_equal(got, want):
+        for name in want._fields:
+            np.testing.assert_allclose(
+                np.asarray(getattr(got, name)), np.asarray(getattr(want, name)),
+                rtol=1e-11, atol=1e-11, err_msg=name,
+            )
+
+    @pytest.mark.parametrize("fault", ["nan", "+inf", "-inf", "label", "weight"])
+    @pytest.mark.parametrize("nonfinite", ["raise", "skip", "allow"])
+    @pytest.mark.parametrize("put", ["device", "identity"])
+    def test_the_three_policies_wherever_the_chunk_is_asked(self, put, nonfinite, fault):
+        x, y, w = self.rows()
+        bx, by, bw = self.plant(x, y, w, fault, self.BAD)
+        where = "device" if put == "device" else "host"
+        if nonfinite == "raise":
+            # the first chunk holds two of the four bad rows: the chunk's count
+            with pytest.raises(ValueError) as err:
+                self.fold(bx, by, bw, put=put, nonfinite="raise")
+            assert str(err.value) == (
+                "2 non-finite input row(s) in a streamed chunk; set "
+                "TPU_ML_NONFINITE_POLICY=skip to drop and count them instead"
+            )
+            assert metrics()["ingest.scan"]["count"] == 1
+            assert "fold.enqueue" not in metrics()
+            return
+        res, moved, spans = self.fold(bx, by, bw, put=put, nonfinite=nonfinite)
+        assert res.chunks == 4
+        if nonfinite == "allow":
+            assert res.rows == self.ROWS and res.skipped_rows == 0
+            assert not self.verdicts(moved)
+            assert not [e for e in spans if e["name"] == "ingest.scan"]
+            assert moved.counter("rows.nonfinite_skipped") == 0
+            # and the bad rows reached the carry
+            assert not all(
+                np.isfinite(np.asarray(leaf)).all() for leaf in res.carry
+            )
+            return
+        keep = np.ones(self.ROWS, bool)
+        keep[list(self.BAD)] = False
+        assert res.skipped_rows == len(self.BAD)
+        assert res.rows == self.ROWS - len(self.BAD)
+        assert moved.counter("rows.nonfinite_skipped") == len(self.BAD)
+        # chunks 1, 3 and 4 said no and were asked again; chunk 2 was clean
+        assert self.verdicts(moved) == {(where, "yes"): 4, (where, "no"): 3}
+        want, _, _ = self.fold(x[keep], y[keep], w[keep], put=put, nonfinite="allow")
+        self.assert_stats_equal(res.carry, want.carry)
+        assert float(res.carry.count) == pytest.approx(w[keep].sum(), rel=1e-12)
+
+    @pytest.mark.parametrize("put", ["device", "identity"])
+    def test_a_chunk_left_with_no_true_row_is_not_folded(self, put):
+        x, y, w = self.rows()
+        middle = range(self.CHUNK, 2 * self.CHUNK)
+        bx, by, bw = self.plant(x, y, w, "nan", middle)
+        res, moved, spans = self.fold(bx, by, bw, put=put, nonfinite="skip")
+        assert res.chunks == 3 and res.skipped_rows == self.CHUNK
+        assert res.rows == self.ROWS - self.CHUNK
+        assert len([e for e in spans if e["name"] == "fold.enqueue"]) == 3
+        # asked once: there was nothing left to put again
+        assert sum(self.verdicts(moved).values()) == 4
+        keep = np.ones(self.ROWS, bool)
+        keep[list(middle)] = False
+        want, _, _ = self.fold(x[keep], y[keep], w[keep], put=put, nonfinite="allow")
+        self.assert_stats_equal(res.carry, want.carry)
+        # every row bad: nothing is folded at all
+        with pytest.raises(ValueError, match="empty dataset"):
+            self.fold(*self.plant(x, y, w, "nan", range(self.ROWS)), put=put,
+                      nonfinite="skip")
+
+    @pytest.mark.parametrize("put", ["device", "identity"])
+    @pytest.mark.parametrize("x64", [False, True])
+    def test_the_verdict_reads_the_dtype_the_device_holds(self, put, x64):
+        """THE ONE DIFFERENCE from the per-batch float64 scan: a finite
+        float64 beyond float32's range is ``inf`` once staged for a float32
+        device, and so a non-finite row; on a float64 wire it is not."""
+        x = self.rows()[0]
+        x[70, 2] = 1e300
+        dtype = np.float64 if x64 else np.float32
+
+        def fold(nonfinite):
+            from spark_rapids_ml_tpu.telemetry import REGISTRY
+
+            before = REGISTRY.snapshot()
+            res = ingest.stream_fold(
+                iter([x]), L.gram_fold_step(), n=self.N,
+                init=L.init_gram_carry(self.N, dtype), chunk_rows=self.CHUNK,
+                put_fn=self.PUTS[put], nonfinite=nonfinite,
+            )
+            return res, REGISTRY.snapshot().delta(before)
+
+        with jax.enable_x64(x64), np.errstate(over="ignore"):
+            if x64:
+                res, moved = fold("raise")
+                assert res.rows == self.ROWS and res.skipped_rows == 0
+                assert {c for _, c in self.verdicts(moved)} == {"yes"}
+                return
+            with pytest.raises(ValueError, match=r"^1 non-finite input row\(s\)"):
+                fold("raise")
+            res, moved = fold("skip")
+            assert res.rows == self.ROWS - 1 and res.skipped_rows == 1
+            kept = np.delete(x, 70, axis=0).astype(np.float32).astype(np.float64)
+            np.testing.assert_allclose(res.carry.xtx, kept.T @ kept, rtol=1e-4)
+            assert np.isfinite(np.asarray(res.carry.xtx)).all()
+
+    def test_the_verdict_is_read_before_its_chunk_is_enqueued(self):
+        """The answer is on the host before ``fold.enqueue`` opens, and no
+        wait (``fold.wait``, a transfer's) is a child of ``ingest.scan``: the
+        span is the program's dispatch and the read of its answer alone."""
+        from spark_rapids_ml_tpu.ops import linear as LIN
+        from spark_rapids_ml_tpu.telemetry import REGISTRY
+
+        x, y, w = self.rows()
+        asked_at_fold = []
+        step = LIN.linear_fold_step()
+
+        def fold_fn(carry, xd, yd, wd):
+            asked_at_fold.append(
+                REGISTRY.snapshot().counter("ingest.verdicts", where="device", clean="yes")
+            )
+            return step(carry, xd, yd, wd)
+
+        res, moved, spans = self.fold(x, y, w, fold_fn=fold_fn)  # resets the registry
+        assert asked_at_fold == [1, 2, 3, 4]
+        assert not [e for e in spans if e["args"].get("parent") == "ingest.scan"]
+        inside = [e for e in spans if e["args"].get("parent") == "fold.dispatch"]
+        assert [e["name"] for e in inside] == (
+            ["h2d.put", "fold.wait", "ingest.scan", "fold.enqueue"] * res.chunks
+        )
+        for put_, wait, scan, enqueue in zip(*[iter(inside)] * 4):
+            assert wait["ts"] + wait["dur"] <= scan["ts"]
+            assert scan["ts"] + scan["dur"] <= enqueue["ts"]
+        # the landings, and the terminal wait outside any dispatch
+        waits = [e for e in spans if e["name"] == "fold.wait"]
+        assert len(waits) == res.chunks + 1
+        assert waits[-1]["args"].get("parent") != "fold.dispatch"
+
+    def test_a_host_array_is_asked_on_the_host_and_waits_for_nothing(self):
+        x, y, w = self.rows()
+        res, moved, spans = self.fold(x, y, w, put="identity")
+        assert self.verdicts(moved) == {("host", "yes"): res.chunks}
+        assert len([e for e in spans if e["name"] == "fold.wait"]) == 1
+        assert len([e for e in spans if e["name"] == "ingest.scan"]) == res.chunks
+
+    def test_the_retry_of_a_transient_asks_again(self, monkeypatch):
+        from spark_rapids_ml_tpu.ops import linear as LIN
+        from spark_rapids_ml_tpu.resilience import faults
+        from spark_rapids_ml_tpu.resilience import retry as R
+
+        monkeypatch.setattr(R.time, "sleep", lambda s: None)
+        x, y, w = self.rows()
+        step = LIN.linear_fold_step()
+        calls = []
+
+        def flaky(carry, xd, yd, wd):
+            calls.append(1)
+            if len(calls) == 2:
+                raise faults.InjectedTransientIOError("the second fold's enqueue")
+            return step(carry, xd, yd, wd)
+
+        res, moved, _ = self.fold(x, y, w, fold_fn=flaky)
+        assert res.chunks == 4 and len(calls) == 5
+        assert self.verdicts(moved) == {("device", "yes"): 5}
+        want, _, _ = self.fold(x, y, w)
+        self.assert_stats_equal(res.carry, want.carry)
+
+    def test_the_counter_is_declared_and_the_program_keeps_its_name(self):
+        """benchmarks/layer_metrics/ingest.chunk_verdicts.json reads the
+        counter by its labels; the device trace shows the program under its
+        module name, as ``jit__fold``'s is pinned above."""
+        import json
+        from pathlib import Path
+
+        from spark_rapids_ml_tpu.telemetry import names
+
+        assert "ingest.verdicts" in names.METRICS
+        assert "ingest.verdicts" not in names.HISTOGRAMS | names.GAUGES
+        lowered = ingest._chunk_finite_prog().lower(
+            [jax.ShapeDtypeStruct((16, 8), np.float32),
+             jax.ShapeDtypeStruct((16,), np.float32),
+             jax.ShapeDtypeStruct((16,), np.float32)]
+        )
+        assert "module @jit__chunk_finite" in lowered.as_text()
+        spec = json.loads(
+            (
+                Path(__file__).resolve().parent.parent
+                / "benchmarks/layer_metrics/ingest.chunk_verdicts.json"
+            ).read_text()
+        )
+        assert spec["reader"] == {
+            "kind": "counter", "counter": "ingest.verdicts",
+            "labels": {"where": "device", "clean": "yes"},
+        }
+
+    def test_over_a_mesh_the_shards_answers_meet_in_one(self):
+        from spark_rapids_ml_tpu.parallel import gram as G
+        from spark_rapids_ml_tpu.parallel import mesh as M
+
+        mesh = M.create_mesh()
+        put = G.chunk_put(mesh)
+        ndev = len(jax.devices())
+        x = np.zeros((8 * ndev, 4))
+        w = np.ones(8 * ndev)
+        prog = ingest._chunk_finite_prog()
+        assert bool(prog([put(x), put(w)])) is True
+        for at in (0, 8 * ndev - 1):  # the first device's share, and the last's
+            bad = x.copy()
+            bad[at, 1] = np.inf
+            assert bool(prog([put(bad), put(w)])) is False
+        w[3] = np.nan
+        assert bool(prog([put(x), put(w)])) is False
+
+    def test_a_put_fn_that_changes_values_is_named(self):
+        def poisoning_put(a):
+            a = np.array(a)
+            if a.ndim == 2:
+                a[0, 0] = np.nan
+            return jax.device_put(a)
+
+        x, y, w = self.rows()
+        for nonfinite in ("raise", "skip"):
+            with pytest.raises(ValueError, match="put_fn may place a chunk"):
+                ingest.stream_fold(
+                    iter([x]), L.gram_fold_step(), n=self.N,
+                    init=L.init_gram_carry(self.N, np.float64),
+                    chunk_rows=self.CHUNK, put_fn=poisoning_put, nonfinite=nonfinite,
+                )
