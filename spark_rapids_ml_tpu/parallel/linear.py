@@ -310,7 +310,7 @@ def make_distributed_logreg_chunk(
         out_specs=(P(), P(), P()),
         check_vma=False,
     )
-    def run(x_aug, y, w_vec, w0, budget):
+    def _newton(x_aug, y, w_vec, w0, budget):
         limit = jnp.minimum(jnp.int32(chunk_iters), budget.astype(jnp.int32))
 
         def cond(carry):
@@ -332,8 +332,11 @@ def make_distributed_logreg_chunk(
         init = (w0, jnp.int32(0), jnp.asarray(jnp.inf, x_aug.dtype))
         return lax.while_loop(cond, body, init)
 
+    # a private function's name is the program's in a device trace
+    # (``jit__newton``): benchmarks/layer_metrics/newton_roofline.json reads
+    # it, tests/test_logreg_resident.py pins it
     return jax.jit(
-        run,
+        _newton,
         in_shardings=(
             NamedSharding(mesh, P(DATA_AXIS, None)),
             NamedSharding(mesh, P(DATA_AXIS)),

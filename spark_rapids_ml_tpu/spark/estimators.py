@@ -1059,6 +1059,7 @@ class SparkLogisticRegression(_HasDistribution, LogisticRegression):
         from spark_rapids_ml_tpu.ops import linear as LIN
         from spark_rapids_ml_tpu.parallel import linear as PL
         from spark_rapids_ml_tpu.spark import ingest
+        from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
 
         ing = ingest.stream_to_mesh(
             selected, features_col=feats, n=n,
@@ -1094,28 +1095,29 @@ class SparkLogisticRegression(_HasDistribution, LogisticRegression):
                     mesh, chunk_iters=checkpoint_every, tol=tol, **reg
                 )
             with trace_range("logreg mesh-local chunked fit"):
-                w, _ = PL.run_chunked_newton(
+                w, it = PL.run_chunked_newton(
                     chunk_fn, xs, ys, ws, w0,
                     start_iter=start_iter, max_iter=max_iter, tol=tol,
                     ckpt=ckpt,
                 )
             w_final = np.asarray(w)
+            done = it - start_iter
         else:
+            # the span covers the wait: the program returns at dispatch, and
+            # the copies to the host are where it is waited for
             with trace_range("logreg mesh-local fit"):
                 if n_classes > 2:
                     fit_fn = PL.make_distributed_softmax_fit(
                         mesh, n_classes, max_iter=max_iter, tol=tol, **reg
                     )
-                    w_flat, _, final_step = fit_fn(xs, ys, ws)
-                    LIN.check_newton_outcome(final_step, w_flat)
-                    w_final = np.asarray(w_flat)
                 else:
                     fit_fn = PL.make_distributed_logreg_fit(
                         mesh, max_iter=max_iter, tol=tol, **reg
                     )
-                    w_full, _, final_step = fit_fn(xs, ys, ws)
-                    LIN.check_newton_outcome(final_step, w_full)
-                    w_final = np.asarray(w_full)
+                w_dev, done, final_step = fit_fn(xs, ys, ws)
+                LIN.check_newton_outcome(final_step, w_dev)
+                w_final, done = np.asarray(w_dev), int(done)
+        REGISTRY.counter_inc("logreg.iterations", done, path="mesh-local")
         if n_classes > 2:
             return self._softmax_model(w_final, n_classes, fit_intercept)
         return self._binary_model(w_final, fit_intercept)

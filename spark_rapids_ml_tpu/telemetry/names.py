@@ -64,6 +64,9 @@ METRICS: frozenset[str] = frozenset({
     # Lloyd iterations a fit's program ran, by path (a loop that met its
     # tolerance or a fixed point runs fewer than maxIter)
     "kmeans.iterations",
+    # Newton iterations a logistic fit's program ran (binary or softmax), by
+    # path (a loop that met its tolerance runs fewer than maxIter)
+    "logreg.iterations",
     # spans: duration, and duration less what child spans covered
     "span.seconds",
     "span.self_seconds",
