@@ -16,7 +16,7 @@ same index built without ever materializing the corpus on device:
    carry with the centers riding the carry as a traced passthrough (one
    compiled program for every iteration). With more than one device the
    fold is mesh-sharded via ``parallel/gram``'s stacked-partials protocol:
-   chunks shard over the data axis (``chunk_put``), each device folds its
+   chunks shard over the data axis (``ChunkPut``), each device folds its
    shard collective-free, and one allreduce per iteration
    (``finalize_chunk_fold``) produces the replicated statistics. Between
    passes, empty cells reseed at farthest-point sample rows and overfull

@@ -302,18 +302,49 @@ def sharded_histogram(
 # realloc). One allreduce at finalize produces the replicated total.
 
 
-def chunk_put(mesh: Mesh):
-    """Chunk placement for mesh-sharded stream folds: [c, n] matrices shard
-    as P(data, None), [c] vectors as P(data). Pass as ``put_fn`` to
-    ``stream_fold`` (chunk_rows must divide by the data-axis size:
-    ``spark.ingest.stream_fold_over_mesh`` sees to it)."""
-    mat = NamedSharding(mesh, P(DATA_AXIS, None))
-    vec = NamedSharding(mesh, P(DATA_AXIS))
+class ChunkPut:
+    """Where a streamed fold's chunks go: sharded by rows over ``mesh``'s
+    data axis ([c, n] matrices as P(data, None), [c] vectors as P(data)), or
+    on the default device, as ``jax.device_put`` picks it, with no mesh.
 
-    def put(a):
-        return jax.device_put(a, mat if a.ndim == 2 else vec)
+    Called with a host buffer it puts the whole chunk (``stream_fold``'s
+    ``put_fn``; chunk_rows must divide by the data-axis size:
+    ``spark.ingest.stream_fold_over_mesh`` sees to it). :meth:`shares` and
+    :meth:`assemble` are for a stream that puts a chunk by pieces, each to
+    the device a whole put would have sent its rows to
+    (``spark.ingest._DeviceChunk``)."""
 
-    return put
+    def __init__(self, mesh: Mesh | None):
+        self.mesh = mesh
+
+    def sharding(self, ndim: int):
+        if self.mesh is None:
+            return None
+        return NamedSharding(self.mesh, P(DATA_AXIS, *[None] * (ndim - 1)))
+
+    def __call__(self, a):
+        return jax.device_put(a, self.sharding(a.ndim))
+
+    def shares(self, rows: int) -> list[tuple[int, int, jax.Device | None]]:
+        """``(lo, hi, device)`` for every addressable device: the rows
+        ``[lo:hi]`` of a chunk of ``rows`` rows that it holds, in their
+        order (the device None is the default one)."""
+        if self.mesh is None:
+            return [(0, rows, None)]
+        where = self.sharding(1).addressable_devices_indices_map((rows,))
+        return sorted(
+            ((*idx[0].indices(rows)[:2], d) for d, idx in where.items()),
+            key=lambda share: (share[0], share[2].id),
+        )
+
+    def assemble(self, shape, parts):
+        """The chunk's array of ``shape`` over the arrays that hold its
+        shares (in :meth:`shares`' order), with no copy."""
+        if self.mesh is None:
+            return parts[0]
+        return jax.make_array_from_single_device_arrays(
+            shape, self.sharding(len(shape)), parts
+        )
 
 
 def init_chunk_carry(example, mesh: Mesh):

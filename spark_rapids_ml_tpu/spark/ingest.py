@@ -22,7 +22,11 @@ This module replaces that with a streaming fill:
   hands the set to its consumer, which ``device_put``s it, the moment it
   fills: a device's whole shard on the resident path (``stream_to_mesh``),
   one fold chunk on the streamed one (``stream_fold``;
-  ``stream_fold_over_mesh`` owns its geometry over a mesh). The one set is
+  ``stream_fold_over_mesh`` owns its geometry over a mesh). The streamed
+  fold alone also asks to be told of the rows as they are written, and puts
+  the chunk by pieces into the one chunk its device holds while the rest is
+  staged (``_DeviceChunk``), so the transfer runs under the host's copy and
+  not after it. The one set is
   kept and rewritten under the buffer rule stated at ``_take_staging``,
   which finds out from the arrays whether a put aliased (``device_put`` of
   a host ndarray may alias rather than copy);
@@ -43,6 +47,7 @@ alternatives.
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
 import os
@@ -487,26 +492,31 @@ class StreamFold:
     resumed: bool = False
 
 
-def _bounded_wait(carry, timeout_s: float):
+def _bounded_wait(carry, timeout_s: float, *, site: str | None = "fold.wait"):
     """``jax.block_until_ready`` with a bound: a device that stopped
     answering (a hung collective, a dead runtime) surfaces as a diagnosable
     :class:`~spark_rapids_ml_tpu.resilience.retry.FoldHangTimeout` instead
     of blocking the driver forever. The waiter runs on a daemon thread; on
     timeout the stuck wait is abandoned with the thread (the process is
-    poisoned for further device work — see retry.ErrorClass.POISONED)."""
+    poisoned for further device work — see retry.ErrorClass.POISONED).
+    ``site`` is the fault site the wait stands for (None: none of its own)."""
     import jax
 
     from spark_rapids_ml_tpu.resilience import faults
     from spark_rapids_ml_tpu.resilience.retry import FoldHangTimeout
 
+    def inject():
+        if site is not None:
+            faults.inject(site)
+
     if not timeout_s or timeout_s <= 0:
-        faults.inject("fold.wait")
+        inject()
         return jax.block_until_ready(carry)
     box: dict[str, Any] = {}
 
     def _wait():
         try:
-            faults.inject("fold.wait")
+            inject()
             box["carry"] = jax.block_until_ready(carry)
         except BaseException as e:  # noqa: BLE001 — re-raised on the caller
             box["error"] = e
@@ -516,7 +526,8 @@ def _bounded_wait(carry, timeout_s: float):
     t.join(timeout_s)
     if t.is_alive():
         raise FoldHangTimeout(
-            f"fold.wait did not complete within {timeout_s:g}s: the device "
+            f"{site or 'a wait for the device'} did not complete within "
+            f"{timeout_s:g}s: the device "
             "fold is hung, not slow — most likely a collective that not "
             "every participant reached, or a device runtime that died (check "
             "device health). Raise "
@@ -909,14 +920,24 @@ class _Stager:
     because a consumer may change its shape in mid-stream (the fold's OOM
     bisection); a set of another key is never rewritten.
 
+    A consumer that asks for it (``on_rows``; the streamed fold alone does)
+    is also told of a set that is still filling: after every slice written,
+    ``on_rows(staged, fill)`` says that rows ``[:fill]`` of it are in place,
+    so that it can put them by pieces while the rest is staged
+    (:class:`_DeviceChunk` says what a piece is). A set that fills goes to
+    ``consume`` as ever, and whoever did not ask is handed full sets alone.
+
     As a context manager it borrows the set kept from the last ingest on
     entry and gives back the newest on every exit, errors included
     (``_borrow_staging``, ``_return_staging``). ``rows`` counts the rows
     staged so far."""
 
-    def __init__(self, key, consume, *, augment_intercept: bool = False):
+    def __init__(
+        self, key, consume, *, augment_intercept: bool = False, on_rows=None
+    ):
         self.key = key
         self.consume = consume
+        self.on_rows = on_rows
         self.augment_intercept = augment_intercept
         self.staged: _StagingSet | None = None  # the set being filled
         self.spare: _StagingSet | None = None  # the set last put from
@@ -960,6 +981,8 @@ class _Stager:
             at += take
             if self.fill == len(staged.x):
                 self.flush()
+            elif self.on_rows is not None:
+                self.on_rows(staged, self.fill)
 
     def flush(self) -> None:
         """Hand the set being filled to the consumer as it stands (a ragged
@@ -979,6 +1002,184 @@ class _Stager:
         finally:
             self.spare, self.staged = staged, None
             self.fill = 0
+
+
+# Pieces a device's share of a chunk is put in, and how many of them a device
+# may hold that have not landed yet. A piece's transfer runs while the next is
+# staged, and what a device holds beside its chunk is the pieces in flight:
+# the host stages faster than a link carries, and while the last chunk's fold
+# runs no landing can, so without a bound most of a second chunk piles up
+# there. 5 of 16 is the room the link fills during a fold of a 4 GiB chunk at
+# n=2048, and leaves the device's peak a fifth over one chunk's (PERF.md
+# section 6, PR 36, has the readings these two were chosen by; PR 35 read
+# pieces of 64 to 512 MiB within a tenth of one another). Properties of the
+# mechanism, not knobs.
+_PIECES = 16
+_PIECES_IN_FLIGHT = 5
+
+
+@functools.cache
+def _land_piece_prog():
+    """Write a piece's arrays into a share's at row ``at`` (module
+    ``jit__land_piece``). The share's arrays are donated and the row is
+    traced, so the update is in place and one executable a device and a
+    shape serves every piece. The second result is ready once the landing
+    has run: the piece's room on the device is free again."""
+    import jax
+    from jax import lax
+
+    def _land_piece(share, piece, at):
+        landed = [
+            lax.dynamic_update_slice_in_dim(s, p, at, axis=0)
+            for s, p in zip(share, piece)
+        ]
+        return landed, at + 1
+
+    return jax.jit(_land_piece, donate_argnums=0)
+
+
+@functools.cache
+def _new_share_prog(key: tuple, device):
+    """Zeroed arrays of ``key``'s shapes and dtypes made ON ``device`` (None:
+    the default one): a program with no argument whose results live there.
+    Not ``jnp.zeros(..., device=device)``: that fills its shard on the
+    default device and copies it over, so on a host of four chips the first
+    held three more shares of a chunk while a stream started (12.9-13.8 GB
+    where 6.3 were meant: PERF.md section 6, PR 36)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    def _new_share():
+        return [jnp.zeros(shape, dtype) for shape, dtype in key]
+
+    if device is None:
+        return jax.jit(_new_share)
+    return jax.jit(_new_share, out_shardings=SingleDeviceSharding(device))
+
+
+class _DeviceChunk:
+    """The one chunk-sized set of device arrays a streamed fold owns (``x``,
+    ``w`` and ``y`` where labels flow, each device's share of them where
+    ``place``, a ``parallel.gram.ChunkPut``, says a whole put would leave
+    it), and how much of the chunk being staged is in it already.
+
+    A PIECE is one of ``_PIECES`` of a device's share of the chunk: a run
+    of consecutive staged rows that go to one device. A piece that is
+    whole in the staging set is put at once (``h2d.put``, asynchronous) and
+    its landing enqueued: one program that writes it into the share's arrays
+    in place, the arrays donated, so the transfer runs while the rest of the
+    chunk is staged and a device never holds a second chunk, only the pieces
+    in flight. The device runs its queue in order, so a landing enqueued
+    after the last chunk's fold cannot overwrite rows that fold still reads.
+    Every chunk lands all its pieces, the zeroed rows past a ragged tail
+    among them, so nothing of the chunk before is left. Counter
+    ``h2d.pieces{path="stream"}`` books one a piece put.
+
+    Two things about when. A piece is landed one put late, just before the
+    next put: a program's dispatch queues behind the runtime's host work on
+    a transfer just issued (its layout change), which by the next put is
+    over. And a device holds at most ``_PIECES_IN_FLIGHT`` pieces that have
+    not landed: before a put that would be one more, the host waits for the
+    oldest landing (span ``h2d.wait`` inside ``h2d.put``, bounded by
+    ``wait``), which is the link setting the host's pace, not a stall: the
+    bytes in flight are what keeps the link busy meanwhile.
+
+    THE BUFFER RULE holds piece by piece through the chunk's: what the
+    fold's ``staged.placed`` keeps is :meth:`arrays`, which are ready once
+    every landing has run, and a landing runs only after its piece's
+    transfer."""
+
+    def __init__(self, place, wait):
+        self.place = place
+        self.wait = wait
+        self.shares: list[tuple] = []  # (lo, hi, device): who holds which rows
+        self.parts: list[list] | None = None  # each share's device arrays
+        self.done: list[int] = []  # rows of each share put, of this chunk
+        self.pending: tuple | None = None  # (share, piece, row): put, to land
+        self.flying: list[collections.deque] = []  # each share's landings
+
+    def drop(self) -> None:
+        """Let go of the device arrays (a bisection needs their room; the
+        stream has ended)."""
+        self.parts, self.done, self.pending, self.flying = None, [], None, []
+
+    def next_chunk(self) -> None:
+        """The pieces start over: of the next chunk, or of this one again."""
+        self.done = []
+
+    @staticmethod
+    def _buffers(staged: _StagingSet) -> list[np.ndarray]:
+        """The set's buffers in the order the fold takes its arrays."""
+        return [b for b in (staged.x, staged.w, staged.y) if b is not None]
+
+    def _land(self) -> None:
+        if self.pending is None:
+            return
+        (i, piece, at), self.pending = self.pending, None
+        try:
+            self.parts[i], landed = _land_piece_prog()(
+                self.parts[i], piece, np.int32(at)
+            )
+        except BaseException:
+            self.done = []  # its rows are not in the chunk: every piece again
+            raise
+        self.flying[i].append(landed)
+
+    def put(self, staged: _StagingSet, fill: int) -> None:
+        """Put every piece not yet put that is whole now that rows
+        ``[:fill]`` of ``staged`` are written."""
+        import jax
+
+        from spark_rapids_ml_tpu.telemetry import trace_range
+
+        bufs = self._buffers(staged)
+        # a landing that failed took the donated arrays with it
+        if self.parts is None or any(
+            a.is_deleted() for part in self.parts for a in part
+        ):
+            self.drop()
+            self.shares = self.place.shares(len(staged.x))
+            self.parts = [
+                _new_share_prog(
+                    tuple(((hi - lo,) + b.shape[1:], b.dtype) for b in bufs), d
+                )()
+                for lo, hi, d in self.shares
+            ]
+            self.flying = [collections.deque() for _ in self.shares]
+        if not self.done:
+            self.done = [0] * len(self.shares)
+        for i, (lo, hi, device) in enumerate(self.shares):
+            rows = -(-(hi - lo) // _PIECES)
+            while (at := self.done[i]) < hi - lo:
+                take = min(rows, hi - lo - at)
+                if lo + at + take > fill:
+                    break
+                with trace_range("h2d.put"):
+                    self._land()
+                    while len(self.flying[i]) >= _PIECES_IN_FLIGHT:
+                        oldest = self.flying[i].popleft()
+                        if not oldest.is_ready():
+                            with trace_range("h2d.wait"):
+                                self.wait(oldest)
+                    piece = [
+                        jax.device_put(b[lo + at : lo + at + take], device)
+                        for b in bufs
+                    ]
+                self.pending = (i, piece, at)
+                self.done[i] += take
+                REGISTRY.counter_inc("h2d.pieces", path="stream")
+
+    def arrays(self, staged: _StagingSet) -> list:
+        """The chunk's arrays, every piece of it put and its landing
+        enqueued: what a whole put of ``staged`` would have handed the
+        fold."""
+        self.put(staged, len(staged.x))
+        self._land()
+        return [
+            self.place.assemble(b.shape, [part[k] for part in self.parts])
+            for k, b in enumerate(self._buffers(staged))
+        ]
 
 
 def _batches(chunks: Iterator, n: int, features_col: str | None) -> Iterator:
@@ -1057,6 +1258,29 @@ def stream_fold(
     transfer had not landed when their fold was enqueued (under
     ``nonfinite="allow"`` alone: a chunk that is asked about has landed).
 
+    PIECES. A chunk whose placement is a ``parallel.gram.ChunkPut`` (the
+    default, and ``stream_fold_over_mesh``'s) is not put when its set is
+    full but while it fills: this function asks the stager for the rows as
+    they are written (``_Stager``'s ``on_rows``; the resident ingest does
+    not ask, and is handed full sets as ever) and puts each piece (one of
+    ``_PIECES`` of a device's share of the chunk) as soon as it is whole,
+    into the one chunk-sized set of device arrays the stream owns
+    (:class:`_DeviceChunk`: ``h2d.put`` a piece, most of them outside
+    ``fold.dispatch``, with ``h2d.wait`` inside where the device holds as
+    many pieces as it may; counter ``h2d.pieces``). The dispatch puts what
+    is left, the last piece or a
+    ragged tail's zeroed rows, and goes on as for a chunk put whole: one
+    wait for the landings, one verdict, ONE fold of the chunk's shape. What
+    a put by pieces changes is when the bytes move and nothing they are: the
+    device arrays are what a whole put would have handed the fold, to the
+    bit. No caller in the package passes another ``put_fn``: one that is
+    not a ``ChunkPut`` is handed the full buffers, one put a chunk, which is
+    the tests' reference for the pieces and their spies' way in; the halves
+    of a bisected chunk are put whole too. Of whole puts ``overlapped``
+    counts the dispatches that met the last fold still running; a stream by
+    pieces has waited for a landing queued behind that fold by then
+    (``h2d.wait``), and reads 0 there.
+
     The copy is the host's per-byte work on a batch, and a batch large enough
     to cut has it run by row blocks on the host pass's pool of threads
     (``_row_blocks``, ``_run_blocks``): this thread hands the blocks over and
@@ -1080,8 +1304,9 @@ def stream_fold(
     found there (``_bad_rows``): ``raise`` ends the fit with their count;
     ``skip`` zeroes them with weight 0 (the framework's mask, exact as pads
     are), moves them from ``rows`` to ``skipped_rows`` (the resume cursor,
-    their sum, does not move), puts the chunk again, asks again and folds
-    it, unless no row of weight is left. The OOM bisection asks of each
+    their sum, does not move), puts the chunk again (every piece of it, into
+    the same device arrays), asks again and folds it, unless no row of
+    weight is left. The OOM bisection asks of each
     piece it puts, and the retry of a transient asks again. A float64 beyond
     float32's range is ``inf`` on a float32 device, and so a non-finite row.
 
@@ -1096,7 +1321,7 @@ def stream_fold(
     rows, 0.0 on pads), so ragged tails and chunk sizes that don't divide
     the row count are exact with no count fix-up. ``init`` is the zero
     carry (or a callable returning it); ``put_fn`` overrides chunk
-    placement (e.g. parallel.gram.chunk_put shards chunks over a mesh).
+    placement (``parallel.gram.ChunkPut(mesh)`` shards chunks over a mesh).
 
     The fold self-heals (resilience/ package):
 
@@ -1125,6 +1350,7 @@ def stream_fold(
     """
     import jax
 
+    from spark_rapids_ml_tpu.parallel import gram as G
     from spark_rapids_ml_tpu.resilience import faults
     from spark_rapids_ml_tpu.resilience import retry as R
     from spark_rapids_ml_tpu.telemetry import current_fit_id, trace_range
@@ -1159,7 +1385,17 @@ def stream_fold(
     policy = R.RetryPolicy.from_config()
     transient_only = frozenset({R.ErrorClass.TRANSIENT})
     want_y = label_col is not None
-    put = put_fn if put_fn is not None else jax.device_put
+    put = put_fn if put_fn is not None else G.ChunkPut(None)
+    # a chunk that goes where a ChunkPut says is put by pieces while it is
+    # staged; any other put_fn is handed whole chunks, as ever
+    device_chunk = (
+        _DeviceChunk(
+            put, lambda a: _bounded_wait(a, fold_wait_timeout_s, site=None)
+        )
+        if isinstance(put, G.ChunkPut)
+        else None
+    )
+    put_ahead = True  # until a piece put ahead fails, then from the next chunk
 
     df_like = features_col is not None and any(
         callable(getattr(source, attr, None))
@@ -1320,10 +1556,16 @@ def stream_fold(
             faults.inject("fold.dispatch")
             # arrays put from the staging set are kept until it is reclaimed
             placed = staged.placed if xb is staged.x else []
+            # the set itself goes by pieces, and most of them have gone; a
+            # bisection's buffers, smaller than the chunk, are put whole
+            pieced = device_chunk is not None and xb is staged.x
 
             def put_chunk():
-                with trace_range("h2d.put"):
-                    arrays = [put(b) for b in bufs]
+                if pieced:
+                    arrays = device_chunk.arrays(staged)
+                else:
+                    with trace_range("h2d.put"):
+                        arrays = [put(b) for b in bufs]
                 placed.extend(arrays)
                 return arrays
 
@@ -1337,8 +1579,11 @@ def stream_fold(
                 if not wb.any():
                     return  # no true row left: nothing to fold
                 # put again, the stale arrays let go first: one chunk resident
+                # (by pieces: every piece again, into the same arrays)
                 del placed[-len(arrays):]
                 del arrays
+                if pieced:
+                    device_chunk.next_chunk()
                 arrays = put_chunk()
             xd, wd = arrays[:2]
             # the fold's device time holds a wait for its chunk's DMA when
@@ -1393,6 +1638,8 @@ def stream_fold(
                     "chunk.bisection", from_rows=cur, to_rows=new
                 )
                 bisections += 1
+                if device_chunk is not None:
+                    device_chunk.drop()  # of the shape that did not fit
                 queue[:0] = _split_chunk_buffers(bx, by, bw, new)
                 chunk_rows = min(chunk_rows, new)
 
@@ -1400,9 +1647,12 @@ def stream_fold(
         """The stager's consumer: a set is one fold chunk. It names nothing
         that holds the stager, so no cycle outlives the fold with a carry
         in it."""
-        nonlocal seen, last_ckpt
+        nonlocal seen, last_ckpt, put_ahead
         seen += fill
         dispatch_buffers(staged)
+        if device_chunk is not None:
+            device_chunk.next_chunk()
+            put_ahead = True
         REGISTRY.gauge_set("stream.last_beat", time.monotonic())
         if fill < len(staged.x):
             return  # the ragged tail: the stream ends here
@@ -1417,10 +1667,36 @@ def stream_fold(
             )
             last_ckpt = n_chunks
 
+    def put_pieces(staged, fill):
+        """The stager's word that rows ``[:fill]`` of a set still filling
+        are written: put the pieces that are whole. This is the dispatch's
+        work begun ahead of it, so a failure the dispatch would retry or
+        bisect (TRANSIENT, RESOURCE_EXHAUSTED) is left to it: nothing more
+        of this chunk is put ahead, and the dispatch puts whatever has not
+        landed (counter ``h2d.put_ahead_abandoned``, and a warning). Anything
+        else ends the stream here: a hung device (``FoldHangTimeout`` from
+        ``h2d.wait``) or a poisoned runtime does not get better by the next
+        slice."""
+        nonlocal put_ahead
+        if not put_ahead:
+            return
+        try:
+            device_chunk.put(staged, fill)
+        except Exception as e:  # noqa: BLE001 — classified here
+            if R.classify(e) not in R.RETRYABLE_DEFAULT:
+                raise
+            put_ahead = False
+            REGISTRY.counter_inc("h2d.put_ahead_abandoned", path="stream")
+            logger.warning(
+                "a piece put ahead of its chunk failed (%s: %s); the rest of "
+                "the chunk is put at its dispatch", type(e).__name__, e,
+            )
+
     # the key is asked for each new set: a bisection changes chunk_rows
     stager = _Stager(
         lambda: (chunk_rows, n_eff, stage_dt, want_y), fold_set,
         augment_intercept=augment_intercept,
+        on_rows=put_pieces if device_chunk is not None else None,
     )
     try:
         with stager:
@@ -1466,6 +1742,8 @@ def stream_fold(
         # inactive stream as OK regardless of beat age, so a dead stream
         # must not read as "wedged" forever
         REGISTRY.gauge_set("stream.active", 0)
+        if device_chunk is not None:
+            device_chunk.drop()
     # per-stream H2D↔compute overlap evidence: fraction of dispatches
     # issued while the prior fold was still on device. Recorded as a
     # histogram so end_fit's snapshot delta reads a per-fit mean into
@@ -1503,7 +1781,7 @@ def stream_fold_over_mesh(
     """:func:`stream_fold` over a device mesh, with the geometry of the
     stacked-partials protocol (``parallel.gram``) owned here and nowhere
     else: chunks of :func:`stream_chunk_rows_for_mesh` rows sharded over the
-    data axis (``chunk_put``), a zero carry of ``example``'s statistics
+    data axis (``ChunkPut``), a zero carry of ``example``'s statistics
     stacked one slice a device (``init_chunk_carry``; ``example`` is the
     pytree of the UNSTACKED statistics, arrays or ShapeDtypeStructs), an OOM
     bisection that stops at the data-axis size and keeps to its multiples,
@@ -1527,7 +1805,7 @@ def stream_fold_over_mesh(
         weight_col=weight_col,
         rows=rows,
         chunk_rows=stream_chunk_rows_for_mesh(mesh),
-        put_fn=G.chunk_put(mesh),
+        put_fn=G.ChunkPut(mesh),
         checkpointer=checkpointer,
         checkpoint_every=checkpoint_every,
         min_chunk_rows=mesh.shape[DATA_AXIS],

@@ -26,6 +26,12 @@ METRICS: frozenset[str] = frozenset({
     # addressable shards the streamed fold put, once a chunk (path="stream"):
     # the data axis of the mesh, so chunks x devices over a fit
     "h2d.shards",
+    # pieces of its chunks the streamed fold put, most of them while the
+    # chunk was still staged (path="stream"); the resident ingest puts none
+    "h2d.pieces",
+    # chunks whose pieces stopped being put ahead after a put or a landing
+    # failed in a way the dispatch retries or bisects (path="stream")
+    "h2d.put_ahead_abandoned",
     # rows the resident ingest padded its shards with (padded_rows - rows,
     # once an ingest): zero rows of weight 0 that every pass walks
     "mesh.pad_rows",
@@ -252,6 +258,7 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "fold.wait",
     "fold.finalize",
     "h2d.put",
+    "h2d.wait",
     "ingest.chunk",
     "ingest.scan",
     "ingest.stage",

@@ -241,7 +241,7 @@ class TestCollectiveRetry:
             n=12,
             init=G.init_chunk_carry(example, mesh),
             chunk_rows=ingest.stream_chunk_rows_for_mesh(mesh),
-            put_fn=G.chunk_put(mesh),
+            put_fn=G.ChunkPut(mesh),
         )
         monkeypatch.setenv(faults.FAULT_PLAN_VAR, "collective:io:1")
         monkeypatch.setattr(R.time, "sleep", lambda s: None)

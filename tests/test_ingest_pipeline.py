@@ -406,6 +406,8 @@ COUNTERS = (
         ("h2d.bytes", {"path": "stream"}),
         ("h2d.bytes", {"path": "mesh"}),
         ("h2d.shards", {"path": "stream"}),
+        ("h2d.pieces", {"path": "stream"}),
+        ("h2d.pieces", {"path": "mesh"}),
         ("kmeans.iterations", {"path": "mesh-local"}),
         ("ingest.rows", {}),
         ("ingest.bytes", {}),
@@ -429,7 +431,12 @@ CENSUS = {
             ("ingest.stage", "compute cov"): 8,
             ("stage.reclaim", "compute cov"): 4,
             ("fold.dispatch", "compute cov"): 4,
-            ("h2d.put", "fold.dispatch"): 4,
+            # PR 36: a chunk goes by pieces, 16 a device's share of 128 rows:
+            # 4 chunks x 4 devices x 16. A batch of 550 rows fills the first
+            # chunk in one slice, so all of that chunk's go at its dispatch;
+            # the pieces that a later slice leaves whole go at once, outside
+            ("h2d.put", "fold.dispatch"): 194,
+            ("h2d.put", "compute cov"): 62,
             ("fold.wait", "fold.dispatch"): 4,
             ("ingest.scan", "fold.dispatch"): 4,
             ("fold.enqueue", "fold.dispatch"): 4,
@@ -441,6 +448,7 @@ CENSUS = {
             "ingest.batches{path=inline}": 7,
             "h2d.bytes{path=stream}": 147456,
             "h2d.shards{path=stream}": 16,
+            "h2d.pieces{path=stream}": 256,
             "ingest.verdicts{where=device,clean=yes}": 4,
             "ingest.rows": 1650,
             "ingest.bytes": 105600,

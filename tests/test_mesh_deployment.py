@@ -125,31 +125,33 @@ def test_each_device_holds_a_quarter_of_every_put_and_a_slice_of_the_carry(
     table, _ = blocks
     on_devices(monkeypatch, 4)
     puts, carries = [], []
-    chunk_put, finalize = G.chunk_put, G.finalize_chunk_fold
+    finalize = G.finalize_chunk_fold
 
-    def spy_chunk_put(mesh):
-        put = chunk_put(mesh)
+    class SpyChunkPut(G.ChunkPut):
+        """What the fold is handed: a chunk's arrays over the shares its
+        pieces were landed in (looked at now: the next chunk's landings are
+        donated them)."""
 
-        def spy(a):
-            puts.append(put(a))
-            return puts[-1]
-
-        return spy
+        def assemble(self, shape, parts):
+            a = super().assemble(shape, parts)
+            puts.append(
+                (a.shape, [(s.data.shape, s.device) for s in a.addressable_shards])
+            )
+            return a
 
     def spy_finalize(carry, mesh):
         carries.append(carry)
         return finalize(carry, mesh)
 
-    monkeypatch.setattr(G, "chunk_put", spy_chunk_put)
+    monkeypatch.setattr(G, "ChunkPut", SpyChunkPut)
     monkeypatch.setattr(G, "finalize_chunk_fold", spy_finalize)
     fit(session, table, ROWS["ragged_tail"])
 
-    chunks = [a for a in puts if a.ndim == 2]
+    chunks = [shape for shape, _ in puts if len(shape) == 2]
     assert len(chunks) == 4 and len(puts) == 8  # a chunk and its weights, four times
-    for a in puts:
-        shards = a.addressable_shards
-        assert [s.data.shape for s in shards] == [(CHUNK // 4, *a.shape[1:])] * 4
-        assert {s.device for s in shards} == set(jax.devices()[:4])
+    for shape, shards in puts:
+        assert [s for s, _ in shards] == [(CHUNK // 4, *shape[1:])] * 4
+        assert {d for _, d in shards} == set(jax.devices()[:4])
     (carry,) = carries
     assert carry.xtx.shape == (4, N, N) and carry.col_sum.shape == (4, N)
     for leaf in jax.tree_util.tree_leaves(carry):

@@ -15,6 +15,7 @@ Three claims, each load-bearing for the out-of-core path:
    trace spans.
 """
 
+import itertools
 from functools import partial
 
 import jax
@@ -113,7 +114,7 @@ class TestStreamedParity:
             n=12,
             init=G.init_chunk_carry(example, mesh),
             chunk_rows=chunk,
-            put_fn=G.chunk_put(mesh),
+            put_fn=G.ChunkPut(mesh),
         )
         stats = G.finalize_chunk_fold(res.carry, mesh)
         want = L.gram_stats(jnp.asarray(x))
@@ -160,19 +161,25 @@ class TestStreamedMemory:
 
 
 class TestStreamedOverlap:
-    def test_dispatch_overlaps_previous_fold(self):
-        """Double-buffering observable: with a fold heavy enough to still
-        be executing when the host finishes staging the next chunk, at
-        least one dispatch must find the carry not-ready."""
-        rng = np.random.default_rng(5)
-        x = np.asarray(rng.normal(size=(2048, 128)), np.float64)
-
+    @staticmethod
+    def heavy_fold(iterations):
         @partial(jax.jit, donate_argnums=0)
-        def heavy_fold(carry, xc, wc):
+        def fold(carry, xc, wc):
             def body(_, c):
                 return L.fold_gram_stats(c, xc, wc)
 
-            return jax.lax.fori_loop(0, 50, body, carry)
+            return jax.lax.fori_loop(0, iterations, body, carry)
+
+        return fold
+
+    def test_dispatch_overlaps_previous_fold(self):
+        """Double-buffering observable: with a fold heavy enough to still
+        be executing when the host finishes staging the next chunk, at
+        least one dispatch must find the carry not-ready. Of chunks put
+        whole: a chunk put by pieces has its own observable, below."""
+        rng = np.random.default_rng(5)
+        x = np.asarray(rng.normal(size=(2048, 128)), np.float64)
+        heavy_fold = self.heavy_fold(50)
 
         # the busy window is scheduler-dependent (CPU async dispatch may
         # finish a fold within the dispatch call itself), so sample a few
@@ -184,6 +191,7 @@ class TestStreamedOverlap:
                 n=128,
                 init=L.init_gram_carry(128, x.dtype),
                 chunk_rows=512,
+                put_fn=jax.device_put,
             )
             assert res.chunks == 4
             if res.overlapped >= 1:
@@ -193,6 +201,43 @@ class TestStreamedOverlap:
                 "no fold dispatch observed the previous fold still executing "
                 "in any of 8 streams — the pipeline is serialized"
             )
+
+    def test_pieces_are_put_while_the_previous_fold_executes(self, monkeypatch):
+        """A chunk put by pieces: the next chunk's first pieces are put, and
+        their landings enqueued, while the last chunk's fold still runs (the
+        host waits for neither); past ``_PIECES_IN_FLIGHT`` of them it waits
+        for the oldest landing, which the fold is ahead of: ``overlapped``
+        is not this path's observable."""
+        rng = np.random.default_rng(5)
+        x = np.asarray(rng.normal(size=(2048, 128)), np.float64)
+        heavy, prog = self.heavy_fold(400), ingest._land_piece_prog()
+        carries, under_a_fold = [], []
+
+        def fold(carry, xc, wc):
+            carries.append(heavy(carry, xc, wc))
+            return carries[-1]
+
+        def spy_land(share, piece, at):
+            last = carries[-1].xtx if carries else None
+            under_a_fold.append(
+                last is not None and not last.is_deleted() and not last.is_ready()
+            )
+            return prog(share, piece, at)
+
+        monkeypatch.setattr(ingest, "_land_piece_prog", lambda: spy_land)
+        for _ in range(8):
+            res = ingest.stream_fold(
+                iter(np.array_split(x, 32)),
+                fold,
+                n=128,
+                init=L.init_gram_carry(128, x.dtype),
+                chunk_rows=512,
+            )
+            assert res.chunks == 4
+            if any(under_a_fold):
+                break
+        else:
+            pytest.fail("no piece was landed while a fold was still executing")
 
     def test_phase_spans_recorded(self, data):
         x, _, _ = data
@@ -264,25 +309,41 @@ class TestStreamedSpans:
         assert m["ingest.stage"]["count"] >= max(batches, res.chunks)
         assert m["ingest.stage"]["count"] <= batches + res.chunks
 
+    @pytest.mark.parametrize("pieces", [False, True])
     @pytest.mark.parametrize("nonfinite", ["raise", "allow"])
-    def test_put_and_enqueue_once_a_chunk_inside_dispatch(self, data, nonfinite):
+    def test_put_and_enqueue_once_a_chunk_inside_dispatch(
+        self, data, nonfinite, pieces
+    ):
+        """A chunk put whole (any ``put_fn`` of the caller's own) has one
+        ``h2d.put``, inside ``fold.dispatch``; a chunk put by pieces has
+        one a piece, and whatever of them the dispatch is left with (the
+        last piece at least, here the chunk's last slice staged) inside."""
         x, _, _ = data
-        res, m, spans, snap = self.fold(x, 3, nonfinite=nonfinite)
+        kw = {} if pieces else {"put_fn": lambda a: jax.device_put(np.array(a))}
+        res, m, spans, snap = self.fold(x, 3, nonfinite=nonfinite, **kw)
         assert res.chunks == 3
-        for phase in ("h2d.put", "fold.enqueue", "fold.dispatch"):
+        puts = [e for e in spans if e["name"] == "h2d.put"]
+        inside = [e for e in puts if e["args"].get("parent") == "fold.dispatch"]
+        for phase in ("fold.enqueue", "fold.dispatch"):
             assert m[phase]["count"] == res.chunks, phase
+        if pieces:
+            assert len(puts) == res.chunks * ingest._PIECES
+            assert res.chunks <= len(inside) < len(puts)
+        else:
+            assert len(puts) == len(inside) == res.chunks
         for e in spans:
-            if e["name"] in ("h2d.put", "fold.enqueue", "ingest.scan"):
+            if e["name"] in ("fold.enqueue", "ingest.scan"):
                 assert e["args"]["parent"] == "fold.dispatch"
         if nonfinite != "allow":
             return
         # nothing asked: fold.dispatch's own seconds are what neither covers
+        # (the timeline keeps a span's microseconds whole)
         own = snap.hist("span.self_seconds", phase="fold.dispatch").total
         assert own == pytest.approx(
             m["fold.dispatch"]["seconds"]
-            - m["h2d.put"]["seconds"]
+            - sum(e["dur"] for e in inside) / 1e6
             - m["fold.enqueue"]["seconds"],
-            abs=1e-9,
+            abs=1e-6 * (len(inside) + 1),
         )
 
     def test_stage_never_covers_a_dispatch(self, data):
@@ -308,7 +369,8 @@ class TestStreamedSpans:
         assert res.skipped_rows == 1 and res.rows == len(x) - 1
         # the first chunk is asked, masked, put again and asked again
         assert m["ingest.scan"]["count"] == res.chunks + 1 == 4
-        assert m["h2d.put"]["count"] == res.chunks + 1
+        # every piece of that chunk again, into the same device arrays
+        assert m["h2d.put"]["count"] == (res.chunks + 1) * ingest._PIECES
         with pytest.raises(ValueError, match="non-finite"):
             self.fold(bad, 3, nonfinite="raise")
         # the verdict that raised booked its seconds, and no fold followed
@@ -1112,10 +1174,19 @@ class TestChunkVerdict:
         assert asked_at_fold == [1, 2, 3, 4]
         assert not [e for e in spans if e["args"].get("parent") == "ingest.scan"]
         inside = [e for e in spans if e["args"].get("parent") == "fold.dispatch"]
-        assert [e["name"] for e in inside] == (
-            ["h2d.put", "fold.wait", "ingest.scan", "fold.enqueue"] * res.chunks
-        )
-        for put_, wait, scan, enqueue in zip(*[iter(inside)] * 4):
+        # a chunk of CHUNK rows filled by batches of its rows, so the
+        # dispatch is left with every piece of the last batch staged: the
+        # puts, then the landing waited for, the verdict and the fold
+        names = [e["name"] for e in inside]
+        per_chunk = [
+            list(g) for k, g in itertools.groupby(names, lambda n: n == "h2d.put")
+            if not k
+        ]
+        assert per_chunk == [["fold.wait", "ingest.scan", "fold.enqueue"]] * res.chunks
+        assert names[-3:] == ["fold.wait", "ingest.scan", "fold.enqueue"]
+        assert res.chunks <= names.count("h2d.put") < res.chunks * ingest._PIECES
+        others = [e for e in inside if e["name"] != "h2d.put"]
+        for wait, scan, enqueue in zip(*[iter(others)] * 3):
             assert wait["ts"] + wait["dur"] <= scan["ts"]
             assert scan["ts"] + scan["dur"] <= enqueue["ts"]
         # the landings, and the terminal wait outside any dispatch
@@ -1185,7 +1256,7 @@ class TestChunkVerdict:
         from spark_rapids_ml_tpu.parallel import mesh as M
 
         mesh = M.create_mesh()
-        put = G.chunk_put(mesh)
+        put = G.ChunkPut(mesh)
         ndev = len(jax.devices())
         x = np.zeros((8 * ndev, 4))
         w = np.ones(8 * ndev)
@@ -1213,3 +1284,410 @@ class TestChunkVerdict:
                     init=L.init_gram_carry(self.N, np.float64),
                     chunk_rows=self.CHUNK, put_fn=poisoning_put, nonfinite=nonfinite,
                 )
+
+
+class TestPiecedPut:
+    """A chunk that goes where a ``ChunkPut`` says is put by pieces while it
+    is staged, into the one chunk-sized set of device arrays the stream owns
+    (``ingest._DeviceChunk``); any other ``put_fn`` is handed whole chunks,
+    the parent's way, which is what the pieced stream is held against here:
+    the same carry to the bit, the same answers from ``raise`` / ``skip`` /
+    ``allow``, one device chunk, THE BUFFER RULE piece by piece, the counter,
+    and the retries and the bisection ending in the exact Gram."""
+
+    N = 7
+    CHUNK = 128  # a piece is 8 rows on one device and 2 on each of four
+
+    @pytest.fixture(autouse=True)
+    def empty_holder(self):
+        ingest.release_staging()
+        yield
+        ingest.release_staging()
+
+    def rows(self, n_rows, seed=23):
+        rng = np.random.default_rng(seed)
+        return np.asarray(rng.normal(size=(n_rows, self.N)), np.float64)
+
+    def fold(self, x, *, ndev=None, pieces=True, fold_fn=None, **kw):
+        """One stream over ``x``: on the default device (``ndev`` None) or
+        sharded over a mesh of ``ndev``; by pieces, or whole (the same
+        placement behind a plain function)."""
+        from spark_rapids_ml_tpu.parallel import gram as G
+        from spark_rapids_ml_tpu.parallel import mesh as M
+        from spark_rapids_ml_tpu.telemetry import REGISTRY
+
+        if ndev is None:
+            place = G.ChunkPut(None)
+            step = fold_fn or L.gram_fold_step()
+            init = L.init_gram_carry(self.N, np.float64)
+        else:
+            mesh = M.create_mesh(devices=jax.devices()[:ndev])
+            place = G.ChunkPut(mesh)
+            step = fold_fn or (lambda c, xd, wd: G.sharded_gram_fold(c, xd, wd, mesh))
+            init = G.init_chunk_carry(
+                L.GramStats(
+                    xtx=jax.ShapeDtypeStruct((self.N, self.N), np.float64),
+                    col_sum=jax.ShapeDtypeStruct((self.N,), np.float64),
+                    count=jax.ShapeDtypeStruct((), np.float64),
+                ),
+                mesh,
+            )
+            kw.setdefault("min_chunk_rows", ndev)
+        before = REGISTRY.snapshot()
+        res = ingest.stream_fold(
+            iter(np.array_split(x, 7)),
+            step,
+            n=self.N,
+            init=init,
+            chunk_rows=kw.pop("chunk_rows", self.CHUNK),
+            put_fn=place if pieces else (lambda a: place(a)),
+            **kw,
+        )
+        return res, REGISTRY.snapshot().delta(before)
+
+    @staticmethod
+    def assert_same_carry(got, want):
+        for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("ndev", [None, 1, 4])
+    @pytest.mark.parametrize(
+        "n_rows", [CHUNK, 3 * CHUNK, 2 * CHUNK + 23], ids=["one", "several", "ragged"]
+    )
+    def test_the_carry_is_the_whole_puts_to_the_bit(self, n_rows, ndev):
+        x = self.rows(n_rows)
+        whole, moved = self.fold(x, ndev=ndev, pieces=False)
+        assert moved.counter("h2d.pieces", path="stream") == 0
+        pieced, moved = self.fold(x, ndev=ndev)
+        assert pieced.chunks == whole.chunks == -(-n_rows // self.CHUNK)
+        assert moved.counter("h2d.pieces", path="stream") == (
+            pieced.chunks * ingest._PIECES * (ndev or 1)
+        )
+        self.assert_same_carry(pieced.carry, whole.carry)
+        # over a mesh the carry is a slice a device
+        total = jax.tree.map(
+            lambda a: np.asarray(a).sum(0) if ndev else np.asarray(a), pieced.carry
+        )
+        np.testing.assert_allclose(total.xtx, x.T @ x, rtol=1e-12)
+        assert float(total.count) == n_rows
+        assert pieced.max_put_bytes == whole.max_put_bytes
+        assert moved.counter("h2d.shards", path="stream") == pieced.chunks * (ndev or 1)
+
+    # a value in the second chunk's seventh piece, and one in the ragged tail
+    BAD = {"middle": CHUNK + 6 * (CHUNK // 16) + 5, "tail": 2 * CHUNK + 20}
+
+    @pytest.mark.parametrize("ndev", [None, 4])
+    @pytest.mark.parametrize("where", list(BAD))
+    @pytest.mark.parametrize("nonfinite", ["raise", "skip", "allow"])
+    def test_a_nonfinite_value_gets_the_whole_puts_answer(self, nonfinite, where, ndev):
+        x = self.rows(2 * self.CHUNK + 23)
+        x[self.BAD[where], 2] = np.inf
+        if nonfinite == "raise":
+            errors = []
+            for pieces in (False, True):
+                with pytest.raises(ValueError, match=r"^1 non-finite input row") as e:
+                    self.fold(x, ndev=ndev, pieces=pieces, nonfinite=nonfinite)
+                errors.append(str(e.value))
+            assert errors[0] == errors[1]
+            return
+        whole, _ = self.fold(x, ndev=ndev, pieces=False, nonfinite=nonfinite)
+        pieced, moved = self.fold(x, ndev=ndev, nonfinite=nonfinite)
+        self.assert_same_carry(pieced.carry, whole.carry)
+        assert (pieced.rows, pieced.skipped_rows) == (whole.rows, whole.skipped_rows)
+        skipped = int(nonfinite == "skip")
+        assert pieced.skipped_rows == skipped and pieced.rows == len(x) - skipped
+        assert np.isfinite(np.asarray(pieced.carry.xtx)).all() == (nonfinite == "skip")
+        # the masked chunk goes again, every piece of it, into the same arrays
+        assert moved.counter("h2d.pieces", path="stream") == (
+            (pieced.chunks + skipped) * ingest._PIECES * (ndev or 1)
+        )
+
+    def test_the_stream_never_holds_two_chunks_on_its_device(self, monkeypatch):
+        """After every chunk's fold is enqueued, and while the next is
+        staged, one array of the chunk's shape is alive, whatever a fold
+        still reads: the landings write into it in place."""
+        chunk = 112  # a shape no other test leaves behind
+        shape = (chunk, self.N)
+        piece = (chunk // ingest._PIECES, self.N)
+
+        def alive(of):
+            # by buffer: an array's ``addressable_shards`` are arrays too
+            return len({
+                a.unsafe_buffer_pointer() for a in jax.live_arrays() if a.shape == of
+            })
+
+        assert alive(shape) == 0
+        seen, pieces_alive = [], []
+        flush, write = ingest._Stager.flush, ingest._StagingSet.write
+
+        def spy_flush(stager):
+            flush(stager)
+            seen.append(alive(shape))
+
+        def spy_write(staged, fill, *a, **kw):
+            write(staged, fill, *a, **kw)
+            seen.append(alive(shape))
+            pieces_alive.append(alive(piece))
+
+        monkeypatch.setattr(ingest._Stager, "flush", spy_flush)
+        monkeypatch.setattr(ingest._StagingSet, "write", spy_write)
+        res, _ = self.fold(
+            self.rows(4 * chunk + 5), nonfinite="allow", chunk_rows=chunk
+        )
+        assert res.chunks == 5 and len(seen) > 2 * res.chunks
+        assert set(seen[1:]) == {1} and seen[0] <= 1
+        # and never more pieces beside it than may be in flight
+        assert max(pieces_alive) <= ingest._PIECES_IN_FLIGHT
+        del res
+        assert alive(shape) == 0  # the stream's end lets go of it
+
+    def test_a_device_holds_no_more_pieces_in_flight_than_the_bound(self, monkeypatch):
+        """Landings that never end by themselves: before a put that would
+        make one piece more than ``_PIECES_IN_FLIGHT`` not landed, the host
+        waits for the oldest (span ``h2d.wait`` inside ``h2d.put``)."""
+        from spark_rapids_ml_tpu.telemetry import TIMELINE
+
+        class Landing:
+            waited = False
+
+            def is_ready(self):
+                return self.waited
+
+        prog, wait = ingest._land_piece_prog(), ingest._bounded_wait
+        landings, in_flight = [], []
+
+        def spy_land(share, piece, at):
+            landings.append(Landing())
+            in_flight.append(sum(not t.waited for t in landings))
+            return prog(share, piece, at)[0], landings[-1]
+
+        def spy_wait(a, timeout_s, **kw):
+            if not isinstance(a, Landing):
+                return wait(a, timeout_s, **kw)
+            assert a is next(t for t in landings if not t.waited)  # the oldest
+            a.waited = True
+
+        monkeypatch.setattr(ingest, "_land_piece_prog", lambda: spy_land)
+        monkeypatch.setattr(ingest, "_bounded_wait", spy_wait)
+        seq = TIMELINE.seq()
+        res, _ = self.fold(self.rows(2 * self.CHUNK))
+        assert len(landings) == res.chunks * ingest._PIECES
+        assert max(in_flight) == ingest._PIECES_IN_FLIGHT
+        waits = [e for e in TIMELINE.events(seq) if e["name"] == "h2d.wait"]
+        assert len(waits) == sum(t.waited for t in landings) > 0
+        assert {e["args"]["parent"] for e in waits} == {"h2d.put"}
+
+    def test_a_piece_is_rewritten_only_after_its_array_is_ready(self, monkeypatch):
+        """THE BUFFER RULE by piece: no row of the staging set is written
+        while an array put from it may still be read by its transfer; and
+        what a set is reclaimed on is the device chunk, which is ready only
+        once every piece has landed."""
+        pieces = []  # (first row, rows, the array put from them)
+        prog = ingest._land_piece_prog()
+        write, reclaim = ingest._StagingSet.write, ingest._StagingSet.reclaim
+        writes, reclaimed_on = [], []
+
+        def spy_land(share, piece, at):
+            pieces.append((int(at), len(piece[0]), piece[0]))
+            return prog(share, piece, at)
+
+        def spy_write(staged, fill, xc, *a, **kw):
+            for at, rows, array in pieces:
+                if at < fill + len(xc) and fill < at + rows:
+                    assert array.is_ready()
+            writes.append((fill, len(xc)))
+            write(staged, fill, xc, *a, **kw)
+
+        def spy_reclaim(staged, wait=True):
+            reclaimed_on.append([(a.shape, a.is_deleted()) for a in staged.placed])
+            return reclaim(staged, wait)
+
+        monkeypatch.setattr(ingest, "_land_piece_prog", lambda: spy_land)
+        monkeypatch.setattr(ingest._StagingSet, "write", spy_write)
+        monkeypatch.setattr(ingest._StagingSet, "reclaim", spy_reclaim)
+        res, moved = self.fold(self.rows(3 * self.CHUNK), nonfinite="allow")
+        assert len(pieces) == res.chunks * ingest._PIECES
+        assert len(writes) >= 7
+        # each later chunk, and the holder at the end, asked the device chunk
+        assert reclaimed_on == [[((self.CHUNK, self.N), False), ((self.CHUNK,), False)]] * 3
+        states = TestStagingSet.states(moved)
+        assert states["fresh"] == 1 and states["reused"] + states["aliased"] == 2
+
+    def test_the_resident_ingest_puts_no_piece(self):
+        import pyarrow as pa
+
+        from spark_rapids_ml_tpu.parallel import mesh as M
+        from spark_rapids_ml_tpu.telemetry import REGISTRY, names
+
+        assert "h2d.pieces" in names.METRICS
+        assert "h2d.pieces" not in names.HISTOGRAMS | names.GAUGES
+        x = self.rows(500)
+
+        class Frame:
+            def count(self):
+                return len(x)
+
+            def _parts(self):
+                for part in np.array_split(x, 4):
+                    flat = pa.array(part.reshape(-1))
+                    offsets = pa.array(
+                        np.arange(0, part.size + 1, part.shape[1], dtype=np.int32)
+                    )
+                    yield [pa.RecordBatch.from_arrays(
+                        [pa.ListArray.from_arrays(offsets, flat)], names=["f"]
+                    )]
+
+        before = REGISTRY.snapshot()
+        ing = ingest.stream_to_mesh(
+            Frame(), features_col="f", n=self.N, mesh=M.create_mesh(data=4)
+        )
+        moved = REGISTRY.snapshot().delta(before)
+        np.testing.assert_array_equal(np.asarray(ing.xs)[:500], x)
+        assert moved.counter("h2d.pieces") == 0
+        assert moved.counter("h2d.bytes", path="mesh") > 0
+
+    @pytest.mark.parametrize("ndev", [None, 4])
+    @pytest.mark.parametrize(
+        "plan, retries, bisections",
+        [("fold.dispatch:io:2", 1, 0), ("fold.dispatch:oom:2", 0, 1)],
+        ids=["retry", "bisection"],
+    )
+    def test_a_retry_and_a_bisection_end_in_the_exact_gram(
+        self, monkeypatch, plan, retries, bisections, ndev
+    ):
+        from spark_rapids_ml_tpu.resilience import faults
+
+        x = self.rows(3 * self.CHUNK + 11)
+        clean, _ = self.fold(x, ndev=ndev)
+        ingest.release_staging()
+        faults.reset_faults()
+        monkeypatch.setenv(faults.FAULT_PLAN_VAR, plan)
+        monkeypatch.setenv("TPU_ML_RETRY_BACKOFF_S", "0")
+        try:
+            res, moved = self.fold(x, ndev=ndev, min_chunk_rows=8)
+        finally:
+            monkeypatch.delenv(faults.FAULT_PLAN_VAR)
+            faults.reset_faults()
+        assert res.bisections == bisections
+        assert moved.counter("retry.attempts") == retries
+        assert res.rows == len(x)
+        if retries:
+            # the pieces had landed: the retry puts none again
+            self.assert_same_carry(res.carry, clean.carry)
+            assert moved.counter("h2d.pieces", path="stream") == (
+                res.chunks * ingest._PIECES * (ndev or 1)
+            )
+        total = np.asarray(res.carry.xtx)
+        total = total.sum(0) if ndev else total
+        np.testing.assert_allclose(total, x.T @ x, rtol=1e-12)
+        # the halves went whole, and the rest of the stream by pieces of a
+        # device chunk of the new shape
+        if bisections:
+            (kept,) = ingest._kept_staging
+            assert kept.key[0] == self.CHUNK // 2
+            assert moved.counter("h2d.pieces", path="stream") > ingest._PIECES
+
+    @pytest.mark.parametrize("nth", [3, 16, 20])
+    def test_a_landing_that_fails_is_made_up_for_at_the_dispatch(self, monkeypatch, nth):
+        """A piece put ahead of its chunk is the dispatch's work begun early:
+        a landing that raises there what the dispatch retries is left to it
+        (nothing more of that chunk is put ahead, and a counter and a
+        warning say so), and it puts every piece of the chunk again under its
+        retries; one that raises at the dispatch (the 16th: a chunk's last)
+        is retried like any transient."""
+        from spark_rapids_ml_tpu.resilience import faults
+
+        x = self.rows(2 * self.CHUNK + 9)
+        clean, _ = self.fold(x)
+        ingest.release_staging()
+        prog, calls = ingest._land_piece_prog(), []
+
+        def flaky_land(share, piece, at):
+            calls.append(int(at))
+            if len(calls) == nth:
+                raise faults.InjectedTransientIOError("the landing failed")
+            return prog(share, piece, at)
+
+        monkeypatch.setattr(ingest, "_land_piece_prog", lambda: flaky_land)
+        monkeypatch.setenv("TPU_ML_RETRY_BACKOFF_S", "0")
+        res, moved = self.fold(x)
+        self.assert_same_carry(res.carry, clean.carry)
+        assert res.chunks == clean.chunks == 3 and res.rows == len(x)
+        # the chunk it belonged to went again, whole
+        assert len(calls) > res.chunks * ingest._PIECES
+        assert moved.counter("retry.attempts") == int(nth == 16)
+        assert moved.counter("h2d.put_ahead_abandoned", path="stream") == int(nth != 16)
+        # the chunk after the abandoned one goes ahead of its dispatch again
+        if nth == 3:
+            assert calls[nth : nth + ingest._PIECES] == calls[nth + ingest._PIECES :][: ingest._PIECES]
+
+    @pytest.mark.parametrize("kind", ["hang", "fatal"])
+    def test_a_put_ahead_fails_the_stream_where_the_dispatch_would(
+        self, monkeypatch, kind
+    ):
+        """What no retry and no bisection cures ends the stream at the piece
+        that met it, once: a device that does not answer within the bound
+        (``FoldHangTimeout`` from ``h2d.wait``) is not waited for again at
+        every slice staged, and a fatal error is not kept for later."""
+        from spark_rapids_ml_tpu.resilience.retry import FoldHangTimeout
+
+        prog, wait = ingest._land_piece_prog(), ingest._bounded_wait
+        raised = []
+
+        class Stuck:
+            def is_ready(self):
+                return False
+
+        def spy_land(share, piece, at):
+            if kind == "fatal" and int(at) == 3 * (self.CHUNK // ingest._PIECES):
+                raised.append(at)
+                raise ValueError("not a device's fault")
+            share, landed = prog(share, piece, at)
+            return share, Stuck() if kind == "hang" else landed
+
+        def spy_wait(a, timeout_s, **kw):
+            if isinstance(a, Stuck):
+                raised.append(a)
+                raise FoldHangTimeout("h2d.wait did not complete")
+            return wait(a, timeout_s, **kw)
+
+        monkeypatch.setattr(ingest, "_bounded_wait", spy_wait)
+        error = FoldHangTimeout if kind == "hang" else ValueError
+        monkeypatch.setattr(ingest, "_land_piece_prog", lambda: spy_land)
+        with pytest.raises(error, match="not a device's fault|h2d.wait did not"):
+            self.fold(self.rows(2 * self.CHUNK))
+        assert len(raised) == 1
+
+    def test_a_share_of_the_device_chunk_is_made_on_its_own_device(self, monkeypatch):
+        """``jnp.zeros(..., device=d)`` fills its shard on the default device
+        and copies it to ``d`` (jax 0.9: ``lax.full`` over
+        ``make_array_from_callback``), which on four chips left the first
+        holding three more shares of a chunk at a stream's start. The shares
+        are results of a program that runs on their device."""
+        import jax._src.array as jax_array
+
+        def refuse(*a, **kw):
+            raise AssertionError("a share was filled elsewhere and copied over")
+
+        devices = jax.devices()[:4]
+        key = (((32, self.N), np.dtype(np.float32)), ((32,), np.dtype(np.float32)))
+        monkeypatch.setattr(jax_array, "make_array_from_callback", refuse)
+        for d in devices:
+            x, w = ingest._new_share_prog(key, d)()
+            assert x.devices() == w.devices() == {d}
+            assert x.shape == (32, self.N) and not np.asarray(x).any()
+        x, _ = ingest._new_share_prog(key, None)()
+        assert x.devices() == {jax.devices()[0]}
+        res, _ = self.fold(self.rows(2 * self.CHUNK + 5), ndev=4)
+        assert res.chunks == 3
+
+    def test_the_landing_program_has_a_name_of_its_own(self):
+        lowered = ingest._land_piece_prog().lower(
+            [jax.ShapeDtypeStruct((16, 4), np.float32)],
+            [jax.ShapeDtypeStruct((2, 4), np.float32)],
+            jax.ShapeDtypeStruct((), np.int32),
+        )
+        text = lowered.as_text()
+        assert "module @jit__land_piece" in text
+        # the share is donated: the update is in place
+        assert "tf.aliasing_output = 0" in text or "jax.buffer_donor" in text

@@ -75,3 +75,51 @@ def test_the_newton_program_holds_the_rows_once_at_the_cells_size(topo):
     memory = compiled.memory_analysis()
     assert 4 * rows * d <= memory.argument_size_in_bytes < 4 * rows * 3_072
     assert memory.temp_size_in_bytes < 1e9
+
+
+def test_a_piece_lands_in_place_at_the_cells_size(topo):
+    """``pca2048_fit_stream``: a piece of 32,768 rows of 2,048 float32 (a
+    sixteenth of the chunk's 524,288) and of its weights written into the
+    device chunk (``spark.ingest._land_piece_prog``, PR 36). The chunk is
+    donated, so the program aliases it to its result and holds nothing the
+    size of a chunk beside its arguments: one chunk on the device, not two."""
+    from jax.sharding import SingleDeviceSharding
+
+    from spark_rapids_ml_tpu.spark import ingest
+
+    rows, n = 524_288, 2_048
+    piece = rows // ingest._PIECES
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype=np.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = ingest._land_piece_prog().lower(
+        [arg((rows, n)), arg((rows,))],
+        [arg((piece, n)), arg((piece,))],
+        arg((), np.int32),
+    ).compile()
+    assert compiled.as_text().startswith("HloModule jit__land_piece")
+    memory = compiled.memory_analysis()
+    chunk = 4 * rows * (n + 1)
+    assert memory.alias_size_in_bytes == chunk
+    assert memory.argument_size_in_bytes < chunk + 4 * piece * (n + 1) + 4096
+    assert memory.temp_size_in_bytes < 4 * piece * (n + 1)
+
+
+def test_a_share_of_the_device_chunk_is_made_where_it_stays(topo):
+    """The zeroed share a stream's pieces land in is the result of a program
+    compiled for its own chip (``spark.ingest._new_share_prog``), with no
+    argument and no temporary: nothing is filled on chip 0 and copied over
+    (what ``jnp.zeros(..., device=d)`` does, and what three four-chip runs
+    of PR 36 paid 8.6 GB for on chip 0)."""
+    from spark_rapids_ml_tpu.spark import ingest
+
+    rows, n = 524_288, 2_048
+    key = (((rows, n), np.dtype(np.float32)), ((rows,), np.dtype(np.float32)))
+    for device in topo.devices[:2]:
+        compiled = ingest._new_share_prog(key, device).lower().compile()
+        assert {s.device_set.pop() for s in compiled.output_shardings} == {device}
+        memory = compiled.memory_analysis()
+        assert memory.argument_size_in_bytes == 0 and memory.temp_size_in_bytes == 0
+        assert 4 * rows * (n + 1) <= memory.output_size_in_bytes < 4 * rows * (n + 1) + 4096
