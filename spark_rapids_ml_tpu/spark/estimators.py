@@ -1439,25 +1439,31 @@ class SparkKMeans(_HasDistribution, KMeans):
                 # program over the ingested shards, weighted k-means++
                 # k-reduction on-device — candidates never leave the mesh
                 with trace_range("kmeans mesh init"):
-                    init_fn = PK.make_distributed_kmeans_parallel_init(
-                        ing.mesh, k, init_steps=self.getInitSteps()
-                    )
-                    cand, counts = init_fn(
-                        ing.xs, ing.ws, jax.random.PRNGKey(self.getSeed())
-                    )
-                    if int((np.asarray(counts) > 0).sum()) <= k:
+                    # the seeding program (jit__kmeans_seed): its dispatch,
+                    # and the wait for its counts on the host
+                    with trace_range("kmeans.seed.rounds"):
+                        init_fn = PK.make_distributed_kmeans_parallel_init(
+                            ing.mesh, k, init_steps=self.getInitSteps()
+                        )
+                        cand, counts = init_fn(
+                            ing.xs, ing.ws, jax.random.PRNGKey(self.getSeed())
+                        )
+                        owned = int((np.asarray(counts) > 0).sum())
+                    if owned <= k:
                         # degenerate oversampling (tiny/collapsed data):
                         # the driver-pass init has the uniform top-up logic
                         centers = self._kmeans_parallel_init_df(
                             selected, input_col, weight_col, k
                         )
                     else:
-                        centers = np.asarray(
-                            KM.weighted_kmeans_plus_plus_init(
-                                jax.random.PRNGKey(self.getSeed() + 1),
-                                cand, counts, k,
+                        # the eager reduction to k, and the centres' copy
+                        with trace_range("kmeans.seed.reduce"):
+                            centers = np.asarray(
+                                KM.weighted_kmeans_plus_plus_init(
+                                    jax.random.PRNGKey(self.getSeed() + 1),
+                                    cand, counts, k,
+                                )
                             )
-                        )
             max_iter, tol = self.getMaxIter(), self.getTol()
             if ckpt is not None:
                 # chunked whole-loop Lloyd: checkpoint_every iterations per

@@ -345,9 +345,12 @@ def stream_to_mesh(
         devices = sorted(devmap, key=lambda d: devmap[d][0].start or 0)
 
         def put_shard(staged: _StagingSet, fill: int) -> None:
-            d = devices[len(x_parts)]
+            index = len(x_parts)
+            d = devices[index]
             nbytes = 0
             with trace_range("h2d.put"):
+                t0 = time.perf_counter()
+                put = []
                 for buf, parts, wanted in (
                     (staged.x, x_parts, True),
                     (staged.y, y_parts, want_y),
@@ -356,7 +359,10 @@ def stream_to_mesh(
                     if wanted:
                         parts.append(jax.device_put(buf, d))
                         staged.placed.append(parts[-1])
+                        put.append(parts[-1])
                         nbytes += buf.nbytes
+                # a shard's buffers are one transfer to its device
+                _transfers.issued(put, nbytes, (index,), "mesh", t0)
             REGISTRY.counter_inc("h2d.bytes", nbytes, path="mesh")
 
         # a set is a device's shard, in the dtype the device holds
@@ -1004,6 +1010,162 @@ class _Stager:
             self.fill = 0
 
 
+# ---------------------------------------------------------------------------
+# Transfers in flight: every host-to-device transfer from its issue to its landing
+# ---------------------------------------------------------------------------
+
+
+class _Transfers:
+    """What is on its way to which device. A ``device_put`` returns once a
+    transfer is issued, and the host meets its end only where it happens to
+    wait for something queued behind it; this is the one owner of the time
+    between. The three places that issue a transfer tell it
+    (:meth:`issued`): ``_DeviceChunk.put`` (a piece), ``stream_to_mesh``'s
+    ``put_shard`` (a resident shard's buffers, one transfer a shard) and
+    ``stream_fold``'s whole put (a caller's ``put_fn``, a bisection's halves:
+    over a mesh one transfer on every device it touches, ``device="*"``).
+    It learns of the landing by waiting for the arrays on a thread of its
+    own, one a ``device`` label (``tpu-ml-h2d-wait-<device>``, daemon,
+    started at that label's first transfer, ended by ``release_staging()``
+    with the pool): a device's transfers are waited for in the order they
+    were issued, and no device's wait stands behind another's. It holds the
+    arrays until they are ready and no longer, raises nothing into the fit,
+    compiles nothing and has no knob: it is always on, as the registry is.
+
+    What it books when a transfer is ready (``path`` is ``"stream"`` or
+    ``"mesh"``; ``device`` the index on the mesh's data axis, 0 without a
+    mesh):
+
+    - ``h2d.transfer_seconds{path, device}``: issue to ready;
+    - ``h2d.link_busy_seconds{path}``: summed over the devices, the seconds
+      in which that device had a transfer issued and not ready, by a count
+      in flight a device (the exact union, no model of a link), booked up to
+      every completion;
+    - ``h2d.any_link_busy_seconds{path}``: the same count over all devices;
+    - ``h2d.transfer_bytes{path}``: the bytes that became ready, so that
+      bytes over busy seconds is one boundary's ratio;
+    - ``h2d.transfers_failed{path}`` in place of the first and the fourth
+      where the wait raised (a warning too), and the next is timed as ever;
+    - a ``TraceAnnotation`` ``h2d.transfer`` on the waiting thread, so that a
+      profiler's trace has a line a link beside the runtime's threads and
+      the device, and a timeline span ``h2d.transfer`` with ``parent`` (the
+      span that issued it, ``h2d.put``), ``estimator``, ``fit_id``,
+      ``device``, ``path`` and ``bytes``: both from the moment the thread
+      begins to wait for this transfer (its issue, or the one before it on
+      that device becoming ready) to its ready.
+
+    ``wait`` blocks until the arrays handed to it are ready
+    (``jax.block_until_ready``; the tests' stubs sleep)."""
+
+    def __init__(self, wait=None):
+        self.wait = wait
+        self.lock = threading.Lock()
+        self.waiters: dict[str, tuple[queue.SimpleQueue, threading.Thread]] = {}
+        # by (path, device), and (path, None) for "any device": the transfers
+        # issued and not ready, and the time its busy seconds are booked up to
+        self.flying: dict[tuple, int] = {}
+        self.booked_to: dict[tuple, float] = {}
+
+    @staticmethod
+    def _keys(path: str, devices: tuple) -> list[tuple]:
+        return [(path, d) for d in devices] + [(path, None)]
+
+    def issued(self, arrays, nbytes: int, devices, path: str, t0: float) -> None:
+        """``arrays`` (``nbytes`` of them) were just put to ``devices``
+        (indices on the data axis) under ``path``, the put having begun at
+        ``t0`` (``time.perf_counter()``). Called on the issuing thread,
+        inside its ``h2d.put``: a thread of its own has no context
+        variables."""
+        from spark_rapids_ml_tpu.telemetry import (
+            current_estimator, current_fit_id, current_span,
+        )
+
+        devices = tuple(devices)
+        label = str(devices[0]) if len(devices) == 1 else "*"
+        cause = (current_span(), current_estimator() or "", current_fit_id() or "")
+        with self.lock:
+            for key in self._keys(path, devices):
+                if not self.flying.get(key):
+                    self.booked_to[key] = max(self.booked_to.get(key, t0), t0)
+                self.flying[key] = self.flying.get(key, 0) + 1
+            if label not in self.waiters:
+                tasks: queue.SimpleQueue = queue.SimpleQueue()
+                thread = threading.Thread(
+                    target=self._watch, args=(tasks,),
+                    name=f"tpu-ml-h2d-wait-{label}", daemon=True,
+                )
+                self.waiters[label] = (tasks, thread)
+                thread.start()
+            # under the lock, so that nothing is queued behind the end
+            # marker of a thread that close() ends meanwhile
+            self.waiters[label][0].put(
+                [arrays, nbytes, devices, path, label, t0, cause]
+            )
+
+    def _watch(self, tasks: queue.SimpleQueue) -> None:
+        while (task := tasks.get()) is not None:
+            self._await(task)
+            del task
+
+    def _await(self, task: list) -> None:
+        """Wait for one transfer's arrays and book it. The task is emptied
+        first, so the arrays have this frame's name alone and go the moment
+        they are ready, before anything is booked."""
+        import jax
+
+        arrays, nbytes, devices, path, label, t0, cause = task
+        task.clear()
+        begun = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation("h2d.transfer"):
+                (self.wait or jax.block_until_ready)(arrays)
+            end, ready = time.perf_counter(), True
+        except Exception:  # noqa: BLE001 — this thread goes on to the next
+            end, ready = time.perf_counter(), False
+            logger.warning(
+                "the wait for a host-to-device transfer failed; it is booked "
+                "in h2d.transfers_failed and not timed", exc_info=True,
+            )
+        del arrays
+        with self.lock:
+            busy = []
+            for key in self._keys(path, devices):
+                self.flying[key] -= 1
+                busy.append(max(0.0, end - self.booked_to[key]))
+                self.booked_to[key] = max(self.booked_to[key], end)
+        REGISTRY.counter_inc("h2d.link_busy_seconds", sum(busy[:-1]), path=path)
+        REGISTRY.counter_inc("h2d.any_link_busy_seconds", busy[-1], path=path)
+        if not ready:
+            REGISTRY.counter_inc("h2d.transfers_failed", path=path)
+            return
+        REGISTRY.histogram_record(
+            "h2d.transfer_seconds", end - t0, path=path, device=label
+        )
+        REGISTRY.counter_inc("h2d.transfer_bytes", nbytes, path=path)
+        parent, estimator, fit_id = cause
+        TIMELINE.record_span(
+            "h2d.transfer", begun, end, parent=parent, estimator=estimator,
+            fit_id=fit_id, device=label, path=path, bytes=nbytes,
+        )
+
+    def in_flight(self) -> int:
+        """Transfers issued and not yet booked."""
+        with self.lock:
+            return sum(n for (_, d), n in self.flying.items() if d is None)
+
+    def close(self) -> None:
+        """End the threads, each once it has waited out what it was already
+        handed; the next transfer starts another."""
+        with self.lock:
+            waiters, self.waiters = self.waiters, {}
+        for tasks, _ in waiters.values():
+            tasks.put(None)
+
+
+# the one owner, fed by the three places that issue a transfer
+_transfers = _Transfers()
+
+
 # Pieces a device's share of a chunk is put in, and how many of them a device
 # may hold that have not landed yet. A piece's transfer runs while the next is
 # staged, and what a device holds beside its chunk is the pieces in flight:
@@ -1162,10 +1324,12 @@ class _DeviceChunk:
                         if not oldest.is_ready():
                             with trace_range("h2d.wait"):
                                 self.wait(oldest)
-                    piece = [
-                        jax.device_put(b[lo + at : lo + at + take], device)
-                        for b in bufs
-                    ]
+                    t0 = time.perf_counter()
+                    cut = [b[lo + at : lo + at + take] for b in bufs]
+                    piece = [jax.device_put(c, device) for c in cut]
+                    _transfers.issued(
+                        piece, sum(c.nbytes for c in cut), (i,), "stream", t0
+                    )
                 self.pending = (i, piece, at)
                 self.done[i] += take
                 REGISTRY.counter_inc("h2d.pieces", path="stream")
@@ -1212,12 +1376,15 @@ def release_staging() -> None:
     memory, held so that the next ingest of the same shape writes into pages
     that are already mapped) and the host pass's pool (its threads end once
     the blocks already handed to them have; the next batch large enough to
-    cut starts another)."""
+    cut starts another), and end the threads that wait for the transfers in
+    flight (``_Transfers``: each once it has waited out what it was handed;
+    the next transfer starts another)."""
     with _kept_staging_lock:
         _kept_staging.clear()
     with _pool_lock:
         while _pool:
             _pool.pop().close()
+    _transfers.close()
 
 
 def stream_fold(
@@ -1494,6 +1661,11 @@ def stream_fold(
             flush=True,
         )
 
+    def shards_of(array) -> int:
+        """One device's share each: the data axis of a mesh, 1 without one
+        (and for a host array)."""
+        return len(getattr(array, "addressable_shards", (array,)))
+
     def chunk_is_finite(arrays, bufs) -> bool:
         """The verdict on one chunk that was put, asked where ``put``
         left it: ``arrays`` on their device(s), once they have landed (the
@@ -1565,7 +1737,13 @@ def stream_fold(
                     arrays = device_chunk.arrays(staged)
                 else:
                     with trace_range("h2d.put"):
+                        t0 = time.perf_counter()
                         arrays = [put(b) for b in bufs]
+                        # one transfer on every device the chunk touches
+                        _transfers.issued(
+                            arrays, sum(b.nbytes for b in bufs),
+                            range(shards_of(arrays[0])), "stream", t0,
+                        )
                 placed.extend(arrays)
                 return arrays
 
@@ -1598,11 +1776,7 @@ def stream_fold(
         nbytes = sum(b.nbytes for b in bufs)
         max_put = max(max_put, nbytes)
         REGISTRY.counter_inc("h2d.bytes", nbytes, path="stream")
-        # one device's share each: the data axis of a mesh, 1 without one
-        REGISTRY.counter_inc(
-            "h2d.shards", len(getattr(xd, "addressable_shards", (xd,))),
-            path="stream",
-        )
+        REGISTRY.counter_inc("h2d.shards", shards_of(xd), path="stream")
         n_chunks += 1
 
     def dispatch_buffers(staged):

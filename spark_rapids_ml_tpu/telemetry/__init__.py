@@ -41,6 +41,7 @@ from spark_rapids_ml_tpu.telemetry.registry import (
 from spark_rapids_ml_tpu.telemetry.spans import (
     current_estimator,
     current_fit_id,
+    current_span,
     current_transform_id,
     install_fit_id_filter,
     reset_current_estimator,
@@ -109,6 +110,7 @@ __all__ = [
     "reset_metrics",
     "current_estimator",
     "current_fit_id",
+    "current_span",
     "current_transform_id",
     "install_fit_id_filter",
     "reset_current_estimator",

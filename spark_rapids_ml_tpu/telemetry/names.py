@@ -32,6 +32,17 @@ METRICS: frozenset[str] = frozenset({
     # chunks whose pieces stopped being put ahead after a put or a landing
     # failed in a way the dispatch retries or bisects (path="stream")
     "h2d.put_ahead_abandoned",
+    # every host-to-device transfer from its issue to its landing, booked
+    # when its arrays are ready (spark.ingest._Transfers; path="stream" or
+    # "mesh"): issue to ready by device (histogram); summed over the devices,
+    # the seconds a device had a transfer issued and not ready, and the
+    # seconds any device had one (exact unions, by a count in flight); the
+    # bytes that became ready; the waits that raised
+    "h2d.transfer_seconds",
+    "h2d.link_busy_seconds",
+    "h2d.any_link_busy_seconds",
+    "h2d.transfer_bytes",
+    "h2d.transfers_failed",
     # rows the resident ingest padded its shards with (padded_rows - rows,
     # once an ingest): zero rows of weight 0 that every pass walks
     "mesh.pad_rows",
@@ -203,6 +214,7 @@ METRIC_PREFIXES: tuple[str, ...] = (
 HISTOGRAMS: frozenset[str] = frozenset({
     "span.seconds",
     "span.self_seconds",
+    "h2d.transfer_seconds",
     "compile.seconds",
     "compile.program_seconds",
     "compile.cache_load_seconds",
@@ -259,6 +271,10 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "fold.finalize",
     "h2d.put",
     "h2d.wait",
+    # a transfer from the moment its waiting thread turns to it to its ready
+    # (spark.ingest._Transfers: a timeline span and a TraceAnnotation on
+    # that thread, no span.seconds series)
+    "h2d.transfer",
     "ingest.chunk",
     "ingest.scan",
     "ingest.stage",
@@ -323,6 +339,10 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "kmeans transform",
     "kmeans mesh fit",
     "kmeans mesh init",
+    # its two halves: the seeding program's dispatch and the wait for its
+    # counts; the eager weighted k-means++ and the centres' copy to the host
+    "kmeans.seed.rounds",
+    "kmeans.seed.reduce",
     "kmeans mesh-local fit",
     "kmeans mesh-local chunked fit",
     "dbscan cluster",

@@ -75,6 +75,13 @@ _open_span: contextvars.ContextVar[_Frame | None] = contextvars.ContextVar(
 )
 
 
+def current_span() -> str:
+    """The name of the innermost span open in this context ("" outside
+    any): what work handed to another thread names as its cause."""
+    frame = _open_span.get()
+    return frame.name if frame is not None else ""
+
+
 def current_estimator() -> str | None:
     return _current_estimator.get()
 

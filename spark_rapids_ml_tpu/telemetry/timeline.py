@@ -66,6 +66,33 @@ def timeline_capacity() -> int:
     return cap
 
 
+# Who records: the process and the thread, asked of the kernel once each and
+# not once an event. Both are system calls, and under a sandboxed kernel
+# (gVisor, where the chip's hosts run) ``os.getpid()`` took 0.18-0.26 ms and
+# ``threading.get_native_id()`` 0.03 ms: 275 events a streamed PCA fit made
+# 60-80 ms of it, more than half of what ``compute cov`` could not name
+# (PERF.md section 6, PR 37). A forked child asks again.
+_pid = os.getpid()
+_thread = threading.local()
+
+
+def _forget_who() -> None:
+    global _pid, _thread
+    _pid, _thread = os.getpid(), threading.local()
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_forget_who)
+
+
+def _tid() -> int:
+    try:
+        return _thread.native_id
+    except AttributeError:
+        _thread.native_id = threading.get_native_id()
+        return _thread.native_id
+
+
 def _now_us() -> int:
     # CLOCK_MONOTONIC microseconds — the same clock trace_range spans use,
     # so span and instant timestamps interleave exactly
@@ -112,8 +139,8 @@ class Timeline:
                 "ph": "X",
                 "ts": int(t0_s * 1e6),
                 "dur": max(0, int((t1_s - t0_s) * 1e6)),
-                "pid": os.getpid(),
-                "tid": threading.get_native_id(),
+                "pid": _pid,
+                "tid": _tid(),
                 "cat": "span",
                 "args": {k: v for k, v in labels.items() if v},
             }
@@ -128,8 +155,8 @@ class Timeline:
                 "name": name,
                 "ph": "i",
                 "ts": _now_us(),
-                "pid": os.getpid(),
-                "tid": threading.get_native_id(),
+                "pid": _pid,
+                "tid": _tid(),
                 "cat": "instant",
                 "s": "t",  # thread-scoped instant (Perfetto render hint)
                 "args": {k: v for k, v in labels.items() if v},

@@ -44,3 +44,24 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(42)
+
+
+@pytest.fixture
+def transfers_booked():
+    """``transfers_booked()`` returns once every host-to-device transfer
+    issued so far is booked: the threads that wait for them
+    (``spark.ingest._Transfers``) can lag a toy's fit, whose arrays are ready
+    at once. ``transfers_booked(owner)`` asks another owner than the
+    module's."""
+    import time
+
+    from spark_rapids_ml_tpu.spark import ingest
+
+    def wait(transfers=None, timeout=30.0):
+        transfers = transfers or ingest._transfers
+        deadline = time.monotonic() + timeout
+        while transfers.in_flight() and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert transfers.in_flight() == 0
+
+    return wait

@@ -408,6 +408,10 @@ COUNTERS = (
         ("h2d.shards", {"path": "stream"}),
         ("h2d.pieces", {"path": "stream"}),
         ("h2d.pieces", {"path": "mesh"}),
+        ("h2d.transfer_bytes", {"path": "stream"}),
+        ("h2d.transfer_bytes", {"path": "mesh"}),
+        ("h2d.transfers_failed", {"path": "stream"}),
+        ("h2d.transfers_failed", {"path": "mesh"}),
         ("kmeans.iterations", {"path": "mesh-local"}),
         ("ingest.rows", {}),
         ("ingest.bytes", {}),
@@ -437,6 +441,8 @@ CENSUS = {
             # the pieces that a later slice leaves whole go at once, outside
             ("h2d.put", "fold.dispatch"): 194,
             ("h2d.put", "compute cov"): 62,
+            # PR 37: a transfer a piece, booked by the thread that waits for it
+            ("h2d.transfer", "h2d.put"): 256,
             ("fold.wait", "fold.dispatch"): 4,
             ("ingest.scan", "fold.dispatch"): 4,
             ("fold.enqueue", "fold.dispatch"): 4,
@@ -449,6 +455,7 @@ CENSUS = {
             "h2d.bytes{path=stream}": 147456,
             "h2d.shards{path=stream}": 16,
             "h2d.pieces{path=stream}": 256,
+            "h2d.transfer_bytes{path=stream}": 147456,
             "ingest.verdicts{where=device,clean=yes}": 4,
             "ingest.rows": 1650,
             "ingest.bytes": 105600,
@@ -463,11 +470,16 @@ CENSUS = {
             ("ingest.stage", "mesh.ingest"): 8,
             ("stage.reclaim", "mesh.ingest"): 5,
             ("h2d.put", "mesh.ingest"): 4,
+            # PR 37: a transfer a shard; the seeding's two halves
+            ("h2d.transfer", "h2d.put"): 4,
+            ("kmeans.seed.rounds", "kmeans mesh init"): 1,
+            ("kmeans.seed.reduce", "kmeans mesh init"): 1,
         },
         {
             "stage.buffers{state=reused}": 4,
             "ingest.batches{path=inline}": 7,
             "h2d.bytes{path=mesh}": 147456,
+            "h2d.transfer_bytes{path=mesh}": 147456,
             "kmeans.iterations{path=mesh-local}": 7,
             "ingest.rows": 1650,
             "ingest.bytes": 105600,
@@ -477,7 +489,9 @@ CENSUS = {
 
 
 @pytest.mark.parametrize("fit", list(CENSUS))
-def test_census_of_spans_and_counters(session, monkeypatch, copying_put, fit):
+def test_census_of_spans_and_counters(
+    session, monkeypatch, copying_put, transfers_booked, fit
+):
     on_devices(monkeypatch, 4)
     monkeypatch.setenv("TPU_ML_STREAM_CHUNK_ROWS", "512")
     blocks = data_blobs.make_blocks(11, 8, 6, 550, 2, spread=1.5, flatten=2.0)
@@ -494,9 +508,11 @@ def test_census_of_spans_and_counters(session, monkeypatch, copying_put, fit):
     est = est.setInputCol(bench_data.COLUMN)
     try:
         est.fit(df)  # compiled, and the staging set kept, before the fit read
+        transfers_booked()
         seq = TIMELINE.seq()
         before = REGISTRY.snapshot()
         est.fit(df)
+        transfers_booked()
     finally:
         set_config(stream_fit_max_resident_bytes=old)
     moved = REGISTRY.snapshot().delta(before)

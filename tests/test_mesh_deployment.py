@@ -233,10 +233,14 @@ def test_what_an_x4_metric_reads_is_declared(path):
     work, a function of ``benchmarks/opcount.py`` fed from the configuration."""
     spec = json.loads(path.read_text())["reader"]
     kind = spec["kind"]
-    if kind == "span":
+    if kind in ("span", "span_zero"):  # span_zero (PR 37): a wait that need not occur
         assert {spec["phase"], spec["per_span"]} <= names.SPAN_PHASES
     elif kind == "counter":
         assert spec["counter"] in names.METRICS
+    elif kind == "counter_per_span_s":  # PR 37: the links' busy share
+        assert spec["counter"] in names.METRICS and spec["phase"] in names.SPAN_PHASES
+    elif kind == "counter_ratio":  # PR 37: a link's rate, the links at once
+        assert {spec["counter"], spec["per_counter"]} <= names.METRICS - names.HISTOGRAMS
     elif kind == "trace_program_ms":
         assert spec["program"] == "jit__psum" and spec["per_span"] in names.SPAN_PHASES
     elif kind == "trace_program":
@@ -249,5 +253,6 @@ def test_what_an_x4_metric_reads_is_declared(path):
 
 
 def test_the_cell_has_its_twelve_metrics():
-    assert len(X4_METRICS) == 12
+    # and since PR 37 four of the links: busy share, overlap, rate, the wait
+    assert len(X4_METRICS) == 12 + 4
     assert CONFIG["per_chip"]["chunk_rows"] * 4 == int(CONFIG["env"]["TPU_ML_STREAM_CHUNK_ROWS"])

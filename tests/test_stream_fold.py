@@ -907,7 +907,8 @@ class TestHostPassPool:
     @pytest.mark.parametrize("batches", [1, 3, 7])
     def test_spans_a_batch_are_what_they_were(self, small_blocks, batches):
         """One ``ingest.stage`` a batch and one ``ingest.scan`` a chunk, on
-        the caller's thread; the workers (and the bounded wait's) open none."""
+        the caller's thread; the workers (and the bounded wait's) open none.
+        A transfer's own span is booked by the thread that waits for it."""
         import threading
 
         from spark_rapids_ml_tpu.telemetry import TIMELINE
@@ -920,7 +921,10 @@ class TestHostPassPool:
         assert self.paths(moved)["pool"] >= batches
         assert m["ingest.scan"]["count"] == res.chunks
         assert max(batches, res.chunks) <= m["ingest.stage"]["count"] <= batches + res.chunks
-        spans = [e for e in TIMELINE.events(seq) if e["cat"] == "span"]
+        spans = [
+            e for e in TIMELINE.events(seq)
+            if e["cat"] == "span" and e["name"] != "h2d.transfer"
+        ]
         assert {e["tid"] for e in spans} == {threading.get_native_id()}
         np.testing.assert_allclose(res.carry.xtx, x.T @ x, rtol=1e-12)
 
@@ -1402,7 +1406,9 @@ class TestPiecedPut:
             (pieced.chunks + skipped) * ingest._PIECES * (ndev or 1)
         )
 
-    def test_the_stream_never_holds_two_chunks_on_its_device(self, monkeypatch):
+    def test_the_stream_never_holds_two_chunks_on_its_device(
+        self, monkeypatch, transfers_booked
+    ):
         """After every chunk's fold is enqueued, and while the next is
         staged, one array of the chunk's shape is alive, whatever a fold
         still reads: the landings write into it in place."""
@@ -1427,6 +1433,10 @@ class TestPiecedPut:
         def spy_write(staged, fill, *a, **kw):
             write(staged, fill, *a, **kw)
             seen.append(alive(shape))
+            # a piece's arrays are also held by the thread that waits for
+            # its transfer, until they are ready: here they are at once, so
+            # once that thread has had its turn
+            transfers_booked()
             pieces_alive.append(alive(piece))
 
         monkeypatch.setattr(ingest._Stager, "flush", spy_flush)

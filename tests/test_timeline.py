@@ -76,6 +76,43 @@ class TestTimelineUnit:
         assert i["s"] == "t"
         assert i["args"] == {"site": "fold.dispatch", "attempt": 1}
 
+    def test_who_records_is_asked_of_the_kernel_once_and_again_after_a_fork(
+        self, monkeypatch
+    ):
+        """``os.getpid()`` and ``threading.get_native_id()`` are system calls
+        (0.2 ms under gVisor): an event reads what was asked once a process
+        and once a thread, and a forked child asks again."""
+        import threading
+
+        from spark_rapids_ml_tpu.telemetry import timeline
+
+        tl = Timeline(capacity=16)
+        tl.record_span("fold", 1.0, 1.5)  # this thread's id is known by now
+        asked = []
+        monkeypatch.setattr(os, "getpid", lambda: asked.append("pid") or 4242)
+        monkeypatch.setattr(
+            threading, "get_native_id", lambda: asked.append("tid") or 77
+        )
+        for _ in range(3):
+            tl.record_span("fold", 1.0, 1.5)
+            tl.record_instant("retry")
+        assert asked == []
+        other = threading.Thread(target=tl.record_instant, args=("retry",))
+        other.start()
+        other.join(timeout=30)
+        # (a thread asks for its own id as it starts, too)
+        assert "pid" not in asked and tl.events()[-1]["tid"] == 77
+        try:
+            timeline._forget_who()  # what a forked child runs first
+            tl.record_instant("retry")
+            assert tl.events()[-1]["pid"] == 4242 and tl.events()[-1]["tid"] == 77
+        finally:
+            monkeypatch.undo()
+            timeline._forget_who()
+        tl.record_instant("retry")
+        assert tl.events()[-1]["pid"] == os.getpid()
+        assert tl.events()[-1]["tid"] == threading.get_native_id()
+
     def test_ring_stays_within_bound(self):
         tl = Timeline(capacity=64)
         for k in range(1000):
