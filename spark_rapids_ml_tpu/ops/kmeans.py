@@ -7,7 +7,14 @@ on GPU; the TPU-native formulation puts both hot ops on the MXU:
   [rows, n]×[n, k] matmul;
 - centroid accumulation: scatter-by-label recast as a one-hot matmul
   onehotᵀ·x ([k, rows]×[rows, n]) — a second MXU pass instead of the GPU's
-  atomic scatters, which TPUs don't like.
+  atomic scatters, which TPUs don't like. For float32 rows it takes three
+  bfloat16 passes where the cross term takes ``HIGHEST``'s six: a one-hot
+  is 0s and 1s, which bfloat16 holds exactly, so its mid and lo parts are
+  zero and only the three parts of the rows are left to multiply
+  (``exact_bf16_parts``, ``_onehot_sums``). Three parts are all of it:
+  8 + 8 + 8 significant bits are float32's 24, so no term that is not
+  zero is dropped. Neither operand of the cross term is exact in
+  bfloat16, so it is not touched.
 
 Row blocks are processed under ``lax.scan`` so the [block, k] distance and
 one-hot tiles stay bounded in VMEM/HBM regardless of partition size (rows·k
@@ -74,6 +81,60 @@ def assign_clusters(
     return jnp.argmin(d, axis=1), jnp.min(d, axis=1)
 
 
+def exact_bf16_parts(dtype) -> int | None:
+    """How many bfloat16 parts hold a value of ``dtype`` exactly, or None
+    where the one-hot product has no such cut to take.
+
+    float32 has 24 significant bits and bfloat16 its exponent range with 8:
+    three roundings to nearest, each of what the last left, hold all 24.
+    float64 has no such cut (its range is not bfloat16's) and bfloat16
+    needs none: both keep the product as it is written. The kernel and the
+    counter ``kmeans.split_iterations`` ask this one rule."""
+    return 3 if jnp.dtype(dtype) == jnp.float32 else None
+
+
+def split_bf16(v: jax.Array) -> list[jax.Array]:
+    """``v`` cut in its ``exact_bf16_parts`` bfloat16 arrays, which sum back
+    to it exactly, largest first.
+
+    Each part is the rest rounded to bfloat16's 8 significant bits
+    (``lax.reduce_precision``, which no compiler folds away as it may an
+    ``astype`` pair under excess precision) and the rest goes on as the
+    exact difference."""
+    out = []
+    for _ in range(exact_bf16_parts(v.dtype) - 1):
+        head = lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+        out.append(head.astype(jnp.bfloat16))
+        v = v - head
+    out.append(v.astype(jnp.bfloat16))
+    return out
+
+
+def _onehot_sums(member: jax.Array, rows: jax.Array) -> jax.Array:
+    """``member.T @ rows`` ([k, n], the rows' float32) in as many bfloat16
+    passes as the rows have exact parts.
+
+    A one-hot's entries are 0 and 1, which bfloat16 holds exactly, so of
+    the six passes a float32 product takes at ``HIGHEST`` (hi·hi, hi·mid,
+    mid·hi, hi·lo, mid·mid, lo·hi) the three that multiply the one-hot's
+    mid and lo parts add zeros. What is left is the one-hot in bfloat16
+    against each part of the rows, accumulated in float32: no term that is
+    not zero is dropped. The parts lie side by side as one [rows, 3n]
+    operand, so the one-hot is built once (three products build it three
+    times and read 0.13 s a fit more at 6.3M rows: PERF.md §6, PR 38); they
+    are cut inside the fusion, block by block, and never written."""
+    parts = split_bf16(rows)
+    prod = lax.dot_general(
+        member.astype(jnp.bfloat16), jnp.concatenate(parts, axis=1),
+        (((0,), (0,)), ((), ())), preferred_element_type=rows.dtype,
+    )
+    # smallest first, as a float32 sum is best taken
+    *heads, total = jnp.split(prod, len(parts), axis=1)
+    for head in reversed(heads):
+        total = total + head
+    return total
+
+
 @partial(jax.jit, static_argnames=("block_rows", "policy"))
 def kmeans_stats(
     x: jax.Array,
@@ -86,6 +147,14 @@ def kmeans_stats(
     """One Lloyd accumulation pass over a row shard, scanned in blocks.
 
     ``weights`` masks padded rows (0 weight) so shape bucketing stays exact.
+
+    The sums follow the dtype of the rows (``exact_bf16_parts``), with no
+    knob: float32 rows are weighed in float32 (exact where a weight is 0
+    or 1, one rounding where it is an instance weight) and summed as the
+    one-hot in bfloat16 against their three bfloat16 parts, which is every
+    non-zero term of the float32 product in half its passes; any other
+    dtype takes the product as it is written. ``counts`` and ``cost`` are
+    float32 sums of the weights and of the weighed distances either way.
     """
     rows, n = x.shape
     k = centers.shape[0]
@@ -104,11 +173,14 @@ def kmeans_stats(
         sums, counts, cost = carry
         xi, wi = blk
         labels, dists = assign_clusters(xi, centers, policy=policy)
-        onehot = (
-            labels[:, None] == jnp.arange(k, dtype=labels.dtype)[None, :]
-        ).astype(x.dtype) * wi[:, None]
-        sums = sums + jnp.matmul(onehot.T, xi, precision=DEFAULT_PRECISION)
-        counts = counts + jnp.sum(onehot, axis=0)
+        member = labels[:, None] == jnp.arange(k, dtype=labels.dtype)[None, :]
+        if exact_bf16_parts(x.dtype) is None:
+            onehot = member.astype(x.dtype) * wi[:, None]
+            sums = sums + jnp.matmul(onehot.T, xi, precision=DEFAULT_PRECISION)
+            counts = counts + jnp.sum(onehot, axis=0)
+        else:
+            sums = sums + _onehot_sums(member, xi * wi[:, None])
+            counts = counts + jnp.sum(jnp.where(member, wi[:, None], 0.0), axis=0)
         cost = cost + jnp.sum(dists * wi)
         return (sums, counts, cost), None
 

@@ -1465,6 +1465,9 @@ class SparkKMeans(_HasDistribution, KMeans):
                                 )
                             )
             max_iter, tol = self.getMaxIter(), self.getTol()
+            # the loop's carry is the shard's dtype (a float32 shard under
+            # x64 would otherwise meet float64 centres from the host)
+            centers = np.asarray(centers, dtype=ing.xs.dtype)
             if ckpt is not None:
                 # chunked whole-loop Lloyd: checkpoint_every iterations per
                 # cached XLA program, durable centers between chunks (the
@@ -1493,6 +1496,10 @@ class SparkKMeans(_HasDistribution, KMeans):
                     )
                     c, cost, done = np.asarray(c), float(cost), int(done)
             REGISTRY.counter_inc("kmeans.iterations", done, path="mesh-local")
+            if KM.exact_bf16_parts(ing.xs.dtype):
+                REGISTRY.counter_inc(
+                    "kmeans.split_iterations", done, path="mesh-local"
+                )
             model = SparkKMeansModel(
                 uid=self.uid, clusterCenters=c, trainingCost=cost
             )

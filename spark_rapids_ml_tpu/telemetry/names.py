@@ -81,6 +81,10 @@ METRICS: frozenset[str] = frozenset({
     # Lloyd iterations a fit's program ran, by path (a loop that met its
     # tolerance or a fixed point runs fewer than maxIter)
     "kmeans.iterations",
+    # those of them whose sums were the one-hot in bfloat16 against the
+    # exact bfloat16 parts of the rows (ops.kmeans.exact_bf16_parts says
+    # which dtypes have such parts: float32 rows), by path
+    "kmeans.split_iterations",
     # Newton iterations a logistic fit's program ran (binary or softmax), by
     # path (a loop that met its tolerance runs fewer than maxIter)
     "logreg.iterations",

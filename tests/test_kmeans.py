@@ -1,8 +1,10 @@
 """KMeans tests — kernel differentials vs NumPy/sklearn and estimator behavior."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 from sklearn.cluster import KMeans as SkKMeans
 
 from spark_rapids_ml_tpu.models.kmeans import KMeans, KMeansModel
@@ -64,6 +66,153 @@ class TestKernels:
         new = np.asarray(KM.update_centers(stats, old))
         np.testing.assert_allclose(new[0], [2.0, 2.0, 2.0])
         np.testing.assert_allclose(new[1], [1.0, 2.0, 3.0])  # untouched
+
+
+def _around_centres(rng, centres, rows, spread):
+    """``rows`` float32 rows, each a centre times (1 + spread·noise): its
+    centre is the nearest by a wide margin at any scale."""
+    owner = rng.integers(0, len(centres), size=rows)
+    noise = rng.standard_normal((rows, centres.shape[1]))
+    return (centres[owner] * (1.0 + spread * noise)).astype(np.float32)
+
+
+def _mask_weights(rng):
+    centres = rng.standard_normal((12, 16)) * 8
+    x = _around_centres(rng, centres, 896, 0.01)
+    w = np.ones(1024, np.float32)
+    w[896:] = 0.0  # pad rows: zeros of weight 0, as the resident shard ends
+    return np.concatenate([x, np.zeros((128, 16), np.float32)]), w, centres, 256
+
+
+def _instance_weights(rng):
+    centres = rng.standard_normal((12, 16)) * 8
+    # eighths: not 0 or 1, and their sums are exact in any order
+    w = (rng.integers(0, 40, size=1024) / 8.0).astype(np.float32)
+    return _around_centres(rng, centres, 1024, 0.01), w, centres, 256
+
+
+def _wide_range(rng):
+    """Rows whose mid and lo parts matter: centres thirty decades apart,
+    negative values, and rows at powers of two and one ulp off them."""
+    scale = 10.0 ** rng.uniform(-15, 15, size=(24, 1))
+    centres = rng.standard_normal((24, 16)) * scale
+    x = _around_centres(rng, centres, 1536, 1e-3)
+    pow2 = np.float32(2.0) ** rng.integers(-40, 40, size=(256, 16))
+    pow2 = pow2 * rng.choice(np.float32([-1, 1]), size=pow2.shape)
+    off = np.nextafter(pow2, rng.choice(np.float32([-np.inf, np.inf]), size=pow2.shape))
+    return np.concatenate([x, pow2, off]).astype(np.float32), np.ones(2048, np.float32), centres, 512
+
+
+def _padding_block(rng):
+    centres = rng.standard_normal((12, 16)) * 8
+    x = _around_centres(rng, centres, 1000, 0.01)
+    return x, np.ones(1000, np.float32), centres, 384  # 152 rows of padding
+
+
+def _scatter_add(labels, terms, k):
+    """Float64 sums of ``terms`` by label: the answer the kernel is held to."""
+    out = np.zeros((k,) + terms.shape[1:])
+    np.add.at(out, labels, terms.astype(np.float64))
+    return out
+
+
+def _ulps(got, want, scale):
+    """Largest error of an entry, in float32 ulps of the sum of its terms'
+    magnitudes (what a float32 accumulation is bounded by)."""
+    scale = np.maximum(scale, np.finfo(np.float32).tiny)
+    return float(np.max(np.abs(got - want) / (np.finfo(np.float32).eps * scale)))
+
+
+class TestTheSumsInThreeBf16Passes:
+    """PR 38: for float32 rows the sums are the one-hot in bfloat16 against
+    the three bfloat16 parts of the rows (``ops.kmeans.exact_bf16_parts``):
+    every term of the float32 product that is not zero, in half its passes."""
+
+    @pytest.mark.parametrize(
+        "case", [_mask_weights, _instance_weights, _wide_range, _padding_block]
+    )
+    def test_float32_sums_against_a_float64_scatter_add(self, rng, case):
+        x, w, centres, block_rows = case(rng)
+        c32 = jnp.asarray(centres, jnp.float32)
+        stats = KM.kmeans_stats(jnp.asarray(x), c32, jnp.asarray(w), block_rows=block_rows)
+        assert stats.sums.dtype == stats.counts.dtype == np.float32
+        labels = np.asarray(KM.assign_clusters(jnp.asarray(x), c32)[0])
+        k = len(centres)
+        terms = x.astype(np.float64) * w[:, None]
+        want, scale = _scatter_add(labels, terms, k), _scatter_add(labels, np.abs(terms), k)
+        # the float32 product the parent took, same blocks, same labels
+        onehot = (labels[:, None] == np.arange(k)).astype(np.float32) * w[:, None]
+        highest = np.zeros((k, x.shape[1]), np.float32)
+        for at in range(0, len(x), block_rows):
+            highest += np.asarray(jnp.matmul(
+                jnp.asarray(onehot[at:at + block_rows]).T,
+                jnp.asarray(x[at:at + block_rows]),
+                precision=KM.DEFAULT_PRECISION,
+            ))
+        err = _ulps(np.asarray(stats.sums, np.float64), want, scale)
+        err_highest = _ulps(highest.astype(np.float64), want, scale)
+        assert err < 2.0, (err, err_highest)
+        assert err <= max(err_highest, 1.0), (err, err_highest)
+        np.testing.assert_array_equal(
+            np.asarray(stats.counts), _scatter_add(labels, w, k).astype(np.float32)
+        )
+
+    @pytest.mark.parametrize("scale", [1e-30, 1e30])
+    def test_a_scaled_block_through_the_product_alone(self, rng, scale):
+        """Rows no distance can be taken of in float32 (their squares leave
+        its range) still sum exactly: the product itself, labels given."""
+        x = (rng.uniform(0.5, 2.0, size=(1024, 16)) * scale).astype(np.float32)
+        x *= rng.choice(np.float32([-1, 1]), size=x.shape)
+        labels = rng.integers(0, 10, size=1024)
+        member = jnp.asarray(labels[:, None] == np.arange(10))
+        got = jax.jit(KM._onehot_sums)(member, jnp.asarray(x))
+        want, bound = _scatter_add(labels, x, 10), _scatter_add(labels, np.abs(x), 10)
+        assert _ulps(np.asarray(got, np.float64), want, bound) < 2.0
+
+    def test_the_parts_sum_back_to_the_float32_input_bitwise(self, rng):
+        assert KM.exact_bf16_parts(np.float32) == 3
+        assert KM.exact_bf16_parts(np.float64) is None
+        assert KM.exact_bf16_parts(jnp.bfloat16) is None
+        near_one = rng.uniform(0.5, 2.0, size=(512, 16)) * rng.choice([-1.0, 1.0], size=(512, 16))
+        v = np.concatenate([
+            _wide_range(rng)[0], near_one * 1e-30, near_one * 1e30,
+            rng.standard_normal((512, 16)),
+        ]).astype(np.float32)
+        parts = jax.jit(KM.split_bf16)(jnp.asarray(v))
+        assert [p.dtype for p in parts] == [jnp.bfloat16] * 3
+        hi, mid, lo = (np.asarray(p.astype(jnp.float32)) for p in parts)
+        assert ((lo + mid) + hi).tobytes() == v.tobytes()
+        assert np.abs(mid).max() > 0 and np.abs(lo).max() > 0
+
+    def test_float64_rows_read_bitwise_what_they_read(self, rng):
+        """The rule follows the dtype: float64 rows (the tests' x64) take
+        the product as it was written, to the bit."""
+        x = rng.standard_normal((500, 6))
+        c = rng.standard_normal((7, 6))
+        w = rng.uniform(0.0, 2.0, size=500)
+        got = KM.kmeans_stats(jnp.asarray(x), jnp.asarray(c), jnp.asarray(w), block_rows=128)
+        assert got.sums.dtype == np.float64
+
+        @jax.jit
+        def parent(x, centers, weights):
+            xb = jnp.pad(x, ((0, 12), (0, 0))).reshape(4, 128, 6)
+            wb = jnp.pad(weights, (0, 12)).reshape(4, 128)
+
+            def step(carry, blk):
+                sums, counts, cost = carry
+                xi, wi = blk
+                labels, dists = KM.assign_clusters(xi, centers)
+                onehot = (
+                    labels[:, None] == jnp.arange(7, dtype=labels.dtype)[None, :]
+                ).astype(x.dtype) * wi[:, None]
+                sums = sums + jnp.matmul(onehot.T, xi, precision=KM.DEFAULT_PRECISION)
+                return (sums, counts + jnp.sum(onehot, axis=0), cost + jnp.sum(dists * wi)), None
+
+            init = (jnp.zeros((7, 6), x.dtype), jnp.zeros((7,), x.dtype), jnp.zeros((), x.dtype))
+            return lax.scan(step, init, (xb, wb))[0]
+
+        for a, b in zip(got, parent(jnp.asarray(x), jnp.asarray(c), jnp.asarray(w))):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 class TestEstimator:

@@ -163,6 +163,29 @@ def test_a_loop_that_reaches_its_tolerance_counts_fewer_iterations(session):
     assert 1 <= ran < 50
 
 
+@pytest.mark.parametrize("wire,split", [("float32", MAX_ITER), ("float64", 0)])
+def test_the_counter_of_split_iterations_follows_the_shards_dtype(
+    session, monkeypatch, wire, split
+):
+    """``kmeans.split_iterations``: the iterations whose sums took the three
+    bfloat16 parts of float32 rows (``ops.kmeans.exact_bf16_parts``); a
+    float64 shard takes the product as written and counts none. Both agree
+    with float64 Lloyd from the same centres to their dtype's rounding."""
+    monkeypatch.setenv(ingest.WIRE_DTYPE_VAR, wire)
+    blocks, order = blocks_of(1500)
+    df = session.createDataFrame(data.to_table(blocks, order))
+    before = REGISTRY.snapshot()
+    model = estimator(initMode="random").fit(df)
+    moved = REGISTRY.snapshot().delta(before)
+    assert moved.counter("kmeans.iterations", path="mesh-local") == MAX_ITER
+    assert moved.counter("kmeans.split_iterations", path="mesh-local") == split
+    centres0 = np.asarray(estimator(initMode="random", maxIter=0).fit(df).clusterCenters)
+    ref = reference_kmeans.lloyd(blocks, order, centres0, MAX_ITER)
+    read = reference_kmeans.compare(model.clusterCenters, model.trainingCost, ref)
+    limit = 1e-5 if split else 1e-9
+    assert read["center_gap"] < limit and read["cost_gap"] < limit, read
+
+
 def test_the_programs_keep_the_names_the_benchmark_reads():
     """benchmarks/layer_metrics/lloyd_roofline.json finds the Lloyd loop in
     the device trace by its module name, ``jit__lloyd``, and the seeding must

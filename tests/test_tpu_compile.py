@@ -8,13 +8,16 @@ at a time may load the TPU's library, and every xdist worker imports every
 test file. Keep such compiles in this one file.
 """
 
+import functools
 import os
+import re
 
 import jax
 import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from spark_rapids_ml_tpu.parallel import kmeans as PK
 from spark_rapids_ml_tpu.parallel import linear as PL
 from spark_rapids_ml_tpu.parallel import mesh as M
 
@@ -41,7 +44,17 @@ def topo():
         os.environ.pop("TPU_LOG_DIR", None)
 
 
-def test_the_newton_program_holds_the_rows_once_at_the_cells_size(topo):
+@pytest.fixture
+def one_chip_mesh(topo):
+    return Mesh(np.array(topo.devices[:1]).reshape(1, 1), (M.DATA_AXIS, M.FEAT_AXIS))
+
+
+def on_mesh(mesh, shape, spec, dtype=np.float32):
+    """A shape with its sharding: there is no device to hold an array."""
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+
+def test_the_newton_program_holds_the_rows_once_at_the_cells_size(one_chip_mesh):
     """``logreg3000_fit_resident``: one chip's 524,288 padded rows of 3,001
     float32. Two findings of PR 34, held here at no chip time:
 
@@ -56,14 +69,11 @@ def test_the_newton_program_holds_the_rows_once_at_the_cells_size(topo):
       temporaries. PERF.md section 6.)
     """
     rows, d = 524_288, 3_001
-    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), (M.DATA_AXIS, M.FEAT_AXIS))
     newton = PL.make_distributed_logreg_chunk(
-        mesh, reg_param=1e-5, elastic_net_param=0.0, fit_intercept=True,
+        one_chip_mesh, reg_param=1e-5, elastic_net_param=0.0, fit_intercept=True,
         chunk_iters=8, tol=0.0,
     )
-
-    def arg(shape, spec, dtype=np.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+    arg = functools.partial(on_mesh, one_chip_mesh)
 
     compiled = newton.lower(
         arg((rows, d), P(M.DATA_AXIS, None)), arg((rows,), P(M.DATA_AXIS)),
@@ -123,3 +133,36 @@ def test_a_share_of_the_device_chunk_is_made_where_it_stays(topo):
         memory = compiled.memory_analysis()
         assert memory.argument_size_in_bytes == 0 and memory.temp_size_in_bytes == 0
         assert 4 * rows * (n + 1) <= memory.output_size_in_bytes < 4 * rows * (n + 1) + 4096
+
+
+def test_the_lloyd_sums_take_bf16_passes_at_the_cells_size(one_chip_mesh):
+    """``kmeans128_fit_resident``: one chip's 6,291,456 padded rows of 128
+    float32, k = 1,000, the whole loop of 20 iterations (PR 38). The sums'
+    product is the one-hot in bfloat16 against the three bfloat16 parts of
+    the rows (``ops.kmeans.exact_bf16_parts``), so of the loop's two
+    products only the distances' cross term keeps ``highest``; the parts
+    are cut inside the fusion, block by block, and never written (cut
+    before the scan they would be 4.83 GB of temporaries)."""
+    rows, n, k = 6_291_456, 128, 1_000
+    lloyd = PK.make_distributed_kmeans_chunk(one_chip_mesh, chunk_iters=20, tol=0.0)
+    arg = functools.partial(on_mesh, one_chip_mesh)
+
+    compiled = lloyd.lower(
+        arg((rows, n), P(M.DATA_AXIS, None)), arg((rows,), P(M.DATA_AXIS)),
+        arg((k, n), P()), arg((), P(), np.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__lloyd")
+    products = [ln for ln in text.splitlines() if re.search(r"= \S+ convolution\(", ln)]
+    at_highest = [ln for ln in products if "operand_precision={highest,highest}" in ln]
+    assert len(at_highest) == 1 and f"f32[8192,{k}]" in at_highest[0]
+    (sums,) = [ln for ln in products if ln not in at_highest]
+    assert f"f32[{k},{3 * n}]" in sums  # the three parts side by side, one product
+    operands = re.search(r"convolution\((%[\w.-]+), (%[\w.-]+)\)", sums).groups()
+    dtypes = {re.search(rf"{re.escape(o)} = (\w+)\[", text).group(1) for o in operands}
+    # the one-hot goes in as the comparison itself, narrower still
+    assert "bf16" in dtypes and dtypes <= {"bf16", "pred"}
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64e6
+    shard = 4 * rows * (n + 1) + 4 * k * n
+    assert shard <= memory.argument_size_in_bytes < shard + 4096
