@@ -46,6 +46,9 @@ from spark_rapids_ml_tpu.telemetry import trace_range
 #: rows sampled (not streamed) for quantile bin-edge estimation — the same
 #: bounded-sample role Spark's findSplits sampling plays
 _MAX_BIN_SAMPLE = 200_000
+#: the fewest rows a resident fit's edge sample takes: Spark's findSplits
+#: samples max(maxBins², 10,000) of them
+_MIN_EDGE_SAMPLE = 10_000
 
 
 def subset_size(strategy: str, n_features: int, *, classification: bool) -> int:
@@ -93,8 +96,22 @@ def quantile_bin_edges(
     if x.shape[0] > _MAX_BIN_SAMPLE:
         rng = np.random.default_rng(seed)
         x = x[rng.choice(x.shape[0], _MAX_BIN_SAMPLE, replace=False)]
-    qs = np.linspace(0.0, 1.0, n_bins + 1)[1:-1]
-    return np.quantile(x, qs, axis=0).T.astype(np.float64)
+    return np.quantile(x, quantile_levels(n_bins), axis=0).T.astype(np.float64)
+
+
+def edge_sample_ids(seed: int, rows: int, n_bins: int) -> np.ndarray:
+    """Sorted ids of the rows whose quantiles make a resident fit's bin
+    edges: Spark's findSplits sample size, max(maxBins², 10,000), drawn
+    without replacement from the seed (all rows where there are fewer)."""
+    m = max(n_bins * n_bins, _MIN_EDGE_SAMPLE)
+    if m >= rows:
+        return np.arange(rows)
+    return np.sort(np.random.default_rng(seed).choice(rows, m, replace=False))
+
+
+def quantile_levels(n_bins: int) -> np.ndarray:
+    """The interior quantile levels of ``n_bins`` bins, in float64."""
+    return np.linspace(0.0, 1.0, n_bins + 1)[1:-1]
 
 
 def tree_feature_importances(
@@ -245,75 +262,125 @@ class _ForestEstimator(_ForestParams, Estimator):
     def getImpurity(self) -> str:
         return self.getOrDefault("impurity")
 
-    def _make_model(self, x, y, w, builder=None):
+    def _make_model(self, x, y, w):
         """THE fit-then-wrap handoff — one copy for every tree estimator;
         subclasses choose the model class via ``_model_cls``."""
-        trees, thresholds = self._fit_arrays(x, y, w, builder=builder)
+        return self._wrap_arrays(*self._fit_arrays(x, y, w))
+
+    def _wrap_arrays(self, trees, thresholds):
         model = self._model_cls(
             uid=self.uid, trees=trees, thresholds=thresholds,
             numFeatures=self._n_features_in,
         )
         return self._copyValues(model)
 
-    def _fit_arrays(
-        self,
-        x: np.ndarray,
-        y: np.ndarray,
-        w: np.ndarray | None,
-        builder=None,
-    ):
-        """(trees, thresholds) — the shared fit body. ``builder`` overrides
-        the single-device :func:`ops.forest.build_forest` (same signature +
-        the static kwargs) so the Spark wrapper can route the build through
-        the mesh-sharded program (parallel/forest.py)."""
+    def _build_static(self, n_features: int) -> dict:
+        """The forest program's static arguments for ``n_features``."""
+        return dict(
+            max_depth=self.getMaxDepth(),
+            n_bins=self.getMaxBins(),
+            k_features=subset_size(
+                self.getOrDefault("featureSubsetStrategy"),
+                n_features,
+                classification=self._classification,
+            ),
+            impurity=self.getImpurity(),
+        )
+
+    def _fit_arrays(self, x: np.ndarray, y: np.ndarray, w: np.ndarray | None):
+        """(trees, thresholds) — the host fit body: edges and bins on the
+        host, the forest program on the default device."""
         n_bins = self.getMaxBins()
         seed = self.getSeed()
-        n_trees = self.getNumTrees()
-        max_depth = self.getMaxDepth()
         fdt = columnar.float_dtype_for(x.dtype)
 
         edges = quantile_bin_edges(x, n_bins, seed, w)
-        binned = bin_features(x, edges)
+        binned = bin_features(x, edges).astype(FO.bins_dtype(n_bins))
         row_stats = self._row_stats(y, fdt)
 
-        rng = np.random.default_rng(seed)
-        base_w = np.ones(len(x), fdt) if w is None else w.astype(fdt)
-        rate = self.getOrDefault("subsamplingRate")
-        if self.getOrDefault("bootstrap"):
-            weights = rng.poisson(rate, size=(n_trees, len(x))).astype(fdt)
-        elif rate < 1.0:
-            # Spark bootstrap=False subsampling is WITHOUT replacement:
-            # Bernoulli(rate) per row per tree (BaggedPoint semantics)
-            weights = (
-                rng.random(size=(n_trees, len(x))) < rate
-            ).astype(fdt)
-        else:
-            weights = np.ones((n_trees, len(x)), fdt)
-        weights *= base_w[None, :]
+        weights = np.asarray(FO.bootstrap_weights(
+            seed, self.getNumTrees(), len(x),
+            bootstrap=self.getOrDefault("bootstrap"),
+            rate=self.getOrDefault("subsamplingRate"),
+        )).astype(fdt)
+        if w is not None:
+            weights *= w.astype(fdt)[None, :]
 
-        k_feat = subset_size(
-            self.getOrDefault("featureSubsetStrategy"),
-            x.shape[1],
-            classification=self._classification,
-        )
-        keys = jax.random.split(jax.random.PRNGKey(seed), n_trees)
-        build = FO.build_forest if builder is None else builder
+        keys = jax.random.split(jax.random.PRNGKey(seed), self.getNumTrees())
         with trace_range("forest build"):
-            trees = build(
+            trees = FO.build_forest(
                 keys,
                 jnp.asarray(binned),
                 jnp.asarray(row_stats),
                 jnp.asarray(weights),
                 jnp.asarray(np.asarray(self.getOrDefault("minInstancesPerNode"), fdt)),
                 jnp.asarray(np.asarray(self.getOrDefault("minInfoGain"), fdt)),
-                max_depth=max_depth,
-                n_bins=n_bins,
-                k_features=k_feat,
-                impurity=self.getImpurity(),
+                **self._build_static(x.shape[1]),
             )
         self._n_features_in = x.shape[1]
         trees = FO.TreeArrays(*(np.asarray(a) for a in trees))
         return trees, split_thresholds(trees, edges)
+
+    def _fit_resident(self, ing) -> tuple[FO.TreeArrays, np.ndarray]:
+        """(trees, thresholds) from rows held on the device mesh (a
+        ``spark.ingest.MeshIngest`` with labels and pad-mask weights): the
+        edges are quantiles of a seeded sample of the resident rows
+        (:func:`edge_sample_ids`), the rows are binned where they lie, the
+        trees' sample counts are drawn there, and the trees come back to the
+        host once. The rows themselves are let go once they are binned.
+
+        Spans ``forest.bin`` (edges, bins and the wait for them) and
+        ``forest build`` (the program's dispatch and the wait for its trees
+        on the host); counters ``forest.trees`` and ``forest.split_nodes``
+        (``path="mesh-local"``), booked from the trees handed back."""
+        from spark_rapids_ml_tpu.parallel import forest as PF
+        from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
+        from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
+
+        mesh, xs = ing.mesh, ing.xs
+        n_bins, seed, n_trees = self.getMaxBins(), self.getSeed(), self.getNumTrees()
+        n = xs.shape[1]
+        fdt = xs.dtype
+        with trace_range("forest.bin"):
+            ids = jnp.asarray(edge_sample_ids(seed, ing.rows, n_bins))
+            edges = PF.quantile_edges(
+                xs[ids], ing.ws[ids], jnp.asarray(quantile_levels(n_bins), fdt)
+            )
+            binned = PF.make_sharded_binner(mesh, n_bins)(xs, edges)
+            row_stats = self._device_row_stats(ing.ys)
+            jax.block_until_ready((binned, row_stats))
+        ing.xs = xs = None  # the rows are their bins from here on
+
+        static = self._build_static(n)
+        shards = mesh.shape[DATA_AXIS]
+        with trace_range("forest build"):
+            weights = PF.make_sharded_weights(
+                mesh, seed=seed, n_trees=n_trees, rows=ing.rows,
+                bootstrap=bool(self.getOrDefault("bootstrap")),
+                rate=float(self.getOrDefault("subsamplingRate")),
+            )(ing.ws)
+            capacity = FO.row_capacity(weights, shards)
+            group = FO.tree_group(
+                capacity, n, static["k_features"], n_bins, row_stats.shape[1],
+                static["max_depth"], n_trees, device=mesh.devices.flat[0],
+            )
+            run = PF.make_sharded_forest(
+                mesh, group=group, capacity=capacity, **static
+            )
+            trees = run(
+                jax.random.split(jax.random.PRNGKey(seed), n_trees),
+                binned, row_stats, weights,
+                jnp.asarray(self.getOrDefault("minInstancesPerNode"), fdt),
+                jnp.asarray(self.getOrDefault("minInfoGain"), fdt),
+            )
+            trees = FO.TreeArrays(*(np.asarray(a) for a in trees))
+        REGISTRY.counter_inc("forest.trees", n_trees, path="mesh-local")
+        REGISTRY.counter_inc(
+            "forest.split_nodes", int(np.sum(trees.feature >= 0)),
+            path="mesh-local",
+        )
+        self._n_features_in = n
+        return trees, split_thresholds(trees, np.asarray(edges, np.float64))
 
     def fit(self, dataset: Any, num_partitions: int | None = None):
         parts = columnar.labeled_partitions(
@@ -456,6 +523,21 @@ class RandomForestClassifier(_ClassifierCols, _ForestEstimator):
             )
         return np.eye(int(classes.max()) + 1, dtype=fdt)[classes]
 
+    def _device_row_stats(self, ys: jax.Array) -> jax.Array:
+        """[rows, C] one-hot class rows of device labels, with the host
+        path's label contract checked on the device (pad rows hold 0)."""
+        from spark_rapids_ml_tpu.parallel import forest as PF
+
+        low, high, fractional = (
+            float(v) for v in jax.device_get(PF.label_facts(ys))
+        )
+        if low < 0 or fractional:
+            raise ValueError(
+                "classification labels must be non-negative integers "
+                "(Spark ML label contract)"
+            )
+        return PF.one_hot(ys, int(high) + 1)
+
     @property
     def _model_cls(self):
         return RandomForestClassificationModel
@@ -520,6 +602,12 @@ class RandomForestRegressor(_ForestEstimator):
     def _row_stats(self, y: np.ndarray, fdt) -> np.ndarray:
         y = y.astype(fdt)
         return np.stack([np.ones_like(y), y, y * y], axis=1)
+
+    def _device_row_stats(self, ys: jax.Array) -> jax.Array:
+        """[rows, 3] regression stats [1, y, y²] of device labels."""
+        from spark_rapids_ml_tpu.parallel import forest as PF
+
+        return PF.moments(ys)
 
     @property
     def _model_cls(self):

@@ -2613,10 +2613,12 @@ class SparkRandomForestClassifier(_HasDistribution, RandomForestClassifier):
     """RandomForestClassifier over pyspark DataFrames.
 
     ``driver-merge`` collects (features, label, weight) through the
-    memory-bounded chunker and builds on the driver's default device;
-    ``mesh-local`` routes the SAME build through the mesh-sharded program
-    (rows sharded, one histogram psum per level, parallel/forest.py) on the
-    driver's device mesh — bit-identical trees."""
+    memory-bounded chunker, bins on the host and builds on the driver's
+    default device; ``mesh-local`` streams the rows onto the driver's
+    device mesh and fits them where they lie (:func:`_fit_forest_resident`):
+    edges, bins, bootstrap and the forest program (rows sharded, one
+    histogram psum per level, parallel/forest.py) all on the device. Given
+    the same edges, the two grow the same trees."""
 
     _ALLOWED_DISTRIBUTIONS = ("driver-merge", "mesh-local")
 
@@ -2624,18 +2626,15 @@ class SparkRandomForestClassifier(_HasDistribution, RandomForestClassifier):
         if not _is_spark_df(dataset):
             core = super().fit(dataset, num_partitions)
             return self._wrap(core)
+        if self.getOrDefault("distribution") == "mesh-local":
+            return self._wrap(_fit_forest_resident(self, dataset))
         x, y, w = _collect_xyw(
             dataset,
             self.getOrDefault("featuresCol"),
             label_col=self.getOrDefault("labelCol"),
             weight_col=self._paramMap.get("weightCol"),
         )
-        builder = (
-            _mesh_forest_builder()
-            if self.getOrDefault("distribution") == "mesh-local"
-            else None
-        )
-        return self._wrap(self._make_model(x, y, w, builder=builder))
+        return self._wrap(self._make_model(x, y, w))
 
     def _wrap(self, core):
         model = SparkRandomForestClassificationModel(
@@ -2671,18 +2670,15 @@ class SparkRandomForestRegressor(_HasDistribution, RandomForestRegressor):
         if not _is_spark_df(dataset):
             core = super().fit(dataset, num_partitions)
             return self._wrap(core)
+        if self.getOrDefault("distribution") == "mesh-local":
+            return self._wrap(_fit_forest_resident(self, dataset))
         x, y, w = _collect_xyw(
             dataset,
             self.getOrDefault("featuresCol"),
             label_col=self.getOrDefault("labelCol"),
             weight_col=self._paramMap.get("weightCol"),
         )
-        builder = (
-            _mesh_forest_builder()
-            if self.getOrDefault("distribution") == "mesh-local"
-            else None
-        )
-        return self._wrap(self._make_model(x, y, w, builder=builder))
+        return self._wrap(self._make_model(x, y, w))
 
     def _wrap(self, core):
         model = SparkRandomForestRegressionModel(
@@ -2702,36 +2698,28 @@ class SparkRandomForestRegressionModel(RandomForestRegressionModel):
         )
 
 
-def _mesh_forest_builder():
-    """A drop-in for ops.forest.build_forest that routes the build through
-    the mesh-sharded program on THIS process's device mesh: rows padded to
-    an equal-shard multiple (pad weight 0 — histogram-invisible), one
-    psum per level. Bit-identical trees to the local build."""
-    import jax
-    import jax.numpy as jnp
+def _fit_forest_resident(est, dataset):
+    """'mesh-local' for the forest wrappers: the rows, the label and the
+    pad-mask weights stream onto the driver's device mesh
+    (``spark.ingest.stream_to_mesh``, as the logistic fit's do) and the
+    forest is fitted where they lie (``models.forest._fit_resident``); the
+    host never holds the table as one matrix. Span ``forest mesh-local fit``
+    covers the whole fit."""
+    from spark_rapids_ml_tpu.spark import ingest
 
-    from spark_rapids_ml_tpu.parallel.forest import make_sharded_forest
-    from spark_rapids_ml_tpu.parallel.mesh import create_mesh
-
-    def build(keys, binned, row_stats, weights, min_inst, min_gain, **static):
-        ndev = len(jax.devices())
-        if ndev <= 1:
-            from spark_rapids_ml_tpu.ops.forest import build_forest
-
-            return build_forest(
-                keys, binned, row_stats, weights, min_inst, min_gain, **static
-            )
-        rows = binned.shape[0]
-        per = -(-rows // ndev)
-        pad = per * ndev - rows
-        if pad:
-            binned = jnp.pad(binned, ((0, pad), (0, 0)))
-            row_stats = jnp.pad(row_stats, ((0, pad), (0, 0)))
-            weights = jnp.pad(weights, ((0, 0), (0, pad)))
-        run = make_sharded_forest(create_mesh(data=ndev), **static)
-        return run(keys, binned, row_stats, weights, min_inst, min_gain)
-
-    return build
+    feats = est.getOrDefault("featuresCol")
+    label = est.getOrDefault("labelCol")
+    weight_col = est._paramMap.get("weightCol")
+    cols = [feats, label] + ([weight_col] if weight_col else [])
+    with trace_range("forest mesh-local fit"):
+        ing = ingest.stream_to_mesh(
+            dataset.select(*cols), features_col=feats,
+            n=_infer_n(dataset, feats), label_col=label,
+            weight_col=weight_col, with_weights=True,
+        )
+        if weight_col and float(ing.ws.sum()) == 0.0:
+            raise ValueError("all instance weights are zero")
+        return est._wrap_arrays(*est._fit_resident(ing))
 
 
 class SparkLinearSVC(_HasDistribution, LinearSVC):

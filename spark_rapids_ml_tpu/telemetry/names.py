@@ -88,6 +88,11 @@ METRICS: frozenset[str] = frozenset({
     # Newton iterations a logistic fit's program ran (binary or softmax), by
     # path (a loop that met its tolerance runs fewer than maxIter)
     "logreg.iterations",
+    # trees a forest fit grew, and the split nodes among them, booked from
+    # the trees handed back to the host, by path (a shallow build shows as
+    # fewer split nodes for the same trees)
+    "forest.trees",
+    "forest.split_nodes",
     # spans: duration, and duration less what child spans covered
     "span.seconds",
     "span.self_seconds",
@@ -353,6 +358,10 @@ SPAN_PHASES: frozenset[str] = frozenset({
     "dbscan spark cluster",
     # trees / ensembles / misc models
     "forest build",
+    # a resident forest fit (SparkRandomForest*, mesh-local): the whole fit,
+    # and its bin edges and bins made on the device with the wait for them
+    "forest mesh-local fit",
+    "forest.bin",
     "gbt boost",
     "fm train",
     "mlp train",

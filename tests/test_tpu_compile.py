@@ -166,3 +166,33 @@ def test_the_lloyd_sums_take_bf16_passes_at_the_cells_size(one_chip_mesh):
     assert memory.temp_size_in_bytes < 64e6
     shard = 4 * rows * (n + 1) + 4 * k * n
     assert shard <= memory.argument_size_in_bytes < shard + 4096
+
+
+def test_the_forest_program_fits_beside_the_rows_at_the_cells_size(one_chip_mesh):
+    """``rf3000_fit_resident``: one chip's 524,288 padded rows binned into
+    3,000 bytes each, 128 bins, depth 13, 55 features a node, the cell's 5
+    trees one at a time over the 317,440 rows a tree keeps of its bootstrap
+    (``ops.forest.row_capacity`` of Poisson(1) counts over 500,000 rows). The
+    whole forest is one program, ``jit__forest``, and what it holds beside
+    its arguments fits the chip with the float32 rows still resident (the
+    fit lets them go before the build; this holds it to the stricter
+    budget)."""
+    from spark_rapids_ml_tpu.parallel import forest as PF
+
+    rows, n, n_bins, trees = 524_288, 3_000, 128, 5
+    run = PF.make_sharded_forest(
+        one_chip_mesh, max_depth=13, n_bins=n_bins, k_features=55,
+        impurity="gini", group=1, capacity=317_440,
+    )
+    arg = functools.partial(on_mesh, one_chip_mesh)
+    compiled = run.lower(
+        arg((trees, 2), P(), np.uint32), arg((rows, n), P(M.DATA_AXIS, None), np.uint8),
+        arg((rows, 2), P(M.DATA_AXIS, None)), arg((trees, rows), P(None, M.DATA_AXIS)),
+        arg((), P()), arg((), P()),
+    ).compile()
+    assert compiled.as_text().startswith("HloModule jit__forest")
+    memory = compiled.memory_analysis()
+    bins = rows * n  # a byte a bin: 1.57 GB where int32 bins were 6.29
+    assert bins <= memory.argument_size_in_bytes < bins + 32e6
+    float_rows = 4 * rows * n
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + float_rows < 15.75 * 2**30
