@@ -331,8 +331,10 @@ class _ForestEstimator(_ForestParams, Estimator):
 
         Spans ``forest.bin`` (edges, bins and the wait for them) and
         ``forest build`` (the program's dispatch and the wait for its trees
-        on the host); counters ``forest.trees`` and ``forest.split_nodes``
-        (``path="mesh-local"``), booked from the trees handed back."""
+        on the host); counters ``forest.trees``, ``forest.split_nodes`` and
+        ``forest.piece_select_levels`` (the trees' split levels whose
+        selection took ``ops.forest._piece_bins``), ``path="mesh-local"``,
+        booked from the trees handed back."""
         from spark_rapids_ml_tpu.parallel import forest as PF
         from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
         from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
@@ -377,6 +379,13 @@ class _ForestEstimator(_ForestParams, Estimator):
         REGISTRY.counter_inc("forest.trees", n_trees, path="mesh-local")
         REGISTRY.counter_inc(
             "forest.split_nodes", int(np.sum(trees.feature >= 0)),
+            path="mesh-local",
+        )
+        REGISTRY.counter_inc(
+            "forest.piece_select_levels",
+            len(trees.feature) * FO.piece_select_levels(
+                n, static["k_features"], n_bins, static["max_depth"]
+            ),
             path="mesh-local",
         )
         self._n_features_in = n

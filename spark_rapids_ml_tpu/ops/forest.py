@@ -60,12 +60,26 @@ _GAIN_ULPS = 32
 _TILE_ROWS = 128
 #: rows a block of the subset selection (:func:`_subset_bins`) compares at once
 _SELECT_BLOCK_ROWS = 1024
+#: pieces whose bins one step of :func:`_piece_bins` takes on the matrix unit
+_SELECT_BLOCK_PIECES = 64
 #: levels of at most this many nodes select their rows' bins on the matrix
 #: unit (:func:`_subset_bins_dense`): its work grows with the nodes
 _DENSE_SELECT_NODES = 8
 #: a tree's kept rows are a multiple of this (:func:`row_capacity`), so that
 #: a tree's shape changes with its weights only by whole blocks
 _CAPACITY_STEP = 1024
+
+
+class _Pieces(NamedTuple):
+    """A level's rows sorted by node and cut in pieces (:func:`_level_pieces`):
+    a piece is one node's run of sorted rows inside one tile of
+    :data:`_TILE_ROWS`."""
+
+    order: jax.Array | None  # [rows] the rows sorted by node, stably (None: as they are)
+    start: jax.Array  # [pieces] each piece's first sorted row
+    end: jax.Array  # [pieces] one past its last
+    tile: jax.Array  # [pieces] the tile it lies in
+    node: jax.Array  # [pieces] its node, ascending
 
 
 class TreeArrays(NamedTuple):
@@ -145,7 +159,8 @@ def _subset_bins(binned: jax.Array, sub_rows: jax.Array) -> jax.Array:
     rows at a time (sliced where the rows lie: a reshape would copy them).
     On a TPU this runs on the vector unit five times faster than the gather
     it stands for (79 ms against 404 at 524,288 rows of 3,000 bytes and 55
-    slots: PERF.md section 6)."""
+    slots: PERF.md section 6); the selection of bins over 256, which one
+    bfloat16 pass does not hold (:func:`_piece_bins` takes those under)."""
     rows, n_feat = binned.shape
     k = sub_rows.shape[1]
     block = math.gcd(rows, _SELECT_BLOCK_ROWS)
@@ -184,6 +199,127 @@ def _subset_bins_dense(
     return jnp.sum(jnp.where(mine, every, 0.0), axis=1).astype(jnp.int32)
 
 
+def _selects_by_pieces(nodes: int, n_feat: int, k: int, n_bins: int) -> bool:
+    """Whether a level of ``nodes`` nodes selects its rows' ``k`` bins of
+    ``n_feat`` by :func:`_piece_bins`: bins under 256, which one bfloat16
+    pass holds exactly, a subset to select, and more nodes than the dense
+    product (:func:`_subset_bins_dense`) is quicker for."""
+    return k < n_feat and n_bins <= 256 and nodes > _DENSE_SELECT_NODES
+
+
+def piece_select_levels(n_feat: int, k_features: int, n_bins: int, max_depth: int) -> int:
+    """The split levels of a tree whose selection takes :func:`_piece_bins`."""
+    k = min(k_features, n_feat)
+    return sum(_selects_by_pieces(2 ** d, n_feat, k, n_bins) for d in range(max_depth))
+
+
+def _tiles(rows: int) -> tuple[int, int]:
+    """(padded rows, tiles): ``rows`` cut in tiles of :data:`_TILE_ROWS`."""
+    padded = -(-rows // _TILE_ROWS) * _TILE_ROWS
+    return padded, padded // _TILE_ROWS
+
+
+def _level_pieces(local: jax.Array, nodes: int) -> _Pieces:
+    """A level's rows sorted by node and cut in tiles of :data:`_TILE_ROWS`;
+    the node boundaries cut the tiles again into at most tiles + nodes
+    pieces, each a run of one node's rows inside one tile. Computed once a
+    level: the selection (:func:`_piece_bins`) and the histogram
+    (:func:`_level_hist`) both read it."""
+    rows = local.shape[0]
+    R = _TILE_ROWS
+    padded, tiles = _tiles(rows)
+    # one node's rows are in order already, and are not moved; the sort
+    # carries the nodes with it, where a gather of them takes 2 ms on a v5e
+    order, sorted_local = None, local
+    if nodes > 1:
+        sorted_local, order = lax.sort_key_val(
+            local, jnp.arange(rows, dtype=jnp.int32), is_stable=True
+        )
+    node_of = jnp.pad(sorted_local, (0, padded - rows), constant_values=nodes - 1)
+    node_starts = jnp.searchsorted(
+        node_of, jnp.arange(nodes, dtype=node_of.dtype), side="left"
+    )
+    start = jnp.sort(
+        jnp.concatenate([jnp.arange(tiles, dtype=node_starts.dtype) * R, node_starts])
+    )
+    end = jnp.concatenate([start[1:], jnp.full((1,), padded, start.dtype)])
+    tile = jnp.minimum(start // R, tiles - 1)
+    node = node_of[jnp.minimum(start, padded - 1)]
+    return _Pieces(order, start, end, tile, node)
+
+
+def _sorted_tiles(x: jax.Array, pieces: _Pieces) -> jax.Array:
+    """[tiles, R, ...] the rows of ``x`` in the level's sorted order, padded
+    with zeros to whole tiles."""
+    padded, tiles = _tiles(x.shape[0])
+    pad = ((0, padded - x.shape[0]),) + ((0, 0),) * (x.ndim - 1)
+    rows = x if pieces.order is None else x[pieces.order]
+    return jnp.pad(rows, pad).reshape(tiles, _TILE_ROWS, *x.shape[1:])
+
+
+def _pieces_of(row_bins: jax.Array, pieces: _Pieces) -> jax.Array:
+    """[pieces, R, k] per-row bins laid out as :func:`_level_hist` reads
+    them: each piece's tile of the sorted rows."""
+    return _sorted_tiles(row_bins, pieces)[pieces.tile]
+
+
+def _byte_words(binned: jax.Array) -> jax.Array:
+    """[rows, W] uint32: a row's bins under 256 four to a word, W a whole
+    number of 128 lanes: byte q of word c holds feature q·W + c (zeros past
+    the last feature), so the words are four slices of the row side by side
+    and unpack into the row as it was. A TPU moves a row of words as whole
+    lanes where it unpacks a row of bytes: 13 against 22 ms for 317,440
+    rows of 3,000 on a v5e (PERF.md section 6), and a level moves its rows
+    once."""
+    rows, n_feat = binned.shape
+    width = -(-n_feat // (4 * 128)) * 128
+    words = jnp.zeros((rows, width), jnp.uint32)
+    for q in range(-(-n_feat // width)):
+        part = binned[:, q * width:(q + 1) * width].astype(jnp.uint32)
+        part = jnp.pad(part, ((0, 0), (0, width - part.shape[1])))
+        words = words | (part << (8 * q))
+    return words
+
+
+def _piece_bins(words: jax.Array, subset: jax.Array, pieces: _Pieces) -> jax.Array:
+    """[pieces, R, k] int32: each piece's tile of sorted rows on its node's
+    subset, ``binned[r, subset[node, j]]`` for bins under 256, from the
+    rows' words (:func:`_byte_words` of ``binned``). One product a piece
+    on the matrix unit, the tile's bins [R, 4W] against the node's [k, 4W]
+    one-hot, :data:`_SELECT_BLOCK_PIECES` pieces a step: bins under 256 and
+    a one-hot are exact at one bfloat16 pass, and each sum has one term that
+    is not zero. Its work grows with the pieces, tiles + nodes, where a
+    row's compares over all its features (:func:`_subset_bins`) grow with
+    the rows times the slots. A piece's rows of other nodes hold bins on its
+    node's subset too; the histogram's mask drops them. The rows move once,
+    into the level's order."""
+    k = subset.shape[1]
+    width = words.shape[1]
+    tiles_ = _sorted_tiles(words, pieces)
+    count = pieces.tile.shape[0]
+    block = min(_SELECT_BLOCK_PIECES, count)
+    features = jnp.arange(4 * width, dtype=subset.dtype)
+
+    def select(i, out):
+        # the last step takes the last block, some of its pieces again
+        first = jnp.minimum(i * block, count - block)
+        tile = lax.dynamic_slice_in_dim(pieces.tile, first, block)
+        node = lax.dynamic_slice_in_dim(pieces.node, first, block)
+        x = tiles_[tile]  # [block, R, W]
+        x = jnp.concatenate([(x >> (8 * q)) & 255 for q in range(4)], axis=-1)
+        hot = features[None, None, :] == subset[node][:, :, None]  # [block, k, 4W]
+        got = jnp.einsum(
+            "prf,pkf->prk", x.astype(jnp.float32), hot.astype(jnp.float32),
+            precision=lax.Precision.DEFAULT,
+        )
+        return lax.dynamic_update_slice_in_dim(out, got.astype(jnp.int32), first, 0)
+
+    return lax.fori_loop(
+        0, -(-count // block), select,
+        jnp.zeros((count, _TILE_ROWS, k), jnp.int32),
+    )
+
+
 def _onehot_sums(tile_bins: jax.Array, tile_stats: jax.Array, n_bins: int) -> jax.Array:
     """[..., S, k, B] Σ over a tile's rows r of onehot(tile_bins[..., r, j])
     ⊗ tile_stats[..., r, :]: a batch of products on the matrix unit whose
@@ -217,48 +353,30 @@ def _onehot_sums(tile_bins: jax.Array, tile_stats: jax.Array, n_bins: int) -> ja
 
 
 def _level_hist(
-    row_bins: jax.Array,  # [rows, k] each row's bins on its node's subset
-    local: jax.Array,  # [rows] node of the level
+    piece_bins: jax.Array,  # [pieces, R, k] each piece's rows' bins on its node's subset
+    pieces: _Pieces,  # the level's layout (:func:`_level_pieces`)
     contrib: jax.Array,  # [rows, S] weighted stats (0 for inactive rows)
     nodes: int,
     n_bins: int,
 ) -> jax.Array:
     """[S, nodes, k, B] histograms of one level, with no scatter of rows.
 
-    The rows are sorted by node and cut in tiles of :data:`_TILE_ROWS`; the
-    node boundaries cut the tiles again into at most tiles + nodes pieces,
-    each a run of one node's rows inside one tile. A piece's histogram is a
-    masked one-hot product over its tile (:func:`_onehot_sums`), and a
-    node's is the sum of its pieces (a segment sum over sorted pieces). A
-    scatter-add of the rows·k (row, slot) pairs is what this replaces: on a
-    TPU it sorts them first, 320 ms a level at 524,288 rows and 55 slots."""
-    rows, k = row_bins.shape
+    A piece's histogram is a masked one-hot product over its tile
+    (:func:`_onehot_sums`), and a node's is the sum of its pieces (a segment
+    sum over sorted pieces). A scatter-add of the rows·k (row, slot) pairs is
+    what this replaces: on a TPU it sorts them first, 320 ms a level at
+    524,288 rows and 55 slots."""
+    k = piece_bins.shape[2]
     S = contrib.shape[1]
     R = _TILE_ROWS
-    padded = -(-rows // R) * R
-    tiles = padded // R
-    order = jnp.argsort(local, stable=True)
-    pad = padded - rows
-    node_of = jnp.pad(local[order], (0, pad), constant_values=nodes - 1)
-    tile_bins = jnp.pad(row_bins[order], ((0, pad), (0, 0))).reshape(tiles, R, k)
-    tile_stats = jnp.pad(contrib[order], ((0, pad), (0, 0))).reshape(tiles, R, S)
-
-    node_starts = jnp.searchsorted(
-        node_of, jnp.arange(nodes, dtype=node_of.dtype), side="left"
-    )
-    starts = jnp.sort(
-        jnp.concatenate([jnp.arange(tiles, dtype=node_starts.dtype) * R, node_starts])
-    )
-    ends = jnp.concatenate([starts[1:], jnp.full((1,), padded, starts.dtype)])
-    tile = jnp.minimum(starts // R, tiles - 1)
-    where = tile[:, None] * R + jnp.arange(R, dtype=starts.dtype)[None, :]
-    inside = (where >= starts[:, None]) & (where < ends[:, None])
+    where = pieces.tile[:, None] * R + jnp.arange(R, dtype=pieces.start.dtype)[None, :]
+    inside = (where >= pieces.start[:, None]) & (where < pieces.end[:, None])
+    tile_stats = _sorted_tiles(contrib, pieces)
     sums = _onehot_sums(
-        tile_bins[tile], tile_stats[tile] * inside[..., None], n_bins
+        piece_bins, tile_stats[pieces.tile] * inside[..., None], n_bins
     )  # [pieces, S, k, B]
-    piece_node = node_of[jnp.minimum(starts, padded - 1)]
     hist = jax.ops.segment_sum(
-        sums.reshape(sums.shape[0], -1), piece_node, num_segments=nodes,
+        sums.reshape(sums.shape[0], -1), pieces.node, num_segments=nodes,
         indices_are_sorted=True,
     )
     return hist.reshape(nodes, S, k, n_bins).transpose(1, 0, 2, 3)
@@ -289,6 +407,12 @@ def _grow(
     def reduce(x):
         return x if axis_name is None else lax.psum(x, axis_name)
 
+    # a tree's rows as words once, for the levels that select by pieces
+    words = (
+        _byte_words(binned) if piece_select_levels(n_feat, k, n_bins, max_depth)
+        else None
+    )
+
     for d in range(max_depth + 1):
         nodes_d = 2 ** d
         offset = nodes_d - 1
@@ -308,13 +432,16 @@ def _grow(
             break
 
         subset = node_subsets(key, d, n_feat, k, fdt)  # [nodes_d, k]
+        pieces = _level_pieces(local, nodes_d)
         if k == n_feat:
-            row_bins = binned.astype(jnp.int32)
-        elif nodes_d <= _DENSE_SELECT_NODES and n_bins <= 256:
-            row_bins = _subset_bins_dense(binned, subset, local)
+            piece_bins = _pieces_of(binned, pieces)
+        elif _selects_by_pieces(nodes_d, n_feat, k, n_bins):
+            piece_bins = _piece_bins(words, subset, pieces)
+        elif n_bins <= 256:
+            piece_bins = _pieces_of(_subset_bins_dense(binned, subset, local), pieces)
         else:
-            row_bins = _subset_bins(binned, subset[local])
-        hist = reduce(_level_hist(row_bins, local, contrib, nodes_d, n_bins))
+            piece_bins = _pieces_of(_subset_bins(binned, subset[local]), pieces)
+        hist = reduce(_level_hist(piece_bins, pieces, contrib, nodes_d, n_bins))
 
         total = jnp.sum(hist[:, :, 0], axis=2)  # [S, nodes_d]
         leaf_stats = lax.dynamic_update_slice(leaf_stats, total.T, (offset, 0))
@@ -361,14 +488,14 @@ def _grow(
         )
 
         # route rows: split nodes send rows to 2·node+1 (+1 if bin > b); a
-        # row's bin at its node's chosen slot is a compare over its k slots
+        # row's bin at its node's chosen feature is a compare over its bins
         decision = jnp.take(
-            jnp.stack([do_split.astype(jnp.int32), best_j, best_b], axis=1),
+            jnp.stack([do_split.astype(jnp.int32), best_f, best_b], axis=1),
             local, axis=0,
         )
         row_split = active & (decision[:, 0] > 0)
-        slot = jnp.arange(k, dtype=jnp.int32)[None, :] == decision[:, 1:2]
-        row_bin = jnp.sum(jnp.where(slot, row_bins, 0), axis=1, dtype=jnp.int32)
+        chosen = jnp.arange(n_feat, dtype=jnp.int32)[None, :] == decision[:, 1:2]
+        row_bin = jnp.sum(jnp.where(chosen, binned, 0), axis=1, dtype=jnp.int32)
         goes_right = (row_bin > decision[:, 2]).astype(jnp.int32)
         node = jnp.where(row_split, 2 * node + 1 + goes_right, node)
         active = active & row_split
@@ -398,8 +525,8 @@ def build_tree(
 ) -> TreeArrays:
     """Grow one histogram tree level-order; fully jittable, fixed shapes.
 
-    A level gathers each active row's bins on its node's k-feature subset
-    (:func:`node_subsets`), accumulates them into ``[S, 2^d, k, B]``
+    A level selects each active row's bins on its node's k-feature subset
+    (:func:`node_subsets`, :func:`_piece_bins`), accumulates them into ``[S, 2^d, k, B]``
     (:func:`_level_hist`), and
     takes the cumsum, the gains and the argmax over (subset slot, bin); the
     depth-capped level computes node totals only. With ``axis_name`` set
@@ -421,12 +548,20 @@ def tree_group(
     each step of its loop over trees: as many as keep a group's working set
     within a sixteenth of the device's memory. The working set of a tree is
     its deepest split level's histogram with its cumsum, right half and
-    gains, and its rows' gathered bins, segment ids and weighted stats.
-    Every tree at once where the device reports no memory limit (the CPU)."""
+    gains, its pieces' bins and stats (:func:`_level_hist`), its rows as
+    words and their copy in the level's order (:func:`_byte_words`) with
+    one step of the selection's tiles and one-hots (:func:`_piece_bins`),
+    and its rows' nodes and weights. Every tree at once where the device
+    reports no memory limit (the CPU)."""
     k = min(k_features, n_feat)
     deepest = 2 ** max(max_depth - 1, 0)
-    per_tree = 4 * (
-        4 * deepest * k * n_bins * n_stats + rows * k * (2 + n_stats) + 4 * rows
+    pieces = _tiles(rows)[1] + deepest
+    per_tree = (
+        4 * 4 * deepest * k * n_bins * n_stats
+        + 4 * pieces * _TILE_ROWS * (k + n_stats)
+        + 2 * rows * -(-n_feat // 512) * 512
+        + 4 * _SELECT_BLOCK_PIECES * n_feat * (_TILE_ROWS + k)
+        + 4 * 4 * rows
     )
     device = device if device is not None else jax.devices()[0]
     limit = (device.memory_stats() or {}).get("bytes_limit")

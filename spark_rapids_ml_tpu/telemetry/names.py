@@ -93,6 +93,9 @@ METRICS: frozenset[str] = frozenset({
     # fewer split nodes for the same trees)
     "forest.trees",
     "forest.split_nodes",
+    # of those trees' split levels, the ones whose rows' subset bins were
+    # one product a piece on the matrix unit (ops.forest._piece_bins), by path
+    "forest.piece_select_levels",
     # spans: duration, and duration less what child spans covered
     "span.seconds",
     "span.self_seconds",

@@ -827,3 +827,143 @@ def test_tree_group_fills_a_sixteenth_of_the_device():
     # sixteenth of 15.75 GiB, so one tree a step
     assert FO.tree_group(317_440, 3000, 55, 128, 2, 13, 5, device=Device(15.75 * 2**30)) == 1
     assert FO.tree_group(1000, 10, 3, 16, 2, 5, 7, device=Device(16e9)) == 7
+
+
+def _layout(case):
+    """(local, nodes, rows) of one level for the piece selection's cases."""
+    rng = np.random.default_rng(17)
+    if case == "one node":
+        return np.zeros(640, np.int64), 1, 640
+    if case == "empty nodes":  # nodes 0, 3 and 5..7 hold no row
+        return rng.choice([1, 2, 4], size=700), 8, 700
+    if case == "a node over many tiles":
+        local = np.full(1024, 2)
+        local[:5], local[-3:] = 0, 3
+        return rng.permutation(local), 4, 1024
+    if case == "128 one-row nodes in a tile":
+        return np.concatenate([np.arange(128), np.full(384, 130)])[rng.permutation(512)], 256, 512
+    if case == "rows off the tile":
+        return rng.integers(0, 32, size=1000), 32, 1000
+    # inactive rows keep a stale heap id, clipped into the level's range
+    node = rng.integers(0, 64, size=900)
+    return np.clip(node - 15, 0, 15), 16, 900
+
+
+@pytest.mark.parametrize("n_bins", [128, 256])
+@pytest.mark.parametrize("case", [
+    "one node", "empty nodes", "a node over many tiles", "128 one-row nodes in a tile",
+    "rows off the tile", "inactive rows",
+])
+def test_piece_selection_is_each_rows_bins_on_its_nodes_subset(case, n_bins):
+    """Each piece's rows hold ``binned[r, subset[local[r], j]]`` exactly, and
+    every row is in exactly one piece; at 256 bins the byte 255 included."""
+    local, nodes, rows = _layout(case)
+    F, k = 40, 7
+    rng = np.random.default_rng(3)
+    binned = rng.integers(0, n_bins, size=(rows, F)).astype(np.uint8)
+    binned[::7, ::3] = n_bins - 1
+    subset = FO.node_subsets(jax.random.PRNGKey(5), int(np.log2(nodes)), F, k, jnp.float32)
+    pieces = FO._level_pieces(jnp.asarray(local), nodes)
+    words = FO._byte_words(jnp.asarray(binned))
+    got = np.asarray(jax.jit(FO._piece_bins)(words, subset, pieces))
+    order = np.arange(rows) if pieces.order is None else np.asarray(pieces.order)
+    subset = np.asarray(subset)
+    seen = np.zeros(rows, int)
+    R = FO._TILE_ROWS
+    for p, (s, e, t, n) in enumerate(zip(*(np.asarray(a) for a in pieces[1:]))):
+        for pos in range(s, min(e, rows)):
+            r = order[pos]
+            assert local[r] == n
+            np.testing.assert_array_equal(got[p, pos - t * R], binned[r, subset[n]])
+            seen[r] += 1
+    assert (seen == 1).all()
+    assert got.dtype == np.int32 and (n_bins - 1 in got)
+
+
+def _by_compares(words, subset, pieces):
+    """``_piece_bins`` by the compares of ``_subset_bins``: the rows' bytes
+    unpacked from their words, each row's node read back from the pieces."""
+    rows = words.shape[0]
+    binned = jnp.concatenate([(words >> (8 * q)) & 255 for q in range(4)], axis=1)
+    at = jnp.arange(rows)
+    node = pieces.node[jnp.searchsorted(pieces.start, at, side="right") - 1]
+    if pieces.order is not None:
+        node = jnp.zeros_like(node).at[pieces.order].set(node)
+    return FO._pieces_of(FO._subset_bins(binned, subset[node]), pieces)
+
+
+def _oracle_selection(monkeypatch):
+    """Every byte level's bins by the compares (``_subset_bins``), laid out
+    in pieces, in place of the matrix unit's product."""
+    from spark_rapids_ml_tpu.parallel import forest as PF
+
+    monkeypatch.setattr(FO, "_piece_bins", _by_compares)
+    PF.make_sharded_forest.cache_clear()
+    FO.forest_program.cache_clear()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("impurity", ["gini", "entropy", "variance"])
+def test_piece_selection_grows_the_compares_trees(monkeypatch, impurity):
+    """``build_tree`` and the sharded forest on four devices, with the piece
+    selection and with the compares in its place: every array bit for bit."""
+    from spark_rapids_ml_tpu.parallel import forest as PF
+    from spark_rapids_ml_tpu.parallel.mesh import create_mesh
+
+    binned, row_stats, w = _toy(impurity, rows=2048, F=12, B=16, seed=4)
+    binned = binned.astype(np.uint8)
+    T = 3
+    weights = np.stack([np.random.default_rng(t).poisson(1.0, 2048) for t in range(T)]).astype(float)
+    keys = jax.random.split(jax.random.PRNGKey(2), T)
+    static = dict(max_depth=7, n_bins=16, k_features=4, impurity=impurity)
+    assert FO.piece_select_levels(12, 4, 16, 7) == 3  # the levels of 16, 32 and 64 nodes
+    args = (jnp.asarray(binned), jnp.asarray(row_stats))
+    gate = (jnp.asarray(1.0), jnp.asarray(0.0))
+    mesh = create_mesh(data=4, devices=jax.devices()[:4])
+
+    def grow():
+        one = FO.build_tree(keys[0], *args, jnp.asarray(weights[0]), *gate, **static)
+        run = PF.make_sharded_forest(mesh, **static)
+        return one, run(keys, *args, jnp.asarray(weights), *gate)
+
+    PF.make_sharded_forest.cache_clear()
+    jax.clear_caches()
+    pieces = grow()
+    _oracle_selection(monkeypatch)
+    compares = grow()
+    for got, want in zip(pieces, compares):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (np.asarray(pieces[1].feature) >= 0).sum() > T * 20  # trees worth comparing
+    monkeypatch.undo()
+    PF.make_sharded_forest.cache_clear()
+    jax.clear_caches()
+
+
+def test_no_byte_level_falls_back_to_the_compares(monkeypatch):
+    """With ``_subset_bins`` refusing, a tree of byte bins still grows, and
+    every level past the dense product's nodes takes the pieces; int32 bins
+    over 256 keep the compares."""
+    calls = []
+    piece_bins = FO._piece_bins
+
+    def refuse(*a):
+        raise AssertionError("a byte level selected its bins by the compares")
+
+    def spy(*a):
+        calls.append(a[1].shape[0])
+        return piece_bins(*a)
+
+    monkeypatch.setattr(FO, "_subset_bins", refuse)
+    monkeypatch.setattr(FO, "_piece_bins", spy)
+    jax.clear_caches()
+    binned, row_stats, w = _toy("gini", rows=1500, F=12, B=64, seed=9)
+    args = (jax.random.PRNGKey(4), jnp.asarray(binned.astype(np.uint8)),
+            jnp.asarray(row_stats), jnp.asarray(w), jnp.asarray(1.0), jnp.asarray(0.0))
+    tree = FO.build_tree(*args, max_depth=6, n_bins=64, k_features=4, impurity="gini")
+    assert (np.asarray(tree.feature) >= 0).sum() > 10
+    assert sorted(calls) == [2 ** d for d in range(6) if 2 ** d > FO._DENSE_SELECT_NODES]
+    assert FO.piece_select_levels(12, 4, 64, 6) == len(calls)
+    assert FO.piece_select_levels(12, 4, 257, 6) == 0
+    assert FO.piece_select_levels(12, 12, 64, 6) == 0  # every feature: nothing to select
+    jax.clear_caches()

@@ -127,6 +127,23 @@ def test_spans_counters_and_the_program_name(session):
     assert lowered.as_text().startswith("module @jit__forest")
 
 
+@pytest.mark.parametrize("max_bins,levels", [(32, 2), (300, 0)])
+def test_the_piece_selection_counter(session, max_bins, levels):
+    """``forest.piece_select_levels`` books trees × the split levels whose
+    selection took the pieces: at depth 6 the levels of 16 and 32 nodes (the
+    dense product takes those of up to 8); none where bins are not bytes."""
+    df, _, _ = table(session, True)
+    before = REGISTRY.snapshot()
+    model = (
+        SparkRandomForestClassifier(distribution="mesh-local")
+        .setNumTrees(3).setMaxDepth(6).setMaxBins(max_bins).setSeed(1).fit(df)
+    )
+    moved = REGISTRY.snapshot().delta(before)
+    assert FO.piece_select_levels(F, 3, max_bins, 6) == levels
+    assert moved.counter("forest.piece_select_levels", path="mesh-local") == 3 * levels
+    assert (model.trees.feature[:, 15:] >= 0).any()  # the trees reach those levels
+
+
 def test_device_edges_are_the_sample_quantiles():
     """The edges of a sample of rows, positive weight only, against
     np.quantile of the same rows; the sample is Spark's size."""

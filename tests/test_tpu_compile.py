@@ -196,3 +196,7 @@ def test_the_forest_program_fits_beside_the_rows_at_the_cells_size(one_chip_mesh
     assert bins <= memory.argument_size_in_bytes < bins + 32e6
     float_rows = 4 * rows * n
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + float_rows < 15.75 * 2**30
+    # a level's selection by pieces holds a tree's rows as words and the
+    # level's sorted copy of them beside the rows' bytes: 5,325,043,712 B of
+    # temporaries, where the selection by compares held 3,990,602,752
+    assert memory.temp_size_in_bytes < 5.5e9
