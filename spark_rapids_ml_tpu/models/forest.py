@@ -331,10 +331,12 @@ class _ForestEstimator(_ForestParams, Estimator):
 
         Spans ``forest.bin`` (edges, bins and the wait for them) and
         ``forest build`` (the program's dispatch and the wait for its trees
-        on the host); counters ``forest.trees``, ``forest.split_nodes`` and
+        on the host); counters ``forest.trees``, ``forest.split_nodes``,
         ``forest.piece_select_levels`` (the trees' split levels whose
-        selection took ``ops.forest._piece_bins``), ``path="mesh-local"``,
-        booked from the trees handed back."""
+        selection took ``ops.forest._piece_bins``) and ``forest.level_blocks``
+        (the blocks of slots the trees' split levels walked their histograms
+        in, ``ops.forest.level_plan``), ``path="mesh-local"``, booked from
+        the trees handed back and the program's static plan."""
         from spark_rapids_ml_tpu.parallel import forest as PF
         from spark_rapids_ml_tpu.parallel.mesh import DATA_AXIS
         from spark_rapids_ml_tpu.telemetry.registry import REGISTRY
@@ -362,12 +364,15 @@ class _ForestEstimator(_ForestParams, Estimator):
                 rate=float(self.getOrDefault("subsamplingRate")),
             )(ing.ws)
             capacity = FO.row_capacity(weights, shards)
+            device = mesh.devices.flat[0]
             group = FO.tree_group(
                 capacity, n, static["k_features"], n_bins, row_stats.shape[1],
-                static["max_depth"], n_trees, device=mesh.devices.flat[0],
+                static["max_depth"], n_trees, device=device,
             )
+            block_bytes = FO.level_budget(device)
             run = PF.make_sharded_forest(
-                mesh, group=group, capacity=capacity, **static
+                mesh, group=group, capacity=capacity, block_bytes=block_bytes,
+                **static
             )
             trees = run(
                 jax.random.split(jax.random.PRNGKey(seed), n_trees),
@@ -385,6 +390,14 @@ class _ForestEstimator(_ForestParams, Estimator):
             "forest.piece_select_levels",
             len(trees.feature) * FO.piece_select_levels(
                 n, static["k_features"], n_bins, static["max_depth"]
+            ),
+            path="mesh-local",
+        )
+        REGISTRY.counter_inc(
+            "forest.level_blocks",
+            len(trees.feature) * FO.level_blocks(
+                capacity, n, static["k_features"], n_bins, row_stats.shape[1],
+                static["max_depth"], block_bytes,
             ),
             path="mesh-local",
         )

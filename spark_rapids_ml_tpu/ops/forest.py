@@ -22,6 +22,10 @@ Why histogram trees, and why breadth-first:
   ever reaches XLA. Histograms over the subset and not over every feature
   are what keep the deep levels in memory: at 3,000 features, 128 bins and
   depth 12 a level over every feature is 12.6 GB, over 55 it is 231 MB;
+  where the subset is wide (a regressor's F/3: 1,000 slots, 6.3 GB a
+  level) the level walks its histogram in blocks of slots that fit a
+  share of the device (:func:`level_plan`), each reduced to its nodes'
+  best split before the next;
 - the per-level histogram is a commutative monoid over rows — the mesh
   version (parallel/forest.py) psums it across row shards and every device
   takes identical split decisions, the same distribution shape as every
@@ -65,6 +69,12 @@ _SELECT_BLOCK_PIECES = 64
 #: levels of at most this many nodes select their rows' bins on the matrix
 #: unit (:func:`_subset_bins_dense`): its work grows with the nodes
 _DENSE_SELECT_NODES = 8
+#: and of at most this many nodes·slots: its [rows, nodes·k] result, 8
+#: nodes of 55 slots, 0.56 GB at the rf-3000-d13 cell's kept rows
+_DENSE_SELECT_COLUMNS = 440
+#: the share of the device's memory one block of a level's histogram may
+#: hold (:func:`level_budget`)
+_BLOCK_SHARE = 6
 #: a tree's kept rows are a multiple of this (:func:`row_capacity`), so that
 #: a tree's shape changes with its weights only by whole blocks
 _CAPACITY_STEP = 1024
@@ -110,21 +120,21 @@ def _impurity_n(stats: jax.Array, impurity: str) -> jax.Array:
     return jnp.where(n > 0, -safe * jnp.sum(ratio * jnp.log(ratio), axis=0), 0.0)
 
 
-def gain_floor(n_tot: jax.Array, impurity: str) -> jax.Array:
-    """The least n-scaled gain that is a gain. For gini and entropy: 1e-12,
-    or what the dtype's rounding leaves of a zero gain, 32 ulps of the
-    node's weight, where that is more. Their gains are differences of
-    impurities as large as the node's weight, so in float32 a split that
-    changes nothing (a pure node's, whose n − Σs²/n is 0 only as far as the
-    division is exact, and a TPU's is not) reads a few ulps of it: 435 to
-    526 such splits of some 8,400 a fit at the rf-3000-d13 cell's size. In
-    float64 the floor is 1e-12 up to nodes of 140 weighted rows. For
-    variance: 1e-12. Its impurity is in the label's unit squared, not a
-    count, so a floor in ulps of the weight would make a regression tree
-    depend on the label's unit."""
-    if impurity == "variance":
-        return jnp.full_like(n_tot, 1e-12)
-    return jnp.maximum(1e-12, _GAIN_ULPS * jnp.finfo(n_tot.dtype).eps * n_tot)
+def gain_floor(total: jax.Array, impurity: str) -> jax.Array:
+    """The least n-scaled gain that is a gain, for nodes of totals
+    ``total`` [S, nodes]: 1e-12, or what the dtype's rounding leaves of a
+    zero gain where that is more. A gain is a difference of impurities as
+    large as the node's weight (gini, entropy) or its Σw·y² (variance), so
+    in float32 a split that changes nothing reads a few ulps of it: a pure
+    node's, whose n − Σs²/n is 0 only as far as the division is exact, and
+    a TPU's is not (435 to 526 such splits of some 8,400 a fit at the
+    rf-3000-d13 cell's size), or a constant label's. The floor is 32 ulps
+    of the weight, or of Σw·y², which is in the label's unit squared as the
+    variance is: a regression tree does not depend on the label's unit. In
+    float64 the floor is 1e-12 up to nodes of 140 weighted rows (of Σw·y²
+    140 for variance)."""
+    scale = total[2] if impurity == "variance" else jnp.sum(total, axis=0)
+    return jnp.maximum(1e-12, _GAIN_ULPS * jnp.finfo(total.dtype).eps * scale)
 
 
 def _node_count(stats: jax.Array, impurity: str) -> jax.Array:
@@ -203,8 +213,11 @@ def _selects_by_pieces(nodes: int, n_feat: int, k: int, n_bins: int) -> bool:
     """Whether a level of ``nodes`` nodes selects its rows' ``k`` bins of
     ``n_feat`` by :func:`_piece_bins`: bins under 256, which one bfloat16
     pass holds exactly, a subset to select, and more nodes than the dense
-    product (:func:`_subset_bins_dense`) is quicker for."""
-    return k < n_feat and n_bins <= 256 and nodes > _DENSE_SELECT_NODES
+    product (:func:`_subset_bins_dense`) is quicker for, or a result
+    [rows, nodes·k] wider than it may build."""
+    return k < n_feat and n_bins <= 256 and (
+        nodes > _DENSE_SELECT_NODES or nodes * k > _DENSE_SELECT_COLUMNS
+    )
 
 
 def piece_select_levels(n_feat: int, k_features: int, n_bins: int, max_depth: int) -> int:
@@ -217,6 +230,61 @@ def _tiles(rows: int) -> tuple[int, int]:
     """(padded rows, tiles): ``rows`` cut in tiles of :data:`_TILE_ROWS`."""
     padded = -(-rows // _TILE_ROWS) * _TILE_ROWS
     return padded, padded // _TILE_ROWS
+
+
+class LevelPlan(NamedTuple):
+    """How a split level walks its histogram: ``blocks`` blocks of
+    ``slots`` subset slots (the last block's start is pulled back to end at
+    the last slot)."""
+
+    slots: int
+    blocks: int
+
+
+def _slot_bytes(rows: int, nodes: int, n_stats: int, n_bins: int) -> int:
+    """Bytes one subset slot of a level's histogram holds at once: its
+    pieces' sums, the stats' three bfloat16 parts side by side and their
+    total (:func:`_onehot_sums`), and the nodes' histogram with its cumsum,
+    right half and gains."""
+    pieces = _tiles(rows)[1] + nodes
+    return 4 * n_bins * n_stats * 4 * (pieces + nodes)
+
+
+def level_plan(
+    rows: int, k: int, n_bins: int, n_stats: int, nodes: int,
+    block_bytes: int | None,
+) -> LevelPlan:
+    """The blocks of subset slots a level of ``nodes`` nodes over ``rows``
+    rows walks its ``k`` slots in: as few as keep a block within
+    ``block_bytes`` (:func:`level_budget`), as even as they come. One block
+    where ``block_bytes`` is None."""
+    if block_bytes is None:
+        return LevelPlan(k, 1)
+    most = max(1, block_bytes // _slot_bytes(rows, nodes, n_stats, n_bins))
+    blocks = -(-k // most)
+    return LevelPlan(-(-k // blocks), blocks)
+
+
+def level_blocks(
+    rows: int, n_feat: int, k_features: int, n_bins: int, n_stats: int,
+    max_depth: int, block_bytes: int | None,
+) -> int:
+    """The blocks all of a tree's split levels take (:func:`level_plan`)."""
+    k = min(k_features, n_feat)
+    return sum(
+        level_plan(rows, k, n_bins, n_stats, 2 ** d, block_bytes).blocks
+        for d in range(max_depth)
+    )
+
+
+def level_budget(device=None) -> int | None:
+    """Bytes one block of a level's histogram may hold on ``device``: a
+    :data:`_BLOCK_SHARE`-th of its memory, which keeps the rf-3000-d13
+    cell's 55 slots one block at every level. None (every level one block)
+    where the device reports no limit (the CPU)."""
+    device = device if device is not None else jax.devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    return int(limit) // _BLOCK_SHARE if limit else None
 
 
 def _level_pieces(local: jax.Array, nodes: int) -> _Pieces:
@@ -281,8 +349,10 @@ def _byte_words(binned: jax.Array) -> jax.Array:
     return words
 
 
-def _piece_bins(words: jax.Array, subset: jax.Array, pieces: _Pieces) -> jax.Array:
-    """[pieces, R, k] int32: each piece's tile of sorted rows on its node's
+def _piece_bins(
+    words: jax.Array, subset: jax.Array, pieces: _Pieces, dtype=jnp.int32
+) -> jax.Array:
+    """[pieces, R, k] ``dtype``: each piece's tile of sorted rows on its node's
     subset, ``binned[r, subset[node, j]]`` for bins under 256, from the
     rows' words (:func:`_byte_words` of ``binned``). One product a piece
     on the matrix unit, the tile's bins [R, 4W] against the node's [k, 4W]
@@ -312,11 +382,11 @@ def _piece_bins(words: jax.Array, subset: jax.Array, pieces: _Pieces) -> jax.Arr
             "prf,pkf->prk", x.astype(jnp.float32), hot.astype(jnp.float32),
             precision=lax.Precision.DEFAULT,
         )
-        return lax.dynamic_update_slice_in_dim(out, got.astype(jnp.int32), first, 0)
+        return lax.dynamic_update_slice_in_dim(out, got.astype(dtype), first, 0)
 
     return lax.fori_loop(
         0, -(-count // block), select,
-        jnp.zeros((count, _TILE_ROWS, k), jnp.int32),
+        jnp.zeros((count, _TILE_ROWS, k), dtype),
     )
 
 
@@ -382,11 +452,99 @@ def _level_hist(
     return hist.reshape(nodes, S, k, n_bins).transpose(1, 0, 2, 3)
 
 
+def _hist_best(hist, totals, fresh, n_bins, impurity, min_instances, min_info_gain):
+    """A histogram [S, nodes, slots, B] reduced to each node's first best
+    valid (gain, slot, bin), [nodes] each, gain −inf where it has none,
+    beside the node totals [S, nodes] ``totals`` makes of its own (its slot
+    0's). ``fresh`` [slots] marks the slots to take (None: all)."""
+    nodes = hist.shape[1]
+    total = totals(jnp.sum(hist[:, :, 0], axis=2))  # [S, nodes]
+    left = jnp.cumsum(hist, axis=3)  # [S, nodes, slots, B]
+    right = total[:, :, None, None] - left
+    gain_n = (
+        _impurity_n(total, impurity)[:, None, None]
+        - _impurity_n(left, impurity)
+        - _impurity_n(right, impurity)
+    )
+    n_tot = _node_count(total, impurity)  # [nodes]
+    n_l = _node_count(left, impurity)
+    n_r = _node_count(right, impurity)
+    safe_tot = jnp.where(n_tot > 0, n_tot, 1.0)
+    ok = (
+        (n_l >= min_instances)
+        & (n_r >= min_instances)
+        & (gain_n / safe_tot[:, None, None] >= min_info_gain)
+        & (gain_n > gain_floor(total, impurity)[:, None, None])
+    )
+    # the last bin's "split" puts everything left — structurally invalid
+    ok = ok & (jnp.arange(n_bins)[None, None, :] < n_bins - 1)
+    if fresh is not None:
+        ok = ok & fresh[None, :, None]
+    # subset slots ascend in feature id, so the first best (j, b) is the
+    # first best (feature, bin)
+    flat = jnp.where(ok, gain_n, -jnp.inf).reshape(nodes, -1)
+    best = jnp.argmax(flat, axis=1)
+    best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
+    return (
+        total, best_gain, (best // n_bins).astype(jnp.int32),
+        (best % n_bins).astype(jnp.int32),
+    )
+
+
+def _best_splits(
+    piece_bins, pieces, contrib, nodes, n_bins, plan, impurity,
+    min_instances, min_info_gain, reduce,
+):
+    """A split level's node totals [S, nodes] and each node's first best
+    valid split over its subset: (gain, slot, bin), [nodes] each, gain −inf
+    where it has none. The level's histogram (:func:`_level_hist`) is walked
+    in ``plan``'s blocks of slots, each reduced to its nodes' best
+    (:func:`_hist_best`) before the next; one block is the whole subset at
+    once. ``reduce`` sums a histogram over the row shards."""
+    k = piece_bins.shape[2]
+    S = contrib.shape[1]
+
+    def block_best(piece_bins, totals, fresh):
+        hist = reduce(_level_hist(piece_bins, pieces, contrib, nodes, n_bins))
+        return _hist_best(
+            hist, totals, fresh, n_bins, impurity, min_instances, min_info_gain
+        )
+
+    if plan.blocks == 1:
+        return block_best(piece_bins, lambda own: own, None)
+
+    def walk(i, carry):
+        # the last block ends at the last slot; what it takes again is not
+        # fresh. Every block's gains take the first block's totals
+        first = jnp.minimum(i * plan.slots, k - plan.slots)
+        got = block_best(
+            lax.dynamic_slice_in_dim(piece_bins, first, plan.slots, axis=2),
+            lambda own: jnp.where(i == 0, own, carry[0]),
+            first + jnp.arange(plan.slots) >= i * plan.slots,
+        )
+        # a later block takes a node only with a greater gain: the first
+        # best in ascending slot order, as one block's argmax
+        better = got[1] > carry[1]
+        return (
+            got[0], jnp.where(better, got[1], carry[1]),
+            jnp.where(better, got[2] + first, carry[2]),
+            jnp.where(better, got[3], carry[3]),
+        )
+
+    fdt = contrib.dtype
+    return lax.fori_loop(0, plan.blocks, walk, (
+        jnp.zeros((S, nodes), fdt), jnp.full((nodes,), -jnp.inf, fdt),
+        jnp.zeros((nodes,), jnp.int32), jnp.zeros((nodes,), jnp.int32),
+    ))
+
+
 def _grow(
     key, binned, row_stats, w, min_instances, min_info_gain, *,
-    max_depth, n_bins, k_features, impurity, axis_name,
+    max_depth, n_bins, k_features, impurity, axis_name, block_bytes=None,
 ) -> TreeArrays:
-    """One tree, level by level (the body of :func:`build_tree`)."""
+    """One tree, level by level (the body of :func:`build_tree`); each
+    split level walks its histogram in the blocks :func:`level_plan` gives
+    for ``block_bytes``."""
     if impurity not in IMPURITIES:
         raise ValueError(f"impurity must be one of {IMPURITIES}")
     rows, n_feat = binned.shape
@@ -433,46 +591,26 @@ def _grow(
 
         subset = node_subsets(key, d, n_feat, k, fdt)  # [nodes_d, k]
         pieces = _level_pieces(local, nodes_d)
+        plan = level_plan(rows, k, n_bins, S, nodes_d, block_bytes)
+        # a level walked in blocks holds its rows' bins on every slot at once:
+        # as bytes where they are
+        narrow = bins_dtype(n_bins) if plan.blocks > 1 else jnp.int32
         if k == n_feat:
             piece_bins = _pieces_of(binned, pieces)
         elif _selects_by_pieces(nodes_d, n_feat, k, n_bins):
-            piece_bins = _piece_bins(words, subset, pieces)
+            piece_bins = _piece_bins(words, subset, pieces, narrow)
         elif n_bins <= 256:
-            piece_bins = _pieces_of(_subset_bins_dense(binned, subset, local), pieces)
+            piece_bins = _pieces_of(
+                _subset_bins_dense(binned, subset, local).astype(narrow), pieces
+            )
         else:
             piece_bins = _pieces_of(_subset_bins(binned, subset[local]), pieces)
-        hist = reduce(_level_hist(piece_bins, pieces, contrib, nodes_d, n_bins))
 
-        total = jnp.sum(hist[:, :, 0], axis=2)  # [S, nodes_d]
+        total, best_gain, best_j, best_b = _best_splits(
+            piece_bins, pieces, contrib, nodes_d, n_bins, plan, impurity,
+            min_instances, min_info_gain, reduce,
+        )
         leaf_stats = lax.dynamic_update_slice(leaf_stats, total.T, (offset, 0))
-
-        left = jnp.cumsum(hist, axis=3)  # [S, nodes_d, k, B]
-        right = total[:, :, None, None] - left
-        gain_n = (
-            _impurity_n(total, impurity)[:, None, None]
-            - _impurity_n(left, impurity)
-            - _impurity_n(right, impurity)
-        )
-        n_tot = _node_count(total, impurity)  # [nodes_d]
-        n_l = _node_count(left, impurity)
-        n_r = _node_count(right, impurity)
-        safe_tot = jnp.where(n_tot > 0, n_tot, 1.0)
-        ok = (
-            (n_l >= min_instances)
-            & (n_r >= min_instances)
-            & (gain_n / safe_tot[:, None, None] >= min_info_gain)
-            & (gain_n > gain_floor(n_tot, impurity)[:, None, None])
-        )
-        # the last bin's "split" puts everything left — structurally invalid
-        ok = ok & (jnp.arange(n_bins)[None, None, :] < n_bins - 1)
-
-        # subset slots ascend in feature id, so the first best (j, b) is the
-        # first best (feature, bin)
-        flat = jnp.where(ok, gain_n, -jnp.inf).reshape(nodes_d, k * n_bins)
-        best = jnp.argmax(flat, axis=1)
-        best_gain = jnp.take_along_axis(flat, best[:, None], axis=1)[:, 0]
-        best_j = (best // n_bins).astype(jnp.int32)
-        best_b = (best % n_bins).astype(jnp.int32)
         best_f = jnp.take_along_axis(subset, best_j[:, None], axis=1)[:, 0]
         do_split = best_gain > -jnp.inf  # [nodes_d]
 
@@ -507,6 +645,7 @@ def _grow(
     jax.jit,
     static_argnames=(
         "max_depth", "n_bins", "k_features", "impurity", "axis_name",
+        "block_bytes",
     ),
 )
 def build_tree(
@@ -522,21 +661,24 @@ def build_tree(
     k_features: int,
     impurity: str,
     axis_name: str | None = None,
+    block_bytes: int | None = None,
 ) -> TreeArrays:
     """Grow one histogram tree level-order; fully jittable, fixed shapes.
 
     A level selects each active row's bins on its node's k-feature subset
     (:func:`node_subsets`, :func:`_piece_bins`), accumulates them into ``[S, 2^d, k, B]``
     (:func:`_level_hist`), and
-    takes the cumsum, the gains and the argmax over (subset slot, bin); the
-    depth-capped level computes node totals only. With ``axis_name`` set
-    (mesh build), each level's histogram and the leaf totals are psum'd over
-    that axis — rows are sharded, decisions replicated.
+    takes the cumsum, the gains and the argmax over (subset slot, bin), in
+    blocks of slots of at most ``block_bytes`` each (one block where it is
+    None: :func:`level_plan`); the depth-capped level computes node totals
+    only. With ``axis_name`` set (mesh build), each level's histogram and
+    the leaf totals are psum'd over that axis — rows are sharded, decisions
+    replicated.
     """
     return _grow(
         key, binned, row_stats, w, min_instances, min_info_gain,
         max_depth=max_depth, n_bins=n_bins, k_features=k_features,
-        impurity=impurity, axis_name=axis_name,
+        impurity=impurity, axis_name=axis_name, block_bytes=block_bytes,
     )
 
 
@@ -547,26 +689,29 @@ def tree_group(
     """How many trees the forest program grows side by side (vmapped) in
     each step of its loop over trees: as many as keep a group's working set
     within a sixteenth of the device's memory. The working set of a tree is
-    its deepest split level's histogram with its cumsum, right half and
-    gains, its pieces' bins and stats (:func:`_level_hist`), its rows as
-    words and their copy in the level's order (:func:`_byte_words`) with
-    one step of the selection's tiles and one-hots (:func:`_piece_bins`),
-    and its rows' nodes and weights. Every tree at once where the device
-    reports no memory limit (the CPU)."""
-    k = min(k_features, n_feat)
-    deepest = 2 ** max(max_depth - 1, 0)
-    pieces = _tiles(rows)[1] + deepest
-    per_tree = (
-        4 * 4 * deepest * k * n_bins * n_stats
-        + 4 * pieces * _TILE_ROWS * (k + n_stats)
-        + 2 * rows * -(-n_feat // 512) * 512
-        + 4 * _SELECT_BLOCK_PIECES * n_feat * (_TILE_ROWS + k)
-        + 4 * 4 * rows
-    )
+    its deepest split level's block of slots (:func:`level_plan` for
+    :func:`level_budget`: the pieces' sums, the histogram with its cumsum,
+    right half and gains), its pieces' bins on every slot and their stats
+    (:func:`_level_hist`), its rows as words and their copy in the level's
+    order (:func:`_byte_words`) with one step of the selection's tiles and
+    one-hots (:func:`_piece_bins`), and its rows' nodes and weights. Every
+    tree at once where the device reports no memory limit (the CPU)."""
     device = device if device is not None else jax.devices()[0]
     limit = (device.memory_stats() or {}).get("bytes_limit")
     if not limit:
         return n_trees
+    k = min(k_features, n_feat)
+    deepest = 2 ** max(max_depth - 1, 0)
+    pieces = _tiles(rows)[1] + deepest
+    plan = level_plan(rows, k, n_bins, n_stats, deepest, level_budget(device))
+    bin_bytes = 1 if plan.blocks > 1 and n_bins <= 256 else 4
+    per_tree = (
+        plan.slots * _slot_bytes(rows, deepest, n_stats, n_bins)
+        + pieces * _TILE_ROWS * (bin_bytes * k + 4 * n_stats)
+        + 2 * rows * -(-n_feat // 512) * 512
+        + 4 * _SELECT_BLOCK_PIECES * n_feat * (_TILE_ROWS + k)
+        + 4 * 4 * rows
+    )
     return int(max(1, min(n_trees, limit // 16 // per_tree)))
 
 
@@ -586,13 +731,15 @@ def row_capacity(weights: jax.Array, shards: int = 1) -> int:
 def grow_forest(
     keys, binned, row_stats, weights, min_instances, min_info_gain, *,
     max_depth, n_bins, k_features, impurity, group, capacity=None,
-    axis_name=None,
+    axis_name=None, block_bytes=None,
 ) -> TreeArrays:
     """[T, ...] TreeArrays: the trees in steps of ``group`` side by side
     (``lax.map``), never all of them vmapped at once unless they fit. With
     ``capacity`` under the rows, each tree first keeps its rows of positive
     weight, in order, in ``capacity`` rows (:func:`row_capacity`), so its
-    levels walk no row it leaves out; the trees are the same."""
+    levels walk no row it leaves out; the trees are the same. Each level
+    walks its histogram in blocks of at most ``block_bytes``
+    (:func:`level_plan`): the trees are the same."""
 
     def one(tree):
         key, w = tree
@@ -603,7 +750,7 @@ def grow_forest(
         return _grow(
             key, b, stats, w, min_instances, min_info_gain,
             max_depth=max_depth, n_bins=n_bins, k_features=k_features,
-            impurity=impurity, axis_name=axis_name,
+            impurity=impurity, axis_name=axis_name, block_bytes=block_bytes,
         )
 
     return lax.map(one, (keys, weights), batch_size=group)
@@ -612,7 +759,7 @@ def grow_forest(
 @lru_cache(maxsize=32)
 def forest_program(
     *, max_depth: int, n_bins: int, k_features: int, impurity: str,
-    group: int, capacity: int | None = None,
+    group: int, capacity: int | None = None, block_bytes: int | None = None,
 ):
     """The single-device forest program; a private function's name is the
     program's in a device trace (``jit__forest``)."""
@@ -622,6 +769,7 @@ def forest_program(
             keys, binned, row_stats, weights, min_instances, min_info_gain,
             max_depth=max_depth, n_bins=n_bins, k_features=k_features,
             impurity=impurity, group=group, capacity=capacity,
+            block_bytes=block_bytes,
         )
 
     return jax.jit(_forest)
@@ -637,16 +785,17 @@ def build_forest(
     **static,
 ) -> TreeArrays:
     """[T, ...] TreeArrays on the default device (program ``jit__forest``),
-    :func:`tree_group` trees at a time over :func:`row_capacity` rows."""
+    :func:`tree_group` trees at a time over :func:`row_capacity` rows, each
+    level in blocks of :func:`level_budget`."""
     weights = jnp.asarray(weights)
     capacity = row_capacity(weights)
     group = tree_group(
         capacity, binned.shape[1], static["k_features"], static["n_bins"],
         row_stats.shape[1], static["max_depth"], keys.shape[0],
     )
-    return forest_program(group=group, capacity=capacity, **static)(
-        keys, binned, row_stats, weights, min_instances, min_info_gain
-    )
+    return forest_program(
+        group=group, capacity=capacity, block_bytes=level_budget(), **static
+    )(keys, binned, row_stats, weights, min_instances, min_info_gain)
 
 
 #: the bootstrap's stream of a fit's seed, apart from the subsets' keys

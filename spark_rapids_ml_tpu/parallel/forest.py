@@ -42,12 +42,15 @@ def make_sharded_forest(
     impurity: str,
     group: int | None = None,
     capacity: int | None = None,
+    block_bytes: int | None = None,
 ):
     """Compile ``run(keys, binned, row_stats, weights, min_inst, min_gain)
     -> TreeArrays [T, ...]`` with rows data-sharded (equal shards; pad rows
     carry weight 0) and trees/outputs replicated, ``group`` trees side by
     side (``ops.forest.tree_group``; one at a time by default), each over
-    ``capacity`` rows of its shard (``ops.forest.row_capacity``). The same
+    ``capacity`` rows of its shard (``ops.forest.row_capacity``), each level
+    in blocks of slots of at most ``block_bytes`` (``ops.forest.level_plan``;
+    one block a level by default). The same
     trees as the single-device :func:`ops.forest.build_forest` (tests
     assert equality: histogram sums are integer-valued, so psum order
     cannot perturb the argmax)."""
@@ -57,7 +60,7 @@ def make_sharded_forest(
             keys, binned, row_stats, weights, min_inst, min_gain,
             max_depth=max_depth, n_bins=n_bins, k_features=k_features,
             impurity=impurity, group=group, capacity=capacity,
-            axis_name=DATA_AXIS,
+            axis_name=DATA_AXIS, block_bytes=block_bytes,
         )
 
     specs = (

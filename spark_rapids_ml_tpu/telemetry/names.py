@@ -96,6 +96,10 @@ METRICS: frozenset[str] = frozenset({
     # of those trees' split levels, the ones whose rows' subset bins were
     # one product a piece on the matrix unit (ops.forest._piece_bins), by path
     "forest.piece_select_levels",
+    # and the blocks of subset slots all of those trees' split levels walked
+    # their histograms in (ops.forest.level_plan: one a level where a block
+    # holds the whole subset), by path
+    "forest.level_blocks",
     # spans: duration, and duration less what child spans covered
     "span.seconds",
     "span.self_seconds",

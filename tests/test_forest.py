@@ -880,7 +880,7 @@ def test_piece_selection_is_each_rows_bins_on_its_nodes_subset(case, n_bins):
     assert got.dtype == np.int32 and (n_bins - 1 in got)
 
 
-def _by_compares(words, subset, pieces):
+def _by_compares(words, subset, pieces, dtype=jnp.int32):
     """``_piece_bins`` by the compares of ``_subset_bins``: the rows' bytes
     unpacked from their words, each row's node read back from the pieces."""
     rows = words.shape[0]
@@ -889,7 +889,7 @@ def _by_compares(words, subset, pieces):
     node = pieces.node[jnp.searchsorted(pieces.start, at, side="right") - 1]
     if pieces.order is not None:
         node = jnp.zeros_like(node).at[pieces.order].set(node)
-    return FO._pieces_of(FO._subset_bins(binned, subset[node]), pieces)
+    return FO._pieces_of(FO._subset_bins(binned, subset[node]), pieces).astype(dtype)
 
 
 def _oracle_selection(monkeypatch):
@@ -967,3 +967,105 @@ def test_no_byte_level_falls_back_to_the_compares(monkeypatch):
     assert FO.piece_select_levels(12, 4, 257, 6) == 0
     assert FO.piece_select_levels(12, 12, 64, 6) == 0  # every feature: nothing to select
     jax.clear_caches()
+
+
+@pytest.mark.parametrize("impurity", ["gini", "variance"])
+def test_a_level_walked_in_blocks_grows_the_same_trees(impurity):
+    """Each split level's histogram walked a few subset slots at a time (the
+    block forced small through the program's ``block_bytes``), the last block
+    pulled back over slots taken before, against the whole subset at once:
+    ``build_tree`` and the sharded forest on four devices grow every array
+    bit for bit. The labels lie on a grid, so every float sum is exact
+    whatever order a block sums it in."""
+    from spark_rapids_ml_tpu.parallel import forest as PF
+    from spark_rapids_ml_tpu.parallel.mesh import create_mesh
+
+    rows, F, B, k = 2048, 30, 16, 10
+    binned, row_stats, w = _toy("gini", rows=rows, F=F, B=B, seed=6)
+    binned = binned.astype(np.uint8)
+    if impurity == "variance":
+        y = np.round(np.random.default_rng(6).normal(size=rows) * 4) / 4
+        row_stats = np.stack([np.ones(rows), y, y * y], axis=1)
+    T = 3
+    weights = np.stack([np.random.default_rng(t).poisson(1.0, rows) for t in range(T)]).astype(float)
+    keys = jax.random.split(jax.random.PRNGKey(2), T)
+    static = dict(max_depth=6, n_bins=B, k_features=k, impurity=impurity)
+    S = row_stats.shape[1]
+    small = 3 * FO._slot_bytes(rows // 4, 1, S, B)  # 3 slots a block at the root
+    plans = [FO.level_plan(r, k, B, S, 2 ** d, small) for r in (rows, rows // 4)
+             for d in range(6)]
+    assert all(p.blocks > 1 for p in plans) and any(p.blocks * p.slots > k for p in plans)
+    args = (jnp.asarray(binned), jnp.asarray(row_stats))
+    gate = (jnp.asarray(1.0), jnp.asarray(0.0))
+    mesh = create_mesh(data=4, devices=jax.devices()[:4])
+
+    def grow(block_bytes):
+        one = FO.build_tree(keys[0], *args, jnp.asarray(weights[0]), *gate,
+                            block_bytes=block_bytes, **static)
+        run = PF.make_sharded_forest(mesh, block_bytes=block_bytes, **static)
+        return one, run(keys, *args, jnp.asarray(weights), *gate)
+
+    whole, blocked = grow(None), grow(small)
+    for got, want in zip(blocked, whole):
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert (np.asarray(whole[1].feature) >= 0).sum() > T * 20  # trees worth comparing
+
+
+def test_the_level_plan_at_the_forest_cells_shapes():
+    """On a v5e's 15.75 GiB: the classifier cell's 55 slots stay one block at
+    every level (it runs the program it ran before blocks), the regressor
+    cell's 1,000 are walked in blocks that hold at most a sixth of the chip,
+    and either grows one tree a step."""
+    class Device:
+        def memory_stats(self):
+            return {"bytes_limit": int(15.75 * 2**30)}
+
+    budget = FO.level_budget(Device())
+    assert budget == int(15.75 * 2**30) // 6
+    rows = 317_440
+    assert FO.level_blocks(rows, 3000, 55, 128, 2, 13, budget) == 13
+    plans = [FO.level_plan(rows, 1000, 128, 3, 2 ** d, budget) for d in range(13)]
+    assert all(p.blocks > 1 and p.slots * p.blocks >= 1000 for p in plans)
+    assert all(p.slots * FO._slot_bytes(rows, 2 ** d, 3, 128) <= budget
+               for d, p in enumerate(plans))
+    assert FO.level_blocks(rows, 3000, 1000, 128, 3, 13, budget) == sum(p.blocks for p in plans)
+    assert FO.level_blocks(rows, 3000, 1000, 128, 3, 13, None) == 13
+    for k, S in ((55, 2), (1000, 3)):
+        assert FO.tree_group(rows, 3000, k, 128, S, 13, 5, device=Device()) == 1
+    # the dense selection builds [rows, nodes·k]: at 1,000 slots no level takes it
+    assert FO.piece_select_levels(3000, 1000, 128, 13) == 13
+    assert FO.piece_select_levels(3000, 55, 128, 13) == 9
+
+
+def test_a_split_that_changes_nothing_is_not_taken(monkeypatch):
+    """A float32 regression node whose label is one constant: every split's
+    variance gain is 0, and what float32 reads of it is rounding, ulps of
+    the node's Σw·y² and far over 1e-12. Under a floor of 1e-12 the tree
+    splits on it; the floor of 32 ulps of Σw·y² refuses it, and the root
+    stays a leaf."""
+    rows, B = 1000, 8
+    rng = np.random.default_rng(0)
+    binned = jnp.asarray(rng.integers(0, B, size=(rows, 4)).astype(np.uint8))
+    y = np.full(rows, 0.1, np.float32)
+    row_stats = jnp.asarray(np.stack([np.ones_like(y), y, y * y], axis=1))
+    w = jnp.asarray(rng.poisson(1.0, rows).astype(np.float32))
+
+    def grow():  # eager, so that the floor in force is the one read
+        return FO._grow(
+            jax.random.PRNGKey(0), binned, row_stats, w, jnp.asarray(1.0, jnp.float32),
+            jnp.asarray(0.0, jnp.float32), max_depth=3, n_bins=B, k_features=4,
+            impurity="variance", axis_name=None,
+        )
+
+    floor = FO.gain_floor
+    with monkeypatch.context() as m:
+        m.setattr(FO, "gain_floor", lambda total, impurity: jnp.full(
+            total.shape[1:], 1e-12, total.dtype))
+        rounding = grow()
+    assert 1e-12 < float(np.asarray(rounding.gain)[0]) < float(
+        floor(jnp.asarray(np.asarray(rounding.leaf_stats)[:1].T), "variance")[0])
+    tree = grow()
+    assert np.asarray(tree.leaf_stats).dtype == np.float32
+    assert (np.asarray(tree.feature) == -1).all()
+    assert float(np.asarray(tree.leaf_stats)[0, 0]) == float(np.asarray(w).sum())

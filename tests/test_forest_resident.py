@@ -182,3 +182,135 @@ def test_the_forest_program_compiles_its_trees_once(session):
     for a, b in zip(first.trees, second.trees):
         np.testing.assert_array_equal(a, b)
     assert isinstance(first.trees, FO.TreeArrays)
+
+
+def grid_table(session, seed: int = 5):
+    """The regressor's table with its label on a grid of quarters: every
+    float sum of the build is exact, whatever order a program sums in."""
+    df, x, y = table(session, False, seed=seed)
+    import pyarrow as pa
+
+    y = np.round(y * 4) / 4
+    offsets = pa.array(np.arange(0, x.size + 1, F, dtype=np.int32))
+    feats = pa.ListArray.from_arrays(offsets, pa.array(x.reshape(-1)))
+    return session.createDataFrame(
+        pa.Table.from_arrays([feats, pa.array(y)], names=["features", "label"])
+    ), x, y
+
+
+def numpy_regression_tree(binned, y, w, subsets, *, max_depth, n_bins, min_inst, eps):
+    """One regression tree of the algorithm in float64 NumPy: level by level,
+    each node's histogram of [w, w·y, w·y²] over its own subset, the variance
+    gain of every (slot, bin), valid over the floor max(1e-12, 32·eps·Σw·y²),
+    the first best (slot, bin) taken."""
+    rows = len(y)
+    max_nodes = 2 ** (max_depth + 1) - 1
+    feature = np.full(max_nodes, -1)
+    split_bin = np.zeros(max_nodes, int)
+    leaf_stats = np.zeros((max_nodes, 3))
+    node = np.zeros(rows, int)
+    active = w > 0
+    stats = np.stack([np.ones(rows), y, y * y], axis=1)
+
+    def var_n(s):
+        safe = np.where(s[..., 0] > 0, s[..., 0], 1.0)
+        return np.where(s[..., 0] > 0, np.maximum(s[..., 2] - s[..., 1] ** 2 / safe, 0.0), 0.0)
+
+    for d in range(max_depth + 1):
+        offset = 2 ** d - 1
+        for n in range(2 ** d):
+            at = active & (node == offset + n)
+            total = (stats[at] * w[at, None]).sum(0)
+            leaf_stats[offset + n] = total
+            if d == max_depth or not at.any():
+                continue
+            best, pick = -np.inf, None
+            for j, f in enumerate(subsets[d][n]):
+                hist = np.zeros((n_bins, 3))
+                np.add.at(hist, binned[at, f], stats[at] * w[at, None])
+                left = np.cumsum(hist, axis=0)
+                gain = var_n(total) - var_n(left) - var_n(total - left)
+                ok = ((left[:, 0] >= min_inst) & (total[0] - left[:, 0] >= min_inst)
+                      & (np.arange(n_bins) < n_bins - 1)
+                      & (gain > max(1e-12, 32 * eps * total[2])))
+                if ok.any() and gain[ok].max() > best:
+                    b = int(np.flatnonzero(ok & (gain == gain[ok].max()))[0])
+                    best, pick = gain[b], (f, b)
+            if pick is not None:
+                feature[offset + n], split_bin[offset + n] = pick
+        if d < max_depth:
+            f = feature[node]
+            go = active & (f >= 0)
+            right = binned[np.arange(rows), np.maximum(f, 0)] > split_bin[node]
+            node = np.where(go, 2 * node + 1 + right, node)
+            active = go
+    return feature, split_bin, leaf_stats
+
+
+def test_the_regressor_grows_the_float64_reference_trees(session):
+    """``SparkRandomForestRegressor`` mesh-local at the published forest's
+    settings, cut to a toy (a third of the features a node, variance, a
+    Poisson(1) bootstrap): every tree node for node the float64 NumPy
+    reference's, grown from the program's own draws on the same bins."""
+    df, x, y = grid_table(session)
+    T, depth, B = 3, 6, 16
+    est = (SparkRandomForestRegressor(distribution="mesh-local")
+           .setNumTrees(T).setMaxDepth(depth).setMaxBins(B).setSeed(11))
+    model = est.fit(df)
+    k = MF.subset_size("auto", F, classification=False)
+    assert k == 3
+    edges = MF.quantile_bin_edges(x, B, 0)
+    binned = MF.bin_features(x, edges)
+    weights = np.asarray(FO.bootstrap_weights(11, T, ROWS, bootstrap=True, rate=1.0))
+    keys = jax.random.split(jax.random.PRNGKey(11), T)
+    eps = float(np.finfo(model.trees.leaf_stats.dtype).eps)
+    for t in range(T):
+        subsets = [np.asarray(FO.node_subsets(keys[t], d, F, k, model.trees.leaf_stats.dtype))
+                   for d in range(depth)]
+        feature, split_bin, leaf_stats = numpy_regression_tree(
+            binned, y, weights[t], subsets, max_depth=depth, n_bins=B, min_inst=1.0, eps=eps)
+        np.testing.assert_array_equal(model.trees.feature[t], feature)
+        np.testing.assert_array_equal(model.trees.split_bin[t][feature >= 0], split_bin[feature >= 0])
+        np.testing.assert_allclose(model.trees.leaf_stats[t], leaf_stats, rtol=1e-12, atol=1e-9)
+    assert (model.trees.feature >= 0).sum() > T * 10  # trees worth comparing
+
+
+@pytest.mark.parametrize(
+    "cls", [SparkRandomForestClassifier, SparkRandomForestRegressor],
+    ids=["classifier", "regressor"],
+)
+def test_a_smaller_fit_grows_the_first_trees_of_a_larger_one(session, cls):
+    """``numTrees`` is a cut and not another forest: the trees of a 2-tree fit
+    are the first two of a 5-tree fit with the same seed (the subsets' keys
+    and the bootstrap draw tree by tree from the seed), though the larger
+    fit keeps more rows a tree and grows its trees side by side."""
+    df = grid_table(session)[0] if cls is SparkRandomForestRegressor else table(session, True)[0]
+    est = cls(distribution="mesh-local").setMaxDepth(5).setMaxBins(32).setSeed(4)
+    small = est.copy().setNumTrees(2).fit(df)
+    large = est.copy().setNumTrees(5).fit(df)
+    for name in ("feature", "split_bin", "is_leaf", "leaf_stats"):
+        np.testing.assert_array_equal(
+            getattr(small.trees, name), getattr(large.trees, name)[:2], err_msg=name)
+    assert (small.trees.feature >= 0).sum() > 2 * 5
+
+
+def test_the_level_blocks_counter(session, monkeypatch):
+    """``forest.level_blocks`` books trees × the blocks of slots the trees'
+    split levels took (``ops.forest.level_plan``): one a level where the
+    device reports no memory, more where a block holds less."""
+    on_devices(monkeypatch, 1)
+    df, _, _ = grid_table(session)
+    est = (SparkRandomForestRegressor(distribution="mesh-local")
+           .setNumTrees(2).setMaxDepth(4).setMaxBins(16).setSeed(1))
+    before = REGISTRY.snapshot()
+    whole = est.fit(df)
+    assert REGISTRY.snapshot().delta(before).counter("forest.level_blocks", path="mesh-local") == 2 * 4
+    small = 2 * FO._slot_bytes(ROWS, 1, 3, 16)
+    monkeypatch.setattr(FO, "level_budget", lambda device=None: small)
+    before = REGISTRY.snapshot()
+    blocked = est.fit(df)
+    moved = REGISTRY.snapshot().delta(before).counter("forest.level_blocks", path="mesh-local")
+    capacity = FO.row_capacity(FO.bootstrap_weights(1, 2, ROWS, bootstrap=True, rate=1.0))
+    assert moved == 2 * FO.level_blocks(capacity, F, 3, 16, 3, 4, small) > 2 * 4
+    for a, b in zip(whole.trees, blocked.trees):
+        np.testing.assert_array_equal(a, b)

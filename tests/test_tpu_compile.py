@@ -200,3 +200,40 @@ def test_the_forest_program_fits_beside_the_rows_at_the_cells_size(one_chip_mesh
     # level's sorted copy of them beside the rows' bytes: 5,325,043,712 B of
     # temporaries, where the selection by compares held 3,990,602,752
     assert memory.temp_size_in_bytes < 5.5e9
+
+
+def test_the_regressor_forest_walks_its_levels_in_blocks_beside_the_rows(one_chip_mesh):
+    """``rfreg3000_fit_resident``: the classifier cell's rows and bins, a
+    regressor's statistics [w, w·y, w·y²] (S = 3, each in three bfloat16
+    parts) and its subset of a third of the features, 1,000 slots a node, at
+    depth 13 over the 317,440 rows a tree keeps. Held whole, a level's
+    histogram at depth 12 is [3, 4,096, 1,000, 128] float32, 6.3 GB, and its
+    pieces' sums 30 GB (the root's alone 20.3 GB as the chip lays them out).
+    Walked in blocks of slots that hold at most a sixth of the chip
+    (``ops.forest.level_budget``), what the program holds beside its
+    arguments fits the chip with the float32 rows still resident."""
+    from spark_rapids_ml_tpu.ops import forest as FO
+    from spark_rapids_ml_tpu.parallel import forest as PF
+
+    rows, n, n_bins, trees, k = 524_288, 3_000, 128, 2, 1_000
+    budget = int(15.75 * 2**30) // FO._BLOCK_SHARE
+    assert FO.level_blocks(317_440, n, k, n_bins, 3, 13, budget) > 13
+    run = PF.make_sharded_forest(
+        one_chip_mesh, max_depth=13, n_bins=n_bins, k_features=k,
+        impurity="variance", group=1, capacity=317_440, block_bytes=budget,
+    )
+    arg = functools.partial(on_mesh, one_chip_mesh)
+    compiled = run.lower(
+        arg((trees, 2), P(), np.uint32), arg((rows, n), P(M.DATA_AXIS, None), np.uint8),
+        arg((rows, 3), P(M.DATA_AXIS, None)), arg((trees, rows), P(None, M.DATA_AXIS)),
+        arg((), P()), arg((), P()),
+    ).compile()
+    assert compiled.as_text().startswith("HloModule jit__forest")
+    memory = compiled.memory_analysis()
+    bins = rows * n
+    assert bins <= memory.argument_size_in_bytes < bins + 32e6
+    float_rows = 4 * rows * n
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + float_rows < 15.75 * 2**30
+    # a block of slots at most, beside the rows as words, their sorted copy,
+    # the kept bytes and the level's bins on every slot as bytes: 8.76 GB
+    assert memory.temp_size_in_bytes < 9.0e9
